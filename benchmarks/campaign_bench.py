@@ -5,10 +5,11 @@ The tentpole before/after: the same campaign spec run twice through
 pre-mesh executor), once as vmapped lane batches sharded over a
 ``jax.sharding`` mesh of every visible device — with the resulting
 manifests asserted byte-identical (the mesh path is exact, not an
-approximation).  On a CPU host export
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` *before* Python
-starts to get a 4-device mesh; with a single device the run still
-measures the vmapped-batching win alone.
+approximation).  The mesh spans every device JAX sees; with a single
+device the run measures the vmapped-batching win alone.
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (exported before
+Python starts) fans out four *virtual CPU* devices: it rehearses the
+mesh path on a CPU host and says nothing about accelerator speed.
 
 Full mode is the acceptance campaign: 64 points (16 same-``sets``
 geometries x 4 co-runner mixes) on a 16384-burst window, target >= 5x
@@ -92,7 +93,7 @@ def run(smoke: bool = False) -> list[tuple]:
     rows = [
         ("campaign/points", points, f"{window}-burst window"),
         ("campaign/devices", n_dev,
-         "XLA_FLAGS=--xla_force_host_platform_device_count to widen"),
+         f"{jax.devices()[0].platform} devices in the sweep mesh"),
         ("campaign/seq_pts_per_s", round(points / t_seq, 2),
          "batch_points=1, journaled"),
         ("campaign/mesh_pts_per_s", round(points / t_mesh, 2),
@@ -123,6 +124,9 @@ def run(smoke: bool = False) -> list[tuple]:
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.env import use_compile_cache
+
+    use_compile_cache()
     print("name,value,note")
     for row in run(smoke="--smoke" in sys.argv):
         print(",".join(str(x) for x in row))

@@ -41,49 +41,66 @@ def run(smoke: bool = False) -> list[tuple]:
     return rows
 
 
+def frame_grid() -> tuple:
+    """The whole-frame sim-driven grid: the YOLOv3 op stream, its
+    per-op compressed DBB traces, the paper-anchor (size, block) points
+    (every size x every block: 12) and their LLC geometries."""
+    from repro.core import traces
+    from repro.core.runtime import compile_network
+    from repro.core.soc import SoCConfig, llc_config_for
+
+    stream = compile_network(conv_buf_bytes=SoCConfig().accel.conv_buf_bytes)
+    sizes = sorted({s for s, _ in PAPER_ANCHORS})
+    blocks = sorted({b for _, b in PAPER_ANCHORS})
+    points = [(s, b) for s in sizes for b in blocks]
+    cfgs = [llc_config_for(s, b) for s, b in points]
+    return stream, traces.network_op_segments(stream), points, cfgs
+
+
+def sim_driven_speedups(stream, per_op, points, cfgs, counts) -> dict:
+    """(size, block) -> NVDLA speedup over no LLC, with op_cycles fed
+    by the lane engine's per-segment hit ``counts`` (one row per
+    point, as ``segment_lane_hit_counts`` returns them)."""
+    import dataclasses
+
+    from repro.core.accelerator import _fold_op_stream_rates, accel_time_s
+    from repro.core.soc import SoCConfig
+
+    soc = SoCConfig()
+    base = accel_time_s(
+        stream, acc=soc.accel,
+        mem=dataclasses.replace(soc.mem, llc=None))["seconds"]
+    out = {}
+    for idx, point in enumerate(points):
+        mem = dataclasses.replace(soc.mem, llc=cfgs[idx])
+        hr = _fold_op_stream_rates(per_op, counts[idx])
+        out[point] = base / accel_time_s(stream, acc=soc.accel, mem=mem,
+                                         hit_rates=hr)["seconds"]
+    return out
+
+
 def _sim_driven_rows() -> list[tuple]:
     """Speedups with op_cycles driven by the exact simulator, plus the
     closed-form re-calibration against the same simulated grid — one
     full-frame lane-engine replay feeds both (the per-op fold gives the
     timing model's hit rates, the per-lane sums give the overall rates
     the re-calibration fits)."""
-    import dataclasses
-
     from repro.core import traces
-    from repro.core.accelerator import (
-        _fold_op_stream_rates,
-        accel_time_s,
-        recalibrate_stream_conflict,
-    )
-    from repro.core.runtime import compile_network
-    from repro.core.soc import SoCConfig, llc_config_for
+    from repro.core.accelerator import recalibrate_stream_conflict
     from repro.core.sweep import segment_lane_hit_counts
 
-    soc = SoCConfig()
-    stream = compile_network(conv_buf_bytes=soc.accel.conv_buf_bytes)
-    sizes = sorted({s for s, _ in PAPER_ANCHORS})
-    blocks = sorted({b for _, b in PAPER_ANCHORS})
-    points = [(s, b) for s in sizes for b in blocks]
-    cfgs = [llc_config_for(s, b) for s, b in points]
-    per_op = traces.network_op_segments(stream)
+    stream, per_op, points, cfgs = frame_grid()
     flat = [seg for segs in per_op for seg in segs]
     counts = segment_lane_hit_counts(flat, cfgs)   # the one grid replay
     total = traces.total_bursts(flat)
-    base = accel_time_s(
-        stream, acc=soc.accel,
-        mem=dataclasses.replace(soc.mem, llc=None))["seconds"]
+    speedups = sim_driven_speedups(stream, per_op, points, cfgs, counts)
     rows = []
     for size, block in sorted(points):
-        idx = points.index((size, block))
-        mem = dataclasses.replace(soc.mem, llc=cfgs[idx])
-        hr = _fold_op_stream_rates(per_op, counts[idx])
-        t = accel_time_s(stream, acc=soc.accel, mem=mem,
-                         hit_rates=hr)["seconds"]
         paper = PAPER_ANCHORS.get((size, block))
         note = ("sim-driven op_cycles, full frame" +
                 (f", paper: {paper}" if paper else ""))
         rows.append((f"fig5/simdrv_{size}KiB_{block}B",
-                     round(base / t, 3), note))
+                     round(speedups[(size, block)], 3), note))
     sim_rates = {points[i]: float(counts[i].sum()) / total
                  for i in range(len(points))}
     cal = recalibrate_stream_conflict(sim_rates)

@@ -4,7 +4,7 @@
 Pallas interpret mode is a correctness harness, not a performance one —
 wall-clock timing happens on the jnp reference path (what XLA:CPU makes
 of the same math), while the projected TPU numbers come from the kernels'
-FLOP/byte counts against v5e peaks (197 int8-TOPS/2, 819 GB/s).
+FLOP counts against the v5e peaks of ``repro.launch.mesh.CHIP_PEAKS``.
 """
 from __future__ import annotations
 
@@ -15,10 +15,7 @@ import jax.numpy as jnp
 
 from repro.kernels.convcore.ref import matmul_int8_ref
 from repro.kernels.swa.ref import swa_attention_ref
-
-PEAK_INT8 = 394e12
-PEAK_BF16 = 197e12
-HBM_BW = 819e9
+from repro.launch.mesh import TARGET_KIND, chip_peaks
 
 
 def _time(fn, *args, iters=5):
@@ -30,6 +27,7 @@ def _time(fn, *args, iters=5):
 
 
 def run(smoke: bool = False) -> list[tuple]:
+    peaks = chip_peaks(TARGET_KIND)
     rows = []
     # convcore GEMM: a darknet-53 mid layer as GEMM (52*52 x 1152 x 256)
     m, k, n = (338, 576, 128) if smoke else (2704, 1152, 256)
@@ -43,7 +41,7 @@ def run(smoke: bool = False) -> list[tuple]:
     rows.append(("kernel/convcore_gemm_cpu_us", round(dt * 1e6, 1),
                  f"{flops/dt/1e9:.1f} GOP/s on CPU ref"))
     rows.append(("kernel/convcore_gemm_tpu_projected_us",
-                 round(flops / PEAK_INT8 * 1e6, 2), "v5e int8 roofline"))
+                 round(flops / peaks.int8_ops * 1e6, 2), "v5e int8 roofline"))
 
     # swa attention: one mixtral-ish head block
     bh, s, d, w = (2, 256, 64, 128) if smoke else (8, 1024, 128, 256)
@@ -57,5 +55,5 @@ def run(smoke: bool = False) -> list[tuple]:
     rows.append(("kernel/swa_cpu_us", round(dt * 1e6, 1),
                  f"banded {fl/dt/1e9:.1f} GFLOP/s on CPU ref"))
     rows.append(("kernel/swa_tpu_projected_us",
-                 round(fl / PEAK_BF16 * 1e6, 2), "v5e bf16 roofline"))
+                 round(fl / peaks.bf16_flops * 1e6, 2), "v5e bf16 roofline"))
     return rows

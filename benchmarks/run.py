@@ -17,6 +17,7 @@ import time
 from benchmarks import campaign_bench, fig4_platforms, fig5_llc
 from benchmarks import fig6_interference, fig6_tail, kernel_bench
 from benchmarks import roofline, serve_bench, socsim_bench
+from repro.utils.env import use_compile_cache
 
 SUITES = {
     "fig4": fig4_platforms.run,
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny grids, assert JSON emission (CI)")
     args = ap.parse_args()
+    use_compile_cache()
     suites = {args.only: SUITES[args.only]} if args.only else SUITES
     print("name,value,note")
     status: dict[str, tuple[bool, float, str]] = {}

@@ -322,6 +322,9 @@ def run(smoke: bool = False) -> list[tuple]:
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.env import use_compile_cache
+
+    use_compile_cache()
     print("name,value,note")
     for row in run(smoke="--smoke" in sys.argv):
         print(",".join(str(x) for x in row))

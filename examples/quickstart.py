@@ -17,6 +17,7 @@ from repro.models import init_params
 from repro.serve import Request, ServeEngine
 from repro.train import AdamWConfig, init_train_state, make_train_step
 from repro.types import param_values
+from repro.utils.env import use_compile_cache
 
 
 def paper_experiments():
@@ -70,6 +71,7 @@ def serve_small_lm(cfg, state):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     paper_experiments()
     cfg, state = train_small_lm()
     serve_small_lm(cfg, state)

@@ -21,6 +21,7 @@ from repro.configs import ARCHS, get_smoke_config
 from repro.models import init_params
 from repro.serve import Request, ServeEngine
 from repro.types import param_values
+from repro.utils.env import use_compile_cache
 
 
 def main() -> None:
@@ -34,6 +35,7 @@ def main() -> None:
                     help="arrival gap between requests (simulated µs)")
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     params = param_values(init_params(jax.random.PRNGKey(0), cfg))

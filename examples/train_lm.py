@@ -19,6 +19,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.train.loop import LoopConfig, train
 from repro.train.optim import AdamWConfig
+from repro.utils.env import use_compile_cache
 
 
 def preset_100m() -> ModelConfig:
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--inject-failure", type=int, default=-1,
                     help="raise a simulated node failure at this step")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.preset == "100m":
         cfg = preset_100m()

@@ -2,13 +2,18 @@
 
 Three parts:
 1. the command stream (the accel/CPU split of all 107 layers),
-2. a *numeric* int8 inference of a reduced YOLO stage through the
-   convcore + postproc Pallas kernels (interpret mode) — validating the
-   computation the perf model accounts for,
+2. a *numeric* int8 inference of darknet's first YOLO stage through
+   the compiled convcore + postproc Pallas kernels at the network's
+   416x416 input (64x64 in interpret mode) — validating the computation
+   the perf model accounts for,
 3. the three performance experiments (Figs 4/5/6).
 
-Run:  PYTHONPATH=src python examples/yolov3_soc_sim.py
+Run on a TPU:  PYTHONPATH=src python examples/yolov3_soc_sim.py
+On a CPU the Pallas kernels run only in interpret mode, and slowly:
+PYTHONPATH=src JAX_PLATFORMS=cpu python examples/yolov3_soc_sim.py --interpret
 """
+import argparse
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +30,7 @@ from repro.core.yolov3 import LAYERS, total_gops
 from repro.kernels.convcore import conv2d_int8
 from repro.kernels.convcore.ref import conv2d_int8_ref
 from repro.kernels.postproc import postprocess
+from repro.utils.env import use_compile_cache
 
 
 def show_command_stream():
@@ -41,12 +47,17 @@ def show_command_stream():
           f"{heavy.weight_passes} weight pass(es)")
 
 
-def numeric_int8_stage():
+def numeric_int8_stage(*, interpret: bool = False) -> list[float]:
     """Run darknet's first two conv layers numerically in int8 on the
-    convcore kernel (reduced 64x64 input for CPU interpret mode)."""
+    convcore kernel over the network's 416x416 input, and return each
+    layer's largest kernel-vs-reference error.  ``interpret`` runs the
+    Pallas kernels in interpret mode, the only way they run on a CPU,
+    over a 64x64 input."""
     print("\nnumeric int8 stage (convcore + postproc kernels):")
+    hw = 64 if interpret else 416
+    errs = []
     key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (1, 64, 64, 3), jnp.float32)
+    x = jax.random.normal(key, (1, hw, hw, 3), jnp.float32)
     sx = calibrate(x)
     xq = quantize(x, sx)
     acc = xq
@@ -57,18 +68,20 @@ def numeric_int8_stage():
         scale = sx * sw
         out = conv2d_int8(acc, wq, scale, jnp.zeros((cout,)), stride=stride,
                           padding=1, relu=True, out_dtype=jnp.float32,
-                          interpret=True)
+                          interpret=interpret)
         ref = conv2d_int8_ref(acc, wq, scale, jnp.zeros((cout,)),
                               stride=stride, padding=1, relu=True,
                               out_dtype=jnp.float32)
         err = float(jnp.max(jnp.abs(out - ref)))
+        errs.append(err)
         print(f"  conv{i}: {acc.shape} -> {out.shape}, kernel==ref err {err:.2e}")
         sx = calibrate(out)
         acc = quantize(out, sx)
     pooled = postprocess(out, jnp.ones((out.shape[-1],)),
                          jnp.zeros((out.shape[-1],)), act="none", pool=2,
-                         interpret=True)
+                         interpret=interpret)
     print(f"  postproc 2x2 maxpool: {out.shape} -> {pooled.shape}")
+    return errs
 
 
 def performance_experiments():
@@ -93,7 +106,17 @@ def performance_experiments():
         print(f"    WSS={wss:4s}: {row}")
 
 
-if __name__ == "__main__":
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in interpret mode, at "
+                         "64x64 (CPU)")
+    args = ap.parse_args()
+    use_compile_cache()
     show_command_stream()
-    numeric_int8_stage()
+    numeric_int8_stage(interpret=args.interpret)
     performance_experiments()
+
+
+if __name__ == "__main__":
+    main()

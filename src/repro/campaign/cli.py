@@ -30,6 +30,7 @@ from repro.campaign.faults import (
 )
 from repro.campaign.manifest import JOURNAL_NAME, MANIFEST_NAME, Journal
 from repro.campaign.spec import CampaignSpec, example_spec
+from repro.utils.env import use_compile_cache
 
 EXIT_FAILED_POINTS = 3
 EXIT_INJECTED_CRASH = 42
@@ -132,9 +133,10 @@ def build_parser() -> argparse.ArgumentParser:
     run_p.add_argument("--mesh", nargs="?", const=-1, default=None,
                        type=int, metavar="N",
                        help="shard point batches over a jax device mesh "
-                            "(all visible devices, or the first N; on a "
-                            "CPU host export XLA_FLAGS=--xla_force_host_"
-                            "platform_device_count=K first)")
+                            "(all visible devices, or the first N).  "
+                            "XLA_FLAGS=--xla_force_host_platform_device_"
+                            "count=K fans out K virtual CPU devices only, "
+                            "for rehearsing a mesh without accelerators")
     run_p.add_argument("--batch-points", type=int, default=32,
                        help="max points per batched lane program "
                             "(1 = strictly sequential; default 32)")
@@ -161,6 +163,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     return args.func(args)
 
 

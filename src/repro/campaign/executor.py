@@ -59,7 +59,7 @@ from repro.core.socsim import (
     check_segment_totals,
     check_segment_totals_batch,
 )
-from repro.core.sweep import LaneMetrics
+from repro.core.sweep import LaneMetrics, UnsupportedTraceError
 
 
 class GuardrailViolation(RuntimeError):
@@ -131,8 +131,8 @@ def run_batch(points: list[CampaignPoint], nvdla_segs: list,
     """Execute a batch of points sharing one trace as vmapped lane
     programs, optionally sharded over ``mesh``.  Every returned
     ``LaneMetrics`` is bit-identical to ``run_point`` for that point;
-    raises (e.g. unsupported stride) mean the caller should fall back
-    to the sequential path."""
+    ``UnsupportedTraceError`` (a stride the lane engine cannot replay)
+    means the caller should fall back to the sequential path."""
     from repro.core.sweep import interference_lane_metrics_batch
 
     chunk_bursts = {p.model.chunk_bursts for p in points}
@@ -315,24 +315,26 @@ def _batch_first_attempts(chunk: list[CampaignPoint], nvdla_segs: list,
     """Precompute attempt-0 results for a point chunk as one vmapped
     (optionally mesh-sharded) lane program, pre-validated with the
     batched closed-form check.  Returns None — sequential fallback for
-    the whole chunk — if the batch engine cannot run it (unsupported
-    stride, inconsistent batch); per-point failures are impossible
-    here because faults are injected downstream, in the per-point
-    attempt loop."""
+    the whole chunk — only when the trace is outside the batch engine's
+    support (``UnsupportedTraceError``).  Every other failure (a device
+    compile error or out-of-memory, a broken invariant) propagates and
+    fails the campaign: a silent fallback would hide it as a slow run.
+    Per-point failures are impossible here because faults are injected
+    downstream, in the per-point attempt loop."""
     try:
         results = run_batch(chunk, nvdla_segs, mesh=mesh)
-        check_segment_totals_batch(
-            accesses=[r.accesses for r in results],
-            llc_hits=[r.llc_hits for r in results],
-            dram_row_hits=[r.dram_row_hits for r in results],
-            total_cycles=[r.total_cycles for r in results],
-            drams=[p.dram.dram() for p in chunk],
-            t_llc_hit=results[0].t_llc_hit if results else 20)
-        return results
-    except Exception as e:
+    except UnsupportedTraceError as e:
         note(f"batch of {len(chunk)} points fell back to sequential: "
              f"{type(e).__name__}: {e}")
         return None
+    check_segment_totals_batch(
+        accesses=[r.accesses for r in results],
+        llc_hits=[r.llc_hits for r in results],
+        dram_row_hits=[r.dram_row_hits for r in results],
+        total_cycles=[r.total_cycles for r in results],
+        drams=[p.dram.dram() for p in chunk],
+        t_llc_hit=results[0].t_llc_hit if results else 20)
+    return results
 
 
 def run_campaign(spec: CampaignSpec, out_dir: str, *,

@@ -371,6 +371,13 @@ def _lane_meta_arrays(lanes: list[list]) -> tuple:
     return jnp.asarray(bases), jnp.asarray(strides), jnp.asarray(counts)
 
 
+class UnsupportedTraceError(ValueError):
+    """A lane trace the segment-lane engine cannot replay (a segment
+    stride outside ``(0, block_bytes]``).  The only batch failure the
+    campaign executor answers with the sequential path, which expands
+    such segments exactly."""
+
+
 def _check_lane_support(lanes, configs) -> None:
     int32_max = np.iinfo(np.int32).max
     min_block = min(c.block_bytes for c in configs)
@@ -382,7 +389,7 @@ def _check_lane_support(lanes, configs) -> None:
                 continue
             total += count
             if stride <= 0 or stride > min_block:
-                raise ValueError(
+                raise UnsupportedTraceError(
                     f"segment stride {stride} outside (0, {min_block}] — "
                     "the segment-lane engine needs stride <= block_bytes "
                     "in every lane; use segment_sweep_hit_rates for "
@@ -407,7 +414,7 @@ def _check_lane_support_meta(lanes_meta, configs) -> None:
         live = count > 0
         bad = live & ((stride <= 0) | (stride > min_block))
         if np.any(bad):
-            raise ValueError(
+            raise UnsupportedTraceError(
                 f"segment stride {int(stride[bad][0])} outside "
                 f"(0, {min_block}] — the segment-lane engine needs "
                 "stride <= block_bytes in every lane; use "
@@ -1114,9 +1121,10 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     ``repro.launch.mesh.make_sweep_mesh``) shards the lane axis across
     devices; ``mesh=None`` runs the same program on one device.
 
-    Raises ``ValueError`` if any lane's trace falls outside the segment
-    engine's support (stride > block_bytes) — callers fall back to the
-    sequential path, which expands such segments exactly.
+    Raises ``UnsupportedTraceError`` (a ``ValueError``) if any lane's
+    trace falls outside the segment engine's support (stride >
+    block_bytes) — callers fall back to the sequential path, which
+    expands such segments exactly.
 
     ``way_masks`` is an equal-length sequence of per-lane LLC way
     partitions (``int`` victim masks, or ``None`` for unpartitioned

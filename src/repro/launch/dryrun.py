@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ^ MUST precede every other import (jax locks the device count on first
-# init).  This module is the only place the 512-device placeholder world is
-# created; tests and benchmarks see the real single CPU device.
+# ^ MUST precede every other import (jax locks the device count and the
+# platform on first init).  This module is the only place the 512-device
+# placeholder world is created; tests and benchmarks see the real single
+# CPU device.  It is a CPU-only compile tool: pinning the platform keeps
+# it off an accelerator another process holds.
 # ruff: noqa: E402
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -203,11 +206,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         out["probe"] = {"p1": p1, "p2": p2, "scale": scale}
         out["cost"] = cost
 
+    peaks = mesh_mod.chip_peaks(mesh_mod.TARGET_KIND)
     out["roofline"] = roofline_terms(
         flops=cost["flops"], bytes_accessed=cost["bytes"],
         wire_bytes=cost["wire_bytes"],
-        peak_flops=mesh_mod.PEAK_BF16_FLOPS, hbm_bw=mesh_mod.HBM_BW,
-        link_bw=mesh_mod.ICI_BW)
+        peak_flops=peaks.bf16_flops, hbm_bw=peaks.hbm_bw,
+        link_bw=peaks.ici_bw)
     mf = _model_flops(cfg, shape)
     out["model_flops"] = mf
     hlo_total = cost["flops"] * n_dev

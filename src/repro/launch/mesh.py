@@ -19,6 +19,7 @@ Axis semantics (see repro.sharding for the full rule table):
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -70,10 +71,34 @@ def make_host_mesh(model_parallel: int = 1):
         np.asarray(devices[: dp * mp]).reshape(dp, mp), ("data", "model"))
 
 
-# Hardware constants (TPU v5e-like target; used by roofline, not runtime)
-PEAK_BF16_FLOPS = 197e12          # per chip
-PEAK_INT8_OPS = 394e12            # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link
-HBM_BYTES = 16 * 1024**3          # per chip
-VMEM_BYTES = 128 * 1024**2        # per core, tiling budget
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator model."""
+    bf16_flops: float      # FLOP/s
+    int8_ops: float        # OP/s
+    hbm_bw: float          # bytes/s
+    hbm_bytes: int
+    ici_bw: float          # bytes/s per interconnect link
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM
+# at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4 links.
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, int8_ops=393e12,
+                             hbm_bw=819e9, hbm_bytes=16 * 10**9,
+                             ici_bw=1600e9 / 8 / 4),
+}
+# the chip the production meshes above describe
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a chip missing from ``CHIP_PEAKS``
+    is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                       ) from None

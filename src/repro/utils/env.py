@@ -1,10 +1,13 @@
-"""Runtime environment control: x64 precision and address dtypes.
+"""Runtime environment control: compile cache, x64 precision and
+address dtypes.
 
 JAX disables 64-bit types by default; an ``jnp.asarray(x, jnp.int64)``
 then *silently* truncates to int32.  For DBB byte addresses that is a
 correctness hazard the moment an address crosses 2^31 (an 8 GiB DRAM
-map does).  Two tools:
+map does).  Three tools:
 
+* ``use_compile_cache`` — turn on JAX's persistent compilation cache at
+  a fixed place; every entry point calls it before its first compile;
 * ``jax_enable_x64`` — flip the global precision switch (call it at
   program start, before any array is built; benchmarks and scripts that
   replay full-frame traces should call it);
@@ -19,6 +22,27 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# <checkout>/.jax_cache: src/repro/utils/env.py is three levels below
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is changed here.  Otherwise the cache goes to
+    ``DEFAULT_COMPILE_CACHE``, a fixed directory inside the checkout: a
+    directory that moved from run to run (a tempdir, a pid) would never
+    be hit again.  Call before the first compile; importing this module
+    changes nothing."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE
 
 
 def jax_enable_x64(use_x64: bool = True) -> None:

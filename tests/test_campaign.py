@@ -278,6 +278,40 @@ def test_quarantine_mid_batch_stays_per_point(tmp_path):
     assert "finite" in info["error"]
 
 
+@pytest.mark.parametrize("error,falls_back", [
+    ("unsupported", True),
+    ("device", False),
+    ("value", False),
+], ids=["unsupported_trace", "device_error", "other_value_error"])
+def test_batch_falls_back_only_on_unsupported_trace(tmp_path, monkeypatch,
+                                                    error, falls_back):
+    """Only a trace the lane engine cannot replay sends a batch down the
+    sequential path; a device failure, or any other error from the batch
+    engine, fails the campaign instead of hiding as a slow run."""
+    from repro.campaign import executor
+    from repro.core.sweep import UnsupportedTraceError
+
+    exc = {"unsupported": UnsupportedTraceError("segment stride 96"),
+           "device": RuntimeError("RESOURCE_EXHAUSTED: out of memory"),
+           "value": ValueError("miss-run reconstruction disagrees")}[error]
+
+    def broken(*a, **kw):
+        raise exc
+
+    monkeypatch.setattr(executor, "run_batch", broken)
+    spec = tiny_spec(4)
+    notes: list[str] = []
+    if not falls_back:
+        with pytest.raises(type(exc), match=str(exc)):
+            run_campaign(spec, str(tmp_path / "bat"), progress=notes.append)
+        assert not any("fell back" in n for n in notes)
+        return
+    res = run_campaign(spec, str(tmp_path / "bat"), progress=notes.append)
+    assert sum("fell back to sequential" in n for n in notes) == 1
+    seq = run_campaign(spec, str(tmp_path / "seq"), batch_points=1)
+    assert canon(res.manifest) == canon(seq.manifest)
+
+
 def test_crash_mid_batch_resume_bit_identical_property():
     """Hypothesis: killing the process at a random point inside a
     mesh-sharded batch, then resuming, lands on the sequential run's

@@ -46,6 +46,18 @@ def test_roofline_terms_dominance():
     assert abs(r["roofline_fraction"] - 0.5) < 1e-9
 
 
+def test_chip_peaks_keyed_by_device_kind():
+    import pytest
+
+    from repro.launch.mesh import TARGET_KIND, chip_peaks
+
+    v5e = chip_peaks(TARGET_KIND)
+    assert (v5e.bf16_flops, v5e.int8_ops, v5e.hbm_bw, v5e.hbm_bytes) == (
+        197e12, 393e12, 819e9, 16 * 10**9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")
+
+
 def test_lr_schedule_shape():
     cfg = AdamWConfig(lr=1e-3, warmup_steps=10, decay_steps=100,
                       min_lr_ratio=0.1)

@@ -186,21 +186,25 @@ class TestCompilerProperties:
         laws as NVDLA lanes: hits <= accesses, DRAM row hits <= misses,
         and the accelerator-stream counters are a subset of the lane."""
         pytest.importorskip("hypothesis")
-        from hypothesis import given, settings, strategies as st
+        from hypothesis import example, given, settings, strategies as st
 
-        from repro.core.sweep import interference_lane_metrics
+        from repro.core.dram import DRAMConfig
+        from repro.core.sweep import MixConfig, interference_lane_metrics
 
         @settings(max_examples=30, deadline=None)
         @given(m=st.integers(1, 20), k=st.integers(1, 20),
                n=st.integers(1, 20),
                grid=st.sampled_from([(2, 2), (4, 8), (8, 4)]))
+        @example(m=1, k=1, n=1, grid=(2, 2))
         def prop(m, k, n, grid):
             cfg = npu.NPUConfig(rows=grid[0], cols=grid[1],
                                 ifm_buf_bytes=128, wgt_buf_bytes=128,
                                 acc_buf_bytes=128)
             trace = npu.npu_chunks([npu.GemmOp("p", m, k, n)], cfg,
                                    chunk_bursts=4)
-            res = interference_lane_metrics(trace, llc=LLC_SMALL)
+            res = interference_lane_metrics(
+                trace, llc=LLC_SMALL, dram=DRAMConfig(),
+                mix=MixConfig(corunners=0, wss="l1"))
             assert 0 <= res.llc_hits <= res.accesses
             assert res.dram_row_hits <= res.accesses - res.llc_hits
             assert res.nvdla_accesses == sum(s.count for s in trace)
