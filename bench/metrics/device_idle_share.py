@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device,
+averaged over the cell's chips: 100 * (1 - busy / window)."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or not t.window_s or not t.busy_s:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
