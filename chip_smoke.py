@@ -34,6 +34,7 @@ only when everything passed is the last line of standard output
 from __future__ import annotations
 
 import argparse
+import functools
 import hashlib
 import json
 import os
@@ -72,34 +73,17 @@ def same(a, b) -> bool:
         np.array_equal(a, b))
 
 
-class _CompileLog:
-    """What JAX's monitoring events report about compiles: seconds in
-    backend compiles (loads from the persistent cache included) and
-    persistent-cache hits."""
+@functools.cache
+def _compile_log():
+    """The process's listener of JAX's compile events
+    (``bench.compile_log.CompileLog``), registered on first use."""
+    from bench.compile_log import CompileLog
 
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self._registered = False
-
-    def register(self) -> None:
-        import jax.monitoring as mon
-
-        if not self._registered:
-            mon.register_event_duration_secs_listener(self._on_duration)
-            mon.register_event_listener(self._on_event)
-            self._registered = True
-
-    def _on_duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+    log = CompileLog()
+    log.register()
+    return log
 
 
-COMPILES = _CompileLog()
 # the timing fields every stats dict and phase line carries
 TIMING = ("seconds", "compile_seconds", "persistent_cache_hits")
 
@@ -111,14 +95,14 @@ def _timed(device, fn, *args) -> tuple:
     dict keyed by ``TIMING``."""
     import jax
 
-    COMPILES.register()
-    c0, h0 = COMPILES.seconds, COMPILES.cache_hits
+    log = _compile_log()
+    c0, h0 = log.seconds, log.cache_hits
     t0 = time.perf_counter()
     with jax.default_device(device):
         out = fn(*args)
     return out, dict(zip(TIMING, (time.perf_counter() - t0,
-                                  COMPILES.seconds - c0,
-                                  COMPILES.cache_hits - h0)))
+                                  log.seconds - c0,
+                                  log.cache_hits - h0)))
 
 
 def _timing(stats: dict, ref: dict | None = None) -> dict:

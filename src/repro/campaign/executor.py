@@ -60,6 +60,7 @@ from repro.core.socsim import (
     check_segment_totals_batch,
 )
 from repro.core.sweep import LaneMetrics, UnsupportedTraceError
+from repro.utils import tracing
 
 
 class GuardrailViolation(RuntimeError):
@@ -327,16 +328,18 @@ def _batch_first_attempts(chunk: list[CampaignPoint], nvdla_segs: list,
         note(f"batch of {len(chunk)} points fell back to sequential: "
              f"{type(e).__name__}: {e}")
         return None
-    check_segment_totals_batch(
-        accesses=[r.accesses for r in results],
-        llc_hits=[r.llc_hits for r in results],
-        dram_row_hits=[r.dram_row_hits for r in results],
-        total_cycles=[r.total_cycles for r in results],
-        drams=[p.dram.dram() for p in chunk],
-        t_llc_hit=results[0].t_llc_hit if results else 20)
+    with tracing.span(tracing.RECORD):
+        check_segment_totals_batch(
+            accesses=[r.accesses for r in results],
+            llc_hits=[r.llc_hits for r in results],
+            dram_row_hits=[r.dram_row_hits for r in results],
+            total_cycles=[r.total_cycles for r in results],
+            drams=[p.dram.dram() for p in chunk],
+            t_llc_hit=results[0].t_llc_hit if results else 20)
     return results
 
 
+@tracing.spanned(tracing.CAMPAIGN)
 def run_campaign(spec: CampaignSpec, out_dir: str, *,
                  resume: bool = False, overwrite: bool = False,
                  policy: RetryPolicy | None = None,
@@ -397,8 +400,9 @@ def run_campaign(spec: CampaignSpec, out_dir: str, *,
                 f"{journal.path} already exists; pass resume=True to "
                 "continue it or overwrite=True to discard it")
     if not os.path.exists(journal.path):
-        journal.append({"kind": "spec", "spec": spec.to_dict(),
-                        "spec_hash": spec.spec_hash})
+        with tracing.span(tracing.RECORD):
+            journal.append({"kind": "spec", "spec": spec.to_dict(),
+                            "spec_hash": spec.spec_hash})
 
     resumed = len(completed)
     pending = [p for p in points
@@ -440,15 +444,17 @@ def run_campaign(spec: CampaignSpec, out_dir: str, *,
                     try:
                         result = _attempt(point, attempt, nvdla_segs,
                                           hooks, policy, compute)
-                        validate_result(point, result, families)
+                        with tracing.span(tracing.RECORD):
+                            validate_result(point, result, families)
                     except Exception as e:
                         last_err = e
                         note(f"point {pid} attempt {attempt} failed: "
                              f"{type(e).__name__}: {e}")
                         continue
-                    journal.append({"kind": "point", "point_id": pid,
-                                    "attempt": attempt,
-                                    "result": result.to_record()})
+                    with tracing.span(tracing.RECORD):
+                        journal.append({"kind": "point", "point_id": pid,
+                                        "attempt": attempt,
+                                        "result": result.to_record()})
                     hooks.after_append(point, journal)
                     completed[pid] = result.to_record()
                     _record_family(point, result, families)
@@ -459,17 +465,19 @@ def run_campaign(spec: CampaignSpec, out_dir: str, *,
                     info = {"error":
                             f"{type(last_err).__name__}: {last_err}",
                             "attempts": policy.max_retries + 1}
-                    journal.append({"kind": "failed", "point_id": pid,
-                                    **info})
+                    with tracing.span(tracing.RECORD):
+                        journal.append({"kind": "failed", "point_id": pid,
+                                        **info})
                     hooks.after_append(point, journal)
                     failed[pid] = info
                     note(f"point {pid} quarantined after "
                          f"{info['attempts']} attempts")
 
-    journal.append({"kind": "done",
-                    "completed": len(completed), "failed": len(failed)})
-    manifest = build_manifest(spec, completed, failed)
-    atomic_write_json(manifest_path, manifest)
+    with tracing.span(tracing.RECORD):
+        journal.append({"kind": "done",
+                        "completed": len(completed), "failed": len(failed)})
+        manifest = build_manifest(spec, completed, failed)
+        atomic_write_json(manifest_path, manifest)
     note(f"campaign {spec.name}: {len(completed)}/{len(points)} completed, "
          f"{len(failed)} quarantined -> {manifest_path}")
     return CampaignResult(manifest=manifest, manifest_path=manifest_path,

@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.core import traces
 from repro.core.cache import LLCConfig, _append_block_runs
+from repro.utils import tracing
 from repro.utils.env import as_address_array
 
 
@@ -471,38 +472,49 @@ def segment_lane_hit_counts(segments, configs: list[LLCConfig]
     if per_lane and len(lanes) != len(configs):
         raise ValueError(f"{len(lanes)} lane traces for "
                          f"{len(configs)} configs")
-    _check_lane_support(lanes, configs)
+    with tracing.span(tracing.LANE_PLAN):
+        _check_lane_support(lanes, configs)
     n_seg = max((len(t) for t in lanes), default=0)
     out = np.zeros((len(configs), max(1, n_seg)), np.int64)
     for bucket in lane_buckets(configs):
-        cfgs_b = [configs[i] for i in bucket]
-        sets, ways, blocks, max_sets, max_ways = _geometry_arrays(cfgs_b)
-        engine = _lane_engine(max_sets, max_ways, max_ways, per_lane)
-        if per_lane:
-            traces_b = [lanes[i] for i in bucket]
-            bases, strides, counts = _lane_meta_arrays(traces_b)
-            plans = [_lane_plan(t, cfgs_b) for t in traces_b]
-            s_pad = bases.shape[1]
-            r_needed = np.zeros((len(bucket), s_pad), np.int32)
-            cold = np.zeros((len(bucket), s_pad), bool)
-            for row, (r, c) in enumerate(plans):
-                r_needed[row, :len(r)] = r
-                cold[row, :len(c)] = c
-            r_needed, cold = jnp.asarray(r_needed), jnp.asarray(cold)
-        else:
-            bases, strides, counts = (a[0] for a in
-                                      _lane_meta_arrays(lanes[:1]))
-            r, c = _lane_plan(lanes[0], cfgs_b)
-            s_pad = int(bases.shape[0])          # >= 1 even for [] traces
-            r_pad_arr = np.zeros(s_pad, np.int32)
-            c_pad = np.zeros(s_pad, bool)
-            r_pad_arr[:len(r)] = r
-            c_pad[:len(c)] = c
-            r_needed, cold = jnp.asarray(r_pad_arr), jnp.asarray(c_pad)
-        hits = np.asarray(engine(bases, strides, counts, r_needed, cold,
-                                 sets, ways, blocks), np.int64)
-        for row, i in enumerate(bucket):
-            out[i, :hits.shape[1]] = hits[row]
+        with tracing.span(tracing.LANE_BATCH):
+            with tracing.span(tracing.LANE_PLAN):
+                cfgs_b = [configs[i] for i in bucket]
+                sets, ways, blocks, max_sets, max_ways = _geometry_arrays(
+                    cfgs_b)
+                engine = _lane_engine(max_sets, max_ways, max_ways, per_lane)
+                if per_lane:
+                    traces_b = [lanes[i] for i in bucket]
+                    bases, strides, counts = _lane_meta_arrays(traces_b)
+                    plans = [_lane_plan(t, cfgs_b) for t in traces_b]
+                    s_pad = bases.shape[1]
+                    r_needed = np.zeros((len(bucket), s_pad), np.int32)
+                    cold = np.zeros((len(bucket), s_pad), bool)
+                    for row, (r, c) in enumerate(plans):
+                        r_needed[row, :len(r)] = r
+                        cold[row, :len(c)] = c
+                    rounds = np.minimum(r_needed, max_ways).max(axis=0).sum()
+                else:
+                    bases, strides, counts = (a[0] for a in
+                                              _lane_meta_arrays(lanes[:1]))
+                    r, c = _lane_plan(lanes[0], cfgs_b)
+                    s_pad = int(bases.shape[0])  # >= 1 even for [] traces
+                    r_needed = np.zeros(s_pad, np.int32)
+                    cold = np.zeros(s_pad, bool)
+                    r_needed[:len(r)] = r
+                    cold[:len(c)] = c
+                    rounds = np.minimum(r_needed, max_ways).sum()
+            with tracing.span(tracing.DISPATCH):
+                r_needed, cold = jnp.asarray(r_needed), jnp.asarray(cold)
+                hits_dev = engine(bases, strides, counts, r_needed, cold,
+                                  sets, ways, blocks)
+            tracing.count(tracing.PROGRAMS, 1)
+            tracing.count(tracing.SCAN_ROUNDS, rounds)
+            tracing.count(tracing.FETCH_BYTES, hits_dev.nbytes)
+            with tracing.span(tracing.FETCH):
+                hits = np.asarray(hits_dev, np.int64)
+            for row, i in enumerate(bucket):
+                out[i, :hits.shape[1]] = hits[row]
     return out
 
 
@@ -1140,100 +1152,118 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
             f"way_masks length {len(way_masks)} != lanes {lanes_n}")
     if lanes_n == 0:
         return []
-    chunks = nvdla_chunks(nvdla_segs, chunk_bursts)
-    lanes, nv_masks, lane_sels = [], [], []
-    for i, (llc, dram, mix) in enumerate(zip(llcs, drams, mixes)):
-        _check_row_block(llc, dram)
-        b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
-                                    chunk_bursts=chunk_bursts,
-                                    _chunks=chunks)
-        lanes.append((b, s, c))
-        nv_masks.append(nv)
-        wm = way_masks[i] if way_masks is not None else None
-        lane_sels.append(None if wm is None
-                         else partition_way_sels(nv, llc, wm))
-    masked = way_masks is not None
-    if masked and mesh is not None:
-        raise ValueError("way-masked batches do not support mesh "
-                         "sharding yet — pass mesh=None")
-    _check_lane_support_meta(lanes, llcs)
+    with tracing.span(tracing.LANE_PLAN):
+        chunks = nvdla_chunks(nvdla_segs, chunk_bursts)
+        lanes, nv_masks, lane_sels = [], [], []
+        for i, (llc, dram, mix) in enumerate(zip(llcs, drams, mixes)):
+            _check_row_block(llc, dram)
+            b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
+                                        chunk_bursts=chunk_bursts,
+                                        _chunks=chunks)
+            lanes.append((b, s, c))
+            nv_masks.append(nv)
+            wm = way_masks[i] if way_masks is not None else None
+            lane_sels.append(None if wm is None
+                             else partition_way_sels(nv, llc, wm))
+        masked = way_masks is not None
+        if masked and mesh is not None:
+            raise ValueError("way-masked batches do not support mesh "
+                             "sharding yet — pass mesh=None")
+        _check_lane_support_meta(lanes, llcs)
     out: list[LaneMetrics | None] = [None] * lanes_n
     for bucket in lane_buckets(llcs):
-        cfgs_b = [llcs[i] for i in bucket]
-        metas_b = [lanes[i] for i in bucket]
-        sets, ways, blocks, max_sets, max_ways = _geometry_arrays(cfgs_b)
-        s_pad = max(1, max(m[2].shape[0] for m in metas_b))
-        shape = (len(bucket), s_pad)
-        bases = np.zeros(shape, np.int32)
-        strides = np.ones(shape, np.int32)
-        counts = np.zeros(shape, np.int32)
-        r_needed = np.zeros(shape, np.int32)
-        way_sels = np.zeros(shape, np.int32)
-        suffix = "none"
-        for row, ((b, s, c), cfg) in enumerate(zip(metas_b, cfgs_b)):
-            k = c.shape[0]
-            bases[row, :k], strides[row, :k], counts[row, :k] = b, s, c
-            bb = cfg.block_bytes
-            last = b + np.maximum(c - 1, 0) * s
-            nb = np.where(c > 0, last // bb - b // bb + 1, 0)
-            sel = lane_sels[bucket[row]]
-            if sel is not None:
-                # way-partitioned lane: every segment retires entirely
-                # in the round scan (no analytic suffix for restricted
-                # allocation), so the plan is the full ceil(nb / sets)
-                way_sels[row, :k] = sel
-                r_needed[row, :k] = (-(-nb // cfg.sets)).astype(np.int32)
-                continue
-            # per-lane tight plan: enough rounds to retire the
-            # min(nb, ways*sets)-block prefix; no cold short-circuit
-            # (conservative cold=False is exact either way, and skipping
-            # the host-side interval tracker keeps the plan O(numpy))
-            r_needed[row, :k] = np.minimum(
-                cfg.ways, -(-nb // cfg.sets)).astype(np.int32)
-            overflow = nb - np.minimum(nb, cfg.ways * cfg.sets)
-            if np.any(overflow > cfg.sets):
-                suffix = "full"
-            elif suffix == "none" and np.any(overflow > 0):
-                suffix = "one"
-        cold = np.zeros(shape, bool)
-        # the static round-buffer depth only needs to cover this batch's
-        # actual plan, not max_ways — chunked interference traces need 1
-        r_pad = max(1, int(r_needed.max()))
-        arrays = [jnp.asarray(bases), jnp.asarray(strides),
-                  jnp.asarray(counts), jnp.asarray(r_needed),
-                  jnp.asarray(cold), sets, ways, blocks]
-        if mesh is not None:
-            arrays = _mesh_shard_lanes(arrays, mesh)
-        if masked:
-            # the zero-mask sentinel keeps unpartitioned rows on the
-            # standard plan inside the same compiled program
-            arrays = arrays + [jnp.asarray(way_sels)]
-        engine = _lane_engine(max_sets, max_ways, r_pad, True,
-                              collect=True, suffix=suffix, masked=masked)
-        hits_dev, miss_dev = engine(*arrays)
-        hits = np.asarray(hits_dev, np.int64)
-        miss_bits = np.asarray(miss_dev)
-        for row, i in enumerate(bucket):
-            b, s, c = lanes[i]
-            n_seg = c.shape[0]
-            lane_hits = int(hits[row, :n_seg].sum())
-            runs = _lane_miss_runs(b, s, c, llcs[i], cold[row],
-                                   miss_bits[row],
-                                   full_prefix=lane_sels[i] is not None)
-            accesses = int(c.sum())
-            run_total = int(runs[1].sum())
-            if run_total != accesses - lane_hits:
-                raise RuntimeError(
-                    "lane miss-run reconstruction disagrees with the "
-                    f"kernel: {run_total} missed blocks vs "
-                    f"{accesses - lane_hits} misses (lane {i})")
-            nv = nv_masks[i]
-            out[i] = _lane_metrics_from_runs(
-                n_segments=n_seg, accesses=accesses, hits=lane_hits,
-                runs=runs, bb=llcs[i].block_bytes, nv=nv,
-                dram=drams[i], t_llc_hit=t_llc_hit,
-                nv_acc=int(c[nv].sum()),
-                nv_hits=int(hits[row, :n_seg][nv].sum()))
+        with tracing.span(tracing.LANE_BATCH):
+            with tracing.span(tracing.LANE_PLAN):
+                cfgs_b = [llcs[i] for i in bucket]
+                metas_b = [lanes[i] for i in bucket]
+                sets, ways, blocks, max_sets, max_ways = _geometry_arrays(
+                    cfgs_b)
+                s_pad = max(1, max(m[2].shape[0] for m in metas_b))
+                shape = (len(bucket), s_pad)
+                bases = np.zeros(shape, np.int32)
+                strides = np.ones(shape, np.int32)
+                counts = np.zeros(shape, np.int32)
+                r_needed = np.zeros(shape, np.int32)
+                way_sels = np.zeros(shape, np.int32)
+                suffix = "none"
+                for row, ((b, s, c), cfg) in enumerate(zip(metas_b, cfgs_b)):
+                    k = c.shape[0]
+                    bases[row, :k], strides[row, :k], counts[row, :k] = b, s, c
+                    bb = cfg.block_bytes
+                    last = b + np.maximum(c - 1, 0) * s
+                    nb = np.where(c > 0, last // bb - b // bb + 1, 0)
+                    sel = lane_sels[bucket[row]]
+                    if sel is not None:
+                        # way-partitioned lane: every segment retires
+                        # entirely in the round scan (no analytic suffix
+                        # for restricted allocation), so the plan is the
+                        # full ceil(nb / sets)
+                        way_sels[row, :k] = sel
+                        r_needed[row, :k] = (-(-nb // cfg.sets)).astype(
+                            np.int32)
+                        continue
+                    # per-lane tight plan: enough rounds to retire the
+                    # min(nb, ways*sets)-block prefix; no cold
+                    # short-circuit (conservative cold=False is exact
+                    # either way, and skipping the host-side interval
+                    # tracker keeps the plan O(numpy))
+                    r_needed[row, :k] = np.minimum(
+                        cfg.ways, -(-nb // cfg.sets)).astype(np.int32)
+                    overflow = nb - np.minimum(nb, cfg.ways * cfg.sets)
+                    if np.any(overflow > cfg.sets):
+                        suffix = "full"
+                    elif suffix == "none" and np.any(overflow > 0):
+                        suffix = "one"
+                cold = np.zeros(shape, bool)
+                # the static round-buffer depth only needs to cover this
+                # batch's actual plan, not max_ways — chunked
+                # interference traces need 1
+                r_pad = max(1, int(r_needed.max()))
+                rounds = r_needed.max(axis=0).sum()
+            with tracing.span(tracing.DISPATCH):
+                arrays = [jnp.asarray(bases), jnp.asarray(strides),
+                          jnp.asarray(counts), jnp.asarray(r_needed),
+                          jnp.asarray(cold), sets, ways, blocks]
+                if mesh is not None:
+                    arrays = _mesh_shard_lanes(arrays, mesh)
+                if masked:
+                    # the zero-mask sentinel keeps unpartitioned rows on
+                    # the standard plan inside the same compiled program
+                    arrays = arrays + [jnp.asarray(way_sels)]
+                engine = _lane_engine(max_sets, max_ways, r_pad, True,
+                                      collect=True, suffix=suffix,
+                                      masked=masked)
+                hits_dev, miss_dev = engine(*arrays)
+            tracing.count(tracing.PROGRAMS, 1)
+            tracing.count(tracing.SCAN_ROUNDS, rounds)
+            tracing.count(tracing.FETCH_BYTES,
+                          hits_dev.nbytes + miss_dev.nbytes)
+            with tracing.span(tracing.FETCH):
+                hits = np.asarray(hits_dev, np.int64)
+                miss_bits = np.asarray(miss_dev)
+            for row, i in enumerate(bucket):
+                b, s, c = lanes[i]
+                n_seg = c.shape[0]
+                lane_hits = int(hits[row, :n_seg].sum())
+                with tracing.span(tracing.MISS_RUNS):
+                    runs = _lane_miss_runs(
+                        b, s, c, llcs[i], cold[row], miss_bits[row],
+                        full_prefix=lane_sels[i] is not None)
+                accesses = int(c.sum())
+                run_total = int(runs[1].sum())
+                if run_total != accesses - lane_hits:
+                    raise RuntimeError(
+                        "lane miss-run reconstruction disagrees with the "
+                        f"kernel: {run_total} missed blocks vs "
+                        f"{accesses - lane_hits} misses (lane {i})")
+                nv = nv_masks[i]
+                with tracing.span(tracing.DRAM_ROWS):
+                    out[i] = _lane_metrics_from_runs(
+                        n_segments=n_seg, accesses=accesses, hits=lane_hits,
+                        runs=runs, bb=llcs[i].block_bytes, nv=nv,
+                        dram=drams[i], t_llc_hit=t_llc_hit,
+                        nv_acc=int(c[nv].sum()),
+                        nv_hits=int(hits[row, :n_seg][nv].sum()))
     return out
 
 

@@ -174,13 +174,16 @@ def test_fault_equivalence_all_kinds(tmp_path):
     write ends bit-identical to an uninterrupted campaign."""
     spec = tiny_spec()
     clean = run_campaign(spec, str(tmp_path / "clean"))
+    # every retry runs the sequential single-lane program of its point:
+    # compile them all first, so a timed attempt never waits on a compile
+    run_campaign(spec, str(tmp_path / "sequential"), batch_points=1)
     plan = plan_from_indices(spec, [
         {"point": 0, "kind": "nan"},
         {"point": 1, "kind": "crash"},
-        {"point": 2, "kind": "hang", "hang_s": 0.8},
+        {"point": 2, "kind": "hang", "hang_s": 4.0},
         {"point": 3, "kind": "torn"},
     ])
-    policy = RetryPolicy(max_retries=2, timeout_s=0.25, backoff_s=0.01)
+    policy = RetryPolicy(max_retries=2, timeout_s=1.0, backoff_s=0.01)
     res, runs = _run_until_done(spec, str(tmp_path / "faulted"),
                                 plan, policy)
     assert runs >= 3            # crash and torn each cost one process
