@@ -262,11 +262,48 @@ def _segment_closed_form(state, b_first, n_blocks, a_interior, a_last,
 # --------------------------------------------------------------------------
 # segment-lane engine: geometry as *traced* operands
 # --------------------------------------------------------------------------
+def _shift_down(x, r, n: int):
+    """``y[i] = x[i + r]`` (False past the end of ``x``) for ``i < n``,
+    with a traced ``0 <= r < 2**k`` where ``len(x) <= 2**k``.  A barrel
+    shifter of static slices and selects: the direct form, a per-lane
+    gather, crashed the TPU v5e compiler where it fused into the round
+    loop's scatter."""
+    bits = max(1, (x.shape[0] - 1).bit_length())
+    x = jnp.pad(x, (0, n + (1 << bits) - 1 - x.shape[0]))
+    for j in reversed(range(bits)):
+        step = 1 << j
+        keep = n + step - 1          # what the lower bits can still reach
+        x = jnp.where((r >> j) & 1, x[step:step + keep], x[:keep])
+    return x
+
+
+def _shift_up(x, u, n: int):
+    """``y[i] = x[i - u]`` for ``u <= i < n``, else False; ``x`` holds
+    ``n`` entries and ``u >= 0`` is traced."""
+    u = jnp.minimum(u, n)
+    for j in range(n.bit_length()):
+        step = 1 << j
+        moved = (jnp.pad(x[:n - step], (step, 0)) if step < n
+                 else jnp.zeros_like(x))
+        x = jnp.where((u >> j) & 1, moved, x)
+    return x
+
+
+def _by_ordinal(miss, b_first, sets, width: int):
+    """A round's per-set bits ``miss`` (False at and past ``sets``) by
+    block ordinal: ``y[i] = miss[(b_first + i) % sets]`` for
+    ``i < min(width, sets)``, else False."""
+    r = b_first % sets
+    head = _shift_down(miss, r, width)              # r + i < sets
+    wrap = _shift_up(miss[:width], sets - r, width)  # r + i >= sets
+    return (head | wrap) & (jnp.arange(width) < sets)
+
+
 def segment_lane_scan(bases, strides, counts, r_needed, cold,
                       sets, ways, block_bytes, way_sels=None,
                       *, max_sets: int, max_ways: int, r_pad: int,
-                      collect: bool = False, suffix: str = "full",
-                      return_state: bool = False):
+                      collect: bool = False, collect_width: int | None = None,
+                      suffix: str = "full", return_state: bool = False):
     """One sweep lane's exact segment replay with *runtime* geometry.
 
     ``bases/strides/counts`` are (S,) int32 segment streams (count == 0
@@ -308,12 +345,22 @@ def segment_lane_scan(bases, strides, counts, r_needed, cold,
     running the exact per-access scan at that geometry.
 
     ``collect=True`` (static) additionally returns the round-scan miss
-    bits, (S, r_pad, max_sets) bool: entry (j, k, s) is True iff round k
-    of segment j missed in set s.  Together with the analytically-known
-    suffix (every block past the round-scanned prefix misses), the
-    caller can reconstruct each segment's exact missed-block runs — the
-    compressed currency of the DRAM row model — without per-access
-    expansion (``repro.core.sweep.interference_lane_metrics_batch``).
+    bits by block ordinal, (S, r_pad, W) bool with ``W = collect_width``
+    (static, default ``max_sets``): entry (j, k, i) is True iff the
+    block at ordinal ``k*sets + i`` of segment j missed in the round
+    scan; its set is ``(b_first + i) % sets``, and entries with
+    ``i >= sets`` are False.  Round k retires ordinals ``k*sets`` up to
+    ``min(n_pre, (k+1)*sets)``, so a width of the largest
+    ``min(n_pre, sets)`` of any segment loses no bit; the caller rounds
+    it up to a multiple of 128, the TPU's lane width (at most
+    ``max_sets``: then the layout is the set-indexed one rotated by
+    ``b_first``).  The bits are narrowed inside each round, so no
+    (S, r_pad, max_sets) buffer is ever made.  Together with the
+    analytically-known suffix (every block past the round-scanned prefix
+    misses), the caller can reconstruct each segment's exact
+    missed-block runs — the compressed currency of the DRAM row model —
+    without per-access expansion
+    (``repro.core.sweep.interference_lane_metrics_batch``).
 
     ``suffix`` (static) specializes the closed-form suffix from the
     host plan:
@@ -351,6 +398,7 @@ def segment_lane_scan(bases, strides, counts, r_needed, cold,
     """
     s_idx = jnp.arange(max_sets, dtype=jnp.int32)
     q_idx = jnp.arange(max_ways, dtype=jnp.int32)
+    width = max_sets if collect_width is None else collect_width
     set_mask = s_idx < sets
     way_mask = q_idx < ways
     imax = jnp.iinfo(jnp.int32).max
@@ -411,10 +459,11 @@ def segment_lane_scan(bases, strides, counts, r_needed, cold,
             hits = hits + jnp.sum(jnp.where(v, a - 1 + hit, 0),
                                   dtype=jnp.int32)
             if collect:
-                miss_buf = miss_buf.at[k].set(v & ~hit)
+                miss = _by_ordinal(v & ~hit, b_first, sets, width)
+                miss_buf = miss_buf.at[k].set(miss)
             return (tags, ts, hits, miss_buf)
 
-        miss_init = jnp.zeros((r_pad, max_sets) if collect else (0, 0),
+        miss_init = jnp.zeros((r_pad, width) if collect else (0, 0),
                               jnp.bool_)
         tags, ts, hits, miss_buf = jax.lax.fori_loop(
             0, jnp.minimum(rounds, r_pad), round_k,

@@ -291,7 +291,8 @@ def segment_sweep_hit_rates(segments, configs: list[LLCConfig]
 @functools.lru_cache(maxsize=32)
 def _lane_engine(max_sets: int, max_ways: int, r_pad: int,
                  per_lane_trace: bool, collect: bool = False,
-                 suffix: str = "full", masked: bool = False):
+                 suffix: str = "full", masked: bool = False,
+                 collect_width: int | None = None):
     from repro.core.cache import segment_lane_scan
 
     if masked and not per_lane_trace:
@@ -304,13 +305,14 @@ def _lane_engine(max_sets: int, max_ways: int, r_pad: int,
     return jax.jit(jax.vmap(
         functools.partial(segment_lane_scan, max_sets=max_sets,
                           max_ways=max_ways, r_pad=r_pad, collect=collect,
-                          suffix=suffix),
+                          collect_width=collect_width, suffix=suffix),
         in_axes=in_axes))
 
 
 @functools.lru_cache(maxsize=32)
 def _single_lane_engine(max_sets: int, max_ways: int, r_pad: int,
-                        suffix: str, return_state: bool = False):
+                        suffix: str, return_state: bool = False,
+                        collect_width: int | None = None):
     """One jitted (unvmapped) masked lane — the way-partitioned QoS
     path and the per-request latency attribution both run single
     lanes at exact geometry."""
@@ -318,8 +320,20 @@ def _single_lane_engine(max_sets: int, max_ways: int, r_pad: int,
 
     return jax.jit(functools.partial(
         segment_lane_scan, max_sets=max_sets, max_ways=max_ways,
-        r_pad=r_pad, collect=True, suffix=suffix,
-        return_state=return_state))
+        r_pad=r_pad, collect=True, collect_width=collect_width,
+        suffix=suffix, return_state=return_state))
+
+
+_LANE_WIDTH = 128   # the TPU's lane width: a narrower minor axis pads to it
+
+
+def _collect_width(live_per_round: int, max_sets: int) -> int:
+    """The miss-bit width of a collecting lane program
+    (``segment_lane_scan(collect_width=)``): the most ordinals any
+    segment retires in one round, ``max(min(n_pre, sets))``, rounded up
+    to the lane width, and never past ``max_sets``."""
+    lanes = -(-max(1, int(live_per_round)) // _LANE_WIDTH)
+    return min(max_sets, _LANE_WIDTH * lanes)
 
 
 def _lane_plan(trace: list, configs: list[LLCConfig]
@@ -813,9 +827,11 @@ def _masked_lane_run(b, s, c, llc: LLCConfig, way_sels,
     nb = np.where(live, last // bb - b // bb + 1, 0)
     r_needed = (-(-nb // sets)).astype(np.int32)
     r_pad = max(1, int(r_needed.max(initial=1)))
+    width = _collect_width(np.minimum(nb, sets).max(initial=0), sets)
     cold = np.zeros(b.shape[0], bool)
     engine = _single_lane_engine(sets, ways, r_pad, "none",
-                                 return_state=return_state)
+                                 return_state=return_state,
+                                 collect_width=width)
     out = engine(jnp.asarray(b, jnp.int32), jnp.asarray(s, jnp.int32),
                  jnp.asarray(c, jnp.int32), jnp.asarray(r_needed),
                  jnp.asarray(cold), sets, ways, bb,
@@ -1030,6 +1046,14 @@ def _lane_miss_runs(base, stride, count, llc: LLCConfig, cold: np.ndarray,
     adjacent-run splits *within* a segment, which the closed-form row
     model is invariant to (identical expanded access sequence).
 
+    ``miss_bits`` is the lane's (S, r_pad, W) ordinal layout
+    (``segment_lane_scan(collect=True)``): bit (j, k, i) is the block at
+    ordinal ``k*sets + i`` of segment j, and ``i < sets`` for every set
+    bit, so ``np.nonzero`` yields the missed ordinals already in
+    (segment, ordinal) order.  ``W`` is whatever the program was given
+    (``_collect_width``): any width that covers each round's live
+    ordinals decodes the same.
+
     ``base/stride/count`` are the lane's (n_segments,) metadata arrays;
     returns ``(first_blocks, n_blocks, seg_idx)`` int64 arrays, fully
     vectorized — no per-segment interpreter work.
@@ -1049,11 +1073,9 @@ def _lane_miss_runs(base, stride, count, llc: LLCConfig, cold: np.ndarray,
     else:
         n_pre = np.where(np.asarray(cold[:n_seg], bool), 0,
                          np.minimum(nb, ways * sets))
-    sj, kj, cj = np.nonzero(miss_bits[:n_seg])
-    ordv = ((cj.astype(np.int64) - b_first[sj]) % sets
-            + kj.astype(np.int64) * sets)
-    order = np.lexsort((ordv, sj))
-    sj, ordv = sj[order].astype(np.int64), ordv[order]
+    sj, kj, ij = np.nonzero(miss_bits[:n_seg])
+    sj = sj.astype(np.int64)
+    ordv = kj.astype(np.int64) * sets + ij
     first = np.ones(sj.shape[0], bool)
     if sj.shape[0]:
         first[1:] = (sj[1:] != sj[:-1]) | (ordv[1:] != ordv[:-1] + 1)
@@ -1186,12 +1208,17 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                 r_needed = np.zeros(shape, np.int32)
                 way_sels = np.zeros(shape, np.int32)
                 suffix = "none"
+                live_per_round = 0
                 for row, ((b, s, c), cfg) in enumerate(zip(metas_b, cfgs_b)):
                     k = c.shape[0]
                     bases[row, :k], strides[row, :k], counts[row, :k] = b, s, c
                     bb = cfg.block_bytes
                     last = b + np.maximum(c - 1, 0) * s
                     nb = np.where(c > 0, last // bb - b // bb + 1, 0)
+                    # a round retires at most one block per set
+                    live_per_round = max(
+                        live_per_round,
+                        int(np.minimum(nb, cfg.sets).max(initial=0)))
                     sel = lane_sels[bucket[row]]
                     if sel is not None:
                         # way-partitioned lane: every segment retires
@@ -1220,6 +1247,7 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                 # interference traces need 1
                 r_pad = max(1, int(r_needed.max()))
                 rounds = r_needed.max(axis=0).sum()
+                width = _collect_width(live_per_round, max_sets)
             with tracing.span(tracing.DISPATCH):
                 arrays = [jnp.asarray(bases), jnp.asarray(strides),
                           jnp.asarray(counts), jnp.asarray(r_needed),
@@ -1232,9 +1260,10 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                     arrays = arrays + [jnp.asarray(way_sels)]
                 engine = _lane_engine(max_sets, max_ways, r_pad, True,
                                       collect=True, suffix=suffix,
-                                      masked=masked)
+                                      masked=masked, collect_width=width)
                 hits_dev, miss_dev = engine(*arrays)
             tracing.count(tracing.PROGRAMS, 1)
+            tracing.count(tracing.MISS_WIDTH, width)
             tracing.count(tracing.SCAN_ROUNDS, rounds)
             tracing.count(tracing.FETCH_BYTES,
                           hits_dev.nbytes + miss_dev.nbytes)

@@ -41,6 +41,7 @@ LEAVES = (LANE_PLAN, DISPATCH, FETCH, MISS_RUNS, DRAM_ROWS, RECORD)
 FETCH_BYTES = "sweep.fetch_bytes"  # bytes of lane-program outputs fetched
 SCAN_ROUNDS = "sweep.scan_rounds"  # serial round-scan steps dispatched
 PROGRAMS = "sweep.programs"        # lane programs dispatched
+MISS_WIDTH = "sweep.miss_width"    # miss-bit widths W of collecting programs
 
 _counts: dict[str, int] = {}
 _lock = threading.Lock()

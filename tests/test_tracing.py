@@ -149,18 +149,43 @@ def test_campaign_counters_match_the_shapes(monkeypatch, tmp_path):
     got = _delta(before, tracing.counters())
     assert res.completed == 4
     ((rounds, (hits, miss)),) = dispatched
+    sets = max(p.geometry.llc().sets for p in spec.expand())
     assert got == {tracing.PROGRAMS: 1, tracing.SCAN_ROUNDS: rounds,
-                   tracing.FETCH_BYTES: _fetched(dispatched)}
+                   tracing.FETCH_BYTES: _fetched(dispatched),
+                   tracing.MISS_WIDTH: sets}
     # hits (lanes, segments) int32, miss bits (lanes, segments, rounds,
-    # sets) bool
+    # ordinals) bool, as wide as the 64 sets: below the 128-lane width
     segments = max(len(sweep.corunner_meta(
         p.model.trace(), llc=p.geometry.llc(), mix=p.mix.mix())[2])
         for p in spec.expand())
-    sets = max(p.geometry.llc().sets for p in spec.expand())
     assert hits.shape == (4, segments) and hits.dtype == np.int32
-    assert miss.shape[:2] == (4, segments) and miss.shape[3] == sets
+    assert miss.shape[:2] == (4, segments) and miss.shape[3] == sets == 64
     assert miss.dtype == bool
     assert segments <= rounds <= segments * miss.shape[2]
+
+
+def test_campaign_miss_bits_are_narrowed_past_128_sets(monkeypatch,
+                                                       tmp_path):
+    """On a 512-set LLC a 16-burst chunk spans at most 16 blocks, so each
+    collecting program hands back 128 ordinals a round, not 512 sets."""
+    from repro.campaign import CampaignSpec, GeometrySpec, MixSpec, ModelSpec
+
+    dispatched = _recording_engine(monkeypatch)
+    spec = CampaignSpec(
+        name="wide", models=(ModelSpec(window_bursts=256),),
+        geometries=tuple(GeometrySpec(size_kib=512 * w * 64 / 1024,
+                                      block=64, ways=w) for w in (1, 2)),
+        mixes=(MixSpec(0, "l1"), MixSpec(2, "llc")))
+    before = tracing.counters()
+    res = run_campaign(spec, str(tmp_path))
+    got = _delta(before, tracing.counters())
+    assert res.completed == 4
+    ((_, (hits, miss)),) = dispatched
+    lanes, segments = hits.shape
+    r_pad = miss.shape[2]
+    assert miss.shape == (lanes, segments, r_pad, 128) and lanes == 4
+    assert got[tracing.FETCH_BYTES] == lanes * segments * (4 + r_pad * 128)
+    assert got[tracing.MISS_WIDTH] == 128 * got[tracing.PROGRAMS] == 128
 
 
 @pytest.mark.parametrize("profiled", [False, True])
