@@ -117,23 +117,30 @@ class CampaignResult:
 
 
 def run_point(point: CampaignPoint, nvdla_segs: list) -> LaneMetrics:
-    """Execute one sweep point: the co-runner-interleaved lane through
-    the exact segment LLC engine + closed-form DRAM row model.  Returns
-    the typed ``LaneMetrics`` record."""
+    """Execute one sweep point: the co-runner-interleaved lane as a
+    one-lane batch (``run_batch``), so a whole-frame lane runs compacted
+    like its batch did.  A trace outside the batch engine's support
+    (``UnsupportedTraceError``) goes through the sequential segment
+    engine, which expands such segments exactly.  Returns the typed
+    ``LaneMetrics`` record."""
     from repro.core.sweep import interference_lane_metrics
 
-    return interference_lane_metrics(
-        nvdla_segs, llc=point.geometry.llc(), dram=point.dram.dram(),
-        mix=point.mix.mix(), chunk_bursts=point.model.chunk_bursts)
+    try:
+        return run_batch([point], nvdla_segs)[0]
+    except UnsupportedTraceError:
+        return interference_lane_metrics(
+            nvdla_segs, llc=point.geometry.llc(), dram=point.dram.dram(),
+            mix=point.mix.mix(), chunk_bursts=point.model.chunk_bursts)
 
 
 def run_batch(points: list[CampaignPoint], nvdla_segs: list,
               mesh=None) -> list[LaneMetrics]:
     """Execute a batch of points sharing one trace as vmapped lane
     programs, optionally sharded over ``mesh``.  Every returned
-    ``LaneMetrics`` is bit-identical to ``run_point`` for that point;
+    ``LaneMetrics`` is bit-identical to the sequential segment engine's
+    (``sweep.interference_lane_metrics``) for that point;
     ``UnsupportedTraceError`` (a stride the lane engine cannot replay)
-    means the caller should fall back to the sequential path."""
+    means the caller should fall back to ``run_point``."""
     from repro.core.sweep import interference_lane_metrics_batch
 
     chunk_bursts = {p.model.chunk_bursts for p in points}
@@ -436,8 +443,8 @@ def run_campaign(spec: CampaignSpec, out_dir: str, *,
                         time.sleep(policy.backoff(attempt - 1))
                     hooks.before_point(point, attempt)
                     # attempt 0 reuses the batch result; every retry
-                    # recomputes sequentially so a bad batch lane can
-                    # never poison a point twice
+                    # recomputes the point as a batch of its own, so a
+                    # bad lane of a batch can never poison it twice
                     compute = ((lambda r=batch[idx]: r)
                                if batch is not None and attempt == 0
                                else None)

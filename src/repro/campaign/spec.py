@@ -43,14 +43,16 @@ _BACKEND_MODELS = {
 
 
 @functools.lru_cache(maxsize=8)
-def _model_trace(window_bursts, chunk_bursts, layer_index):
+def _model_trace(window_bursts, chunk_bursts, layer_index, regions):
     from repro.core import traces
 
+    regions = regions or traces.REGIONS
     if window_bursts is None:
-        return traces.network_trace()
+        return traces.network_trace(regions=regions)
     return traces.default_dbb_window(max_bursts=window_bursts,
                                      chunk_bursts=chunk_bursts,
-                                     layer_index=layer_index)
+                                     layer_index=layer_index,
+                                     regions=regions)
 
 
 @functools.lru_cache(maxsize=8)
@@ -90,7 +92,11 @@ class ModelSpec:
     pre-backend ``point_id`` is unchanged) and ``layer_index`` is
     dropped for NPU points (the NPU has no NVDLA layer windows); to
     keep the hash faithful, a field that would be dropped must sit at
-    its default — validated below."""
+    its default — validated below.
+
+    ``regions`` moves the NVDLA's DBB address map: the bases of its
+    weight heap and its two feature-map regions (``None``: the map of
+    ``repro.core.traces``); it hashes only when set."""
     name: str = "yolov3"
     window_bursts: int | None = 4096
     chunk_bursts: int = 16
@@ -98,8 +104,18 @@ class ModelSpec:
     backend: str = "nvdla"
     npu_rows: int = 16
     npu_cols: int = 16
+    regions: tuple[int, int, int] | None = None
 
     def __post_init__(self):
+        if self.regions is not None:
+            object.__setattr__(self, "regions",
+                               tuple(int(r) for r in self.regions))
+            if len(self.regions) != 3 or min(self.regions) < 0:
+                raise ValueError("regions are three byte bases (weight "
+                                 "heap, feature maps A and B), got "
+                                 f"{self.regions}")
+        if self.backend != "nvdla" and self.regions is not None:
+            raise ValueError("regions only apply to backend='nvdla'")
         if self.backend not in _BACKENDS:
             raise ValueError(f"unknown backend {self.backend!r}; campaign "
                              f"backends are: {_BACKENDS}")
@@ -135,10 +151,14 @@ class ModelSpec:
                               self.chunk_bursts, self.npu_rows,
                               self.npu_cols)
         return _model_trace(self.window_bursts, self.chunk_bursts,
-                            self.layer_index)
+                            self.layer_index, self.regions)
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
+        if self.regions is None:
+            del d["regions"]
+        else:
+            d["regions"] = list(self.regions)
         if self.backend == "nvdla":
             # pre-backend hash compatibility: NVDLA dicts are exactly
             # what they were before the backend axis existed

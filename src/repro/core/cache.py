@@ -30,7 +30,9 @@ hit counts (tests/test_traces.py proves parity):
 * **batched multi-geometry scan** (``repro.core.sweep``): (tags, age)
   padded to the largest geometry in a sweep and ``jax.vmap``-ed over
   (sets, ways, block_bytes) so a whole Fig. 5 grid compiles once and
-  runs as a single device program.
+  runs as a single device program (``segment_lane_scan``); an
+  interference lane compacted into records of the arbiter's repeating
+  pattern runs one record per step (``record_lane_scan``).
 
 Used two ways: exactly, on sampled windows of the NVDLA DBB stream (the
 per-stream hit rates feed the accelerator timing model); and as the
@@ -538,6 +540,163 @@ def segment_lane_scan(bases, strides, counts, r_needed, cold,
     if return_state:
         out += ((tags_f, ts_f),)
     return out if len(out) > 1 else out[0]
+
+
+def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
+                     r_needed, sets, ways, block_bytes,
+                     *, max_sets: int, max_ways: int, r_pad: int):
+    """One sweep lane of *compacted records*, exact, with runtime geometry.
+
+    A record is ``R`` repeats of the arbiter's pattern: in every repeat
+    each member ``m`` issues its next ``chunks[m]`` accesses, members in
+    order.  Member ``m`` is one dense stride run over the whole record,
+    ``counts[m]`` accesses from ``bases[m]`` by ``strides[m]``, so its
+    access ``j`` falls at the record-local time
+
+        (j // chunks[m]) * periods + offsets[m] + j % chunks[m]
+
+    (``periods`` = the sum of the chunks, ``offsets[m]`` = the chunks of
+    the members before ``m``; the last repeat may be short).  All inputs
+    are (S, P) int32 per record and member, except ``periods`` and
+    ``r_needed``, (S,); members with ``counts == 0`` are padding, and a
+    record whose member 0 alone is live is a plain segment.
+
+    Per set, a record's block arrivals are the members' arrival
+    sequences merged by the time of each block's first access.  The
+    caller (``repro.core.sweep``) forms records only where that merge is
+    exact for LRU: the members' block ranges are disjoint, and a block
+    whose accesses straddle two repeats sees fewer than ``ways`` other
+    arrivals in its set between its first and last access, so it stays
+    resident and each block is one LRU touch stamped with its last
+    access.  Then, as for one segment (``segment_lane_scan``):
+
+    * the first ``min(ways, arrivals)`` arrivals of every set go through
+      a round scan, one arrival per set per round, ``r_needed`` rounds
+      (at most ``ways``); each round picks, per set, the member whose
+      next arrival comes first;
+    * after ``ways`` arrivals a set holds only blocks of the record, so
+      every later arrival misses, and the set ends holding the ``ways``
+      blocks of the record touched last: the closed-form suffix picks
+      them from the members' last ``ways`` arrivals.
+
+    A way's position never decides anything unmasked lanes can see
+    (stamps are distinct, and never-filled ways are alike), so the
+    suffix fills ways in stamp order.
+
+    Returns the hits per record and member, (S, P) int32, and the
+    round scan's hits: (S, r_pad, max_sets) codes (int8 where
+    ``P * max_ways`` allows, else int16), where code
+    ``m * max_ways + q + 1`` at (record, round, set ``s``) says member
+    ``m``'s arrival number ``q`` in set ``s`` hit, that is the block at
+    ordinal ``(s - bases[m] // block_bytes) % sets + q * sets`` of the
+    member; 0 is a miss or no arrival.  Every block the codes do not
+    name missed on its first access, and every later access to a block
+    hits — from which ``repro.core.sweep`` rebuilds the exact miss runs
+    in the order of the uncompacted trace.
+    """
+    n_mem = bases.shape[-1]
+    code_type = (jnp.int8 if n_mem * max_ways < np.iinfo(np.int8).max
+                 else jnp.int16)
+    s_idx = jnp.arange(max_sets, dtype=jnp.int32)
+    m_idx = jnp.arange(n_mem, dtype=jnp.int32)[:, None]
+    set_mask = (s_idx < sets)[None, :]
+    way_idx = jnp.arange(max_ways, dtype=jnp.int32)
+    way_mask = way_idx < ways
+    imax = jnp.iinfo(jnp.int32).max
+    bb = block_bytes
+
+    def col(x):                            # (P,) -> (P, 1), against sets
+        return x[:, None]
+
+    def per_record(carry, meta):
+        tags, ts, counter = carry          # (max_ways, max_sets) x2, scalar
+        base, stride, count, chunk, offset, period, rounds = meta
+        live = count > 0
+        chunk = jnp.maximum(chunk, 1)
+        b_first = base // bb
+        b_last = (base + (count - 1) * stride) // bb
+        n_blocks = jnp.where(live, b_last - b_first + 1, 0)
+        off = jnp.where(set_mask, (s_idx[None, :] - col(b_first)) % sets, 0)
+        # each member's arrivals in each set
+        per = jnp.where(set_mask & (off < col(n_blocks)),
+                        (col(n_blocks) - off + sets - 1) // sets, 0)
+        total = jnp.sum(per, axis=0)
+
+        def vtime(j):
+            return (j // col(chunk)) * period + col(offset) + j % col(chunk)
+
+        def block_of(q):                   # member ordinal q's block
+            return col(b_first) + off + q * sets
+
+        def round_k(k, inner):
+            tags, ts, hits, ptr, code = inner
+            blk = block_of(ptr)
+            valid = set_mask & (ptr < per)
+            t_lo = jnp.where(valid, vtime(_first_access(
+                blk, col(base), col(stride), bb)), imax)
+            first = jnp.min(t_lo, axis=0)
+            arrive = first < imax
+            pick = valid & (t_lo == first[None, :])   # one member per set
+            t = jnp.sum(jnp.where(pick, blk // sets, 0),
+                        axis=0).astype(jnp.int32)
+            t_hi = jnp.sum(jnp.where(pick, vtime(_last_access(
+                blk, col(base), col(stride), col(count), bb)), 0), axis=0,
+                dtype=jnp.int32)
+            key = jnp.where(tags == t[None, :], -1,
+                            jnp.where(way_mask[:, None], ts, imax))
+            kmin = jnp.min(key, axis=0)
+            hit = (kmin == -1) & arrive
+            is_min = key == kmin[None, :]
+            touched = (jnp.cumsum(is_min, axis=0) == 1) & is_min & arrive
+            tags = jnp.where(touched, t[None, :], tags)
+            ts = jnp.where(touched, (counter + t_hi + 1)[None, :], ts)
+            scored = pick & hit[None, :]
+            hits = hits + jnp.sum(scored, axis=1, dtype=jnp.int32)
+            mark = jnp.sum(jnp.where(scored, m_idx * max_ways + ptr + 1, 0),
+                           axis=0)
+            code = code.at[k].set(mark.astype(code_type))
+            return tags, ts, hits, ptr + pick, code
+
+        tags, ts, hits, _, code = jax.lax.fori_loop(
+            0, jnp.minimum(rounds, r_pad), round_k,
+            (tags, ts, jnp.zeros(n_mem, jnp.int32),
+             jnp.zeros((n_mem, max_sets), jnp.int32),
+             jnp.zeros((r_pad, max_sets), code_type)))
+
+        # closed-form suffix: a set past `ways` arrivals ends holding the
+        # record's `ways` blocks touched last, each member's latest first
+        full = set_mask[0] & (total > ways)
+
+        def newest(w, inner):
+            tags, ts, taken = inner
+            q = per - 1 - taken
+            valid = set_mask & (q >= 0)
+            blk = block_of(q)
+            t_hi = jnp.where(valid, vtime(_last_access(
+                blk, col(base), col(stride), col(count), bb)), -1)
+            last = jnp.max(t_hi, axis=0)
+            pick = valid & (t_hi == last[None, :])
+            t = jnp.sum(jnp.where(pick, blk // sets, 0), axis=0)
+            write = (way_idx == w)[:, None] & full[None, :]
+            tags = jnp.where(write, t.astype(jnp.int32)[None, :], tags)
+            ts = jnp.where(write, (counter + last + 1)[None, :], ts)
+            return tags, ts, taken + pick
+
+        tags, ts, _ = jax.lax.fori_loop(
+            0, ways, newest,
+            (tags, ts, jnp.zeros((n_mem, max_sets), jnp.int32)))
+        # every access after a block's first is a hit
+        hits = hits + jnp.where(live, count - n_blocks, 0)
+        repeats = jnp.max(jnp.where(live, (count + chunk - 1) // chunk, 0))
+        return (tags, ts, counter + repeats * period), (hits, code)
+
+    init = (jnp.full((max_ways, max_sets), -1, jnp.int32),
+            jnp.zeros((max_ways, max_sets), jnp.int32),
+            jnp.int32(0))
+    _, (hits, codes) = jax.lax.scan(
+        per_record, init, (bases, strides, counts, chunks, offsets,
+                           periods, r_needed))
+    return hits, codes
 
 
 @dataclasses.dataclass

@@ -11,7 +11,8 @@ or, for stride-run segment streams (the compressed DBB traces of
 ``repro.core.traces`` and the LLC miss runs the segment engine emits),
 computed in closed form by ``segment_row_hits``: rows touched per
 segment, per-bank open-row carry across segment boundaries, bit
--identical to the per-access scan with O(segments * banks) work.
+-identical to the per-access scan with O(bank visits) work, min(rows,
+banks) per segment.
 FR-FCFS's *scheduling* effect (row hits served first under load) and
 inter-master contention are modeled at the queue level in
 ``repro.core.interference`` — this module is the deterministic service
@@ -99,11 +100,15 @@ def _bank_first_last_rows(r0: int, r1: int, banks: int):
 def _row_hits_bulk(base: np.ndarray, stride: np.ndarray, count: np.ndarray,
                    banks: int, rb: int, rows_state: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray]:
-    """Vectorized carry chain for stride <= row_bytes segments: the
-    per-bank open-row state a segment observes is the ``last`` row of
-    the most recent earlier segment that visited the bank (exclusive
-    running maximum over visit indices), so the whole serial loop
-    collapses to O(segments * banks) numpy with no Python per segment.
+    """Vectorized carry chain for stride <= row_bytes segments.  A
+    segment sweeps the row run [r0, r1]; it visits each bank at most
+    once per ``banks`` rows, so its carry-in hits are decided by its
+    *bank visits* — per bank its first and last row — alone: the first
+    row hits when the bank's previous visit, by an earlier segment,
+    left that row open.  Sorting the visits by bank (stably, so each
+    bank keeps segment order) puts every visit next to the one before
+    it, so the whole serial loop is O(visits) numpy with no Python per
+    segment, and a visit count of min(rows, banks) per segment.
     Returns (per_segment row hits, final open rows) — bit-identical to
     the scalar loop."""
     n = base.shape[0]
@@ -112,23 +117,26 @@ def _row_hits_bulk(base: np.ndarray, stride: np.ndarray, count: np.ndarray,
     live = count > 0
     r0 = base // rb
     r1 = (base + np.maximum(count - 1, 0) * stride) // rb
-    b = np.arange(banks, dtype=np.int64)[None, :]
-    first = r0[:, None] + ((b - r0[:, None]) % banks)
-    last = r1[:, None] - ((r1[:, None] - b) % banks)
-    visited = (first <= r1[:, None]) & live[:, None]
-    idx = np.where(visited, np.arange(n, dtype=np.int64)[:, None], -1)
-    latest = np.maximum.accumulate(idx, axis=0)
-    prev = np.vstack([np.full((1, banks), -1, np.int64), latest[:-1]])
-    prev_last = np.where(
-        prev >= 0,
-        np.take_along_axis(last, np.maximum(prev, 0), axis=0),
-        rows_state[None, :banks])
-    carry = (visited & (prev_last == first)).sum(axis=1)
+    visits = np.where(live, np.minimum(r1 - r0 + 1, banks), 0)
+    seg = np.repeat(np.arange(n, dtype=np.int64), visits)
+    first = (r0[seg] + np.arange(seg.shape[0], dtype=np.int64)
+             - np.repeat(np.cumsum(visits) - visits, visits))
+    last = r1[seg] - ((r1[seg] - first) % banks)
+    bank = first % banks
+    order = np.argsort(bank.astype(np.int16 if banks < 2 ** 15
+                                   else np.int64), kind="stable")
+    b, f, lst = bank[order], first[order], last[order]
+    opens = np.empty_like(b, dtype=bool)
+    opens[:1] = True
+    opens[1:] = b[1:] != b[:-1]         # the bank's first visit
+    prev = np.empty_like(lst)
+    prev[1:] = lst[:-1]
+    prev[opens] = rows_state[b[opens]]
+    carry = np.bincount(seg[order[prev == f]], minlength=n)
     per_seg = np.where(live, count - (r1 - r0 + 1) + carry, 0)
-    final = np.where(
-        latest[-1] >= 0,
-        np.take_along_axis(last, np.maximum(latest[-1:], 0), axis=0)[0],
-        rows_state[:banks])
+    final = rows_state[:banks].copy()
+    closes = np.append(b[1:] != b[:-1], True)[:b.shape[0]]
+    final[b[closes]] = lst[closes]
     return per_seg.astype(np.int64), final.astype(np.int64)
 
 
@@ -138,7 +146,8 @@ def segment_row_hits(segments, cfg: DRAMConfig,
 
     Bit-identical to replaying the expanded trace through
     ``access_latencies`` (tests/test_dram_segments.py, with Hypothesis),
-    with serial work O(segments * banks) instead of O(accesses):
+    with work O(bank visits), min(rows, banks) per segment, instead of
+    O(accesses):
 
     * a segment with stride <= row_bytes sweeps the contiguous row run
       [base//row_bytes, last//row_bytes]; every row is visited once,

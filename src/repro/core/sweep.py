@@ -39,6 +39,9 @@ Public API:
                             executor's data-parallel path) and
                             optionally per-lane way-partitioned
                             (``way_masks=``);
+* ``compact_lane``        — one interleaved lane as ``LaneRecords``:
+                            repeats of the arbiter's pattern, each run
+                            in one step of ``cache.record_lane_scan``;
 * ``partition_way_sels``  — victim/co-runner allocation masks for an
                             Intel-CAT-style two-class way partition;
 * ``lane_request_latencies`` — per-victim-chunk memory latencies (the
@@ -48,7 +51,8 @@ Public API:
                             frame;
 * ``sweep_interference``  — Fig. 6 grid: closed-form slowdowns + exact
                             segment-lane hit rates and closed-form DRAM
-                            row-hit rates under BwWrite co-runners.
+                            row-hit rates under BwWrite co-runners,
+                            windowed or full frame.
 
 The expanded-trace per-access lanes (``batched_hits`` /
 ``batched_hits_per_trace``) are deprecated: they serialize on burst
@@ -56,6 +60,7 @@ count and exist only as a parity oracle for the segment-lane engine.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
 import warnings
@@ -525,6 +530,9 @@ def segment_lane_hit_counts(segments, configs: list[LLCConfig]
             tracing.count(tracing.PROGRAMS, 1)
             tracing.count(tracing.SCAN_ROUNDS, rounds)
             tracing.count(tracing.FETCH_BYTES, hits_dev.nbytes)
+            scanned = sum(len(lanes[i]) for i in bucket)   # nothing compacts
+            tracing.count(tracing.LANE_SEGMENTS, scanned)
+            tracing.count(tracing.LANE_SEGMENTS_RAW, scanned)
             with tracing.span(tracing.FETCH):
                 hits = np.asarray(hits_dev, np.int64)
             for row, i in enumerate(bucket):
@@ -742,6 +750,122 @@ def corunner_meta(nvdla_segs: list, *, llc: LLCConfig, mix: MixConfig,
                          for p in parts])
     order = np.lexsort((slots, chunks))   # chunk-major, arbiter slots
     return bases[order], strides[order], counts[order], nv[order]
+
+
+@dataclasses.dataclass(frozen=True)
+class LaneRecords:
+    """One lane compacted into records (``cache.record_lane_scan``):
+    per record and member (S, P) int64 ``bases``, ``strides``,
+    ``counts`` (the member's accesses in the record), ``chunks`` (its
+    accesses per repeat) and ``offsets`` (the chunks of the members
+    before it); per record (S,) ``periods`` (accesses per repeat) and
+    ``raw`` (the uncompacted segments it stands for); ``nv`` (S, P)
+    marks NVDLA members.  Live members come first in every record."""
+    bases: np.ndarray
+    strides: np.ndarray
+    counts: np.ndarray
+    chunks: np.ndarray
+    offsets: np.ndarray
+    periods: np.ndarray
+    raw: np.ndarray
+    nv: np.ndarray
+
+    @property
+    def members(self) -> int:
+        """Members of the widest record: 1 when every record is one
+        plain segment."""
+        live = (self.counts > 0).sum(axis=1)
+        return max(1, int(live.max(initial=1)))
+
+
+def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
+    """Compact one interleaved lane (``corunner_meta``'s arrays) into
+    records: each maximal run of two or more arbiter rounds in which
+    every member — the NVDLA chunk, then each co-runner's — continues
+    its own stride run with the same chunk becomes one record (the last
+    round may be shorter).  A run breaks where an NVDLA segment ends, a
+    co-runner wraps in its working set (its round has more pieces) or
+    chunk sizes change.  Every other segment stays a record of its own.
+
+    A run of several members is kept only where the record engine's
+    merge is exact at ``llc``: the members' block ranges are disjoint,
+    each chunk spans at least one block, and a block a member's chunks
+    share sees fewer than ``ways`` arrivals of the other members in its
+    set in between (at most one chunk of each).  The records replay
+    exactly the lane's access order.  ``members`` is the lane's segments
+    per round without a wrap: 1 + its co-runners."""
+    b, s, c = (np.asarray(a, np.int64) for a in (b, s, c))
+    nv = np.asarray(nv, bool)
+    n_seg = c.shape[0]
+    starts = np.flatnonzero(nv)          # every round opens with the NVDLA
+    if n_seg == 0 or starts.size == 0 or starts[0] != 0:
+        raise ValueError("a lane is a sequence of arbiter rounds, each "
+                         "opened by one NVDLA chunk")
+    size = np.diff(np.append(starts, n_seg))
+    p = members
+    normal = size == p
+    at = np.minimum(starts[:, None] + np.arange(p)[None, :], n_seg - 1)
+    B, St, Cn = b[at], s[at], c[at]
+    cont = (normal[:-1] & normal[1:] & (St[1:] == St[:-1]).all(1)
+            & (B[1:] == B[:-1] + Cn[:-1] * St[:-1]).all(1))
+    eq = cont & (Cn[1:] == Cn[:-1]).all(1)
+    tail = cont & (Cn[1:] < Cn[:-1]).all(1) & ~np.append(eq[1:], False)
+    link = eq | tail
+    link[1:] &= ~tail[:-1]
+    edge = np.diff(np.concatenate([[0], link.astype(np.int8), [0]]))
+    first, last = np.flatnonzero(edge == 1), np.flatnonzero(edge == -1)
+    if p > 1 and first.size:
+        first, last = _exact_runs(first, last, B, St, Cn, llc)
+    covered = np.zeros(starts.shape[0] + 1, np.int64)
+    np.add.at(covered, first, 1)
+    np.add.at(covered, last + 1, -1)
+    in_run = np.repeat(np.cumsum(covered[:-1]) > 0, size)
+    # the runs: members from their first round, accesses summed
+    cum = np.vstack([np.zeros((1, p), np.int64), np.cumsum(Cn, axis=0)])
+    r_count = cum[last + 1] - cum[first]
+    r_chunk = Cn[first] if p > 1 else r_count
+    plain = np.flatnonzero(~in_run)
+    z = np.zeros((plain.size, p), np.int64)
+    one = np.zeros((plain.size, p), np.int64)
+    one[:, 0] = 1
+
+    def stack(run_part, seg_part):
+        return np.concatenate([run_part, seg_part])
+
+    order = np.argsort(stack(starts[first], plain), kind="stable")
+    counts = stack(r_count, z + one * c[plain, None])
+    chunks = stack(r_chunk, z + one * c[plain, None])
+    return LaneRecords(
+        bases=stack(B[first], z + one * b[plain, None])[order],
+        strides=stack(St[first], np.where(one, s[plain, None], 1))[order],
+        counts=counts[order],
+        chunks=chunks[order],
+        offsets=(np.cumsum(chunks, axis=1) - chunks)[order],
+        periods=chunks.sum(axis=1)[order],
+        raw=stack((last - first + 1) * p, np.ones(plain.size, np.int64)
+                  )[order],
+        nv=stack(np.arange(p)[None, :].repeat(first.size, 0) == 0,
+                 (one > 0) & nv[plain, None])[order])
+
+
+def _exact_runs(first, last, B, St, Cn, llc: LLCConfig):
+    """The runs of several members the record engine replays exactly at
+    ``llc`` (``compact_lane``)."""
+    bb, sets, ways = llc.block_bytes, llc.sets, llc.ways
+    width = Cn[first] * St[first]                     # bytes per chunk
+    lo = B[first] // bb
+    hi = (B[last] + (Cn[last] - 1) * St[last]) // bb
+    ok = (width >= bb).all(1)
+    p = B.shape[1]
+    for m in range(p):
+        for m2 in range(m + 1, p):
+            ok &= (hi[:, m] < lo[:, m2]) | (hi[:, m2] < lo[:, m])
+    # a shared boundary block waits out one chunk of every other member
+    arrivals = -(-(-(-width // bb) + 1) // sets)
+    shares = ((B[first] % bb) != 0) | ((width % bb) != 0)
+    others = arrivals.sum(1, keepdims=True) - arrivals
+    ok &= ~(shares & (others >= ways)).any(1)
+    return first[ok], last[ok]
 
 
 def _lane_metrics_from_runs(*, n_segments, accesses, hits, runs, bb, nv,
@@ -1102,33 +1226,24 @@ def _lane_miss_runs(base, stride, count, llc: LLCConfig, cold: np.ndarray,
     return b_first[run_seg] + run_ord, run_len.astype(np.int64), run_seg
 
 
-def _mesh_shard_lanes(arrays, mesh):
-    """Pad the lane axis to a multiple of the mesh size with count-0
-    no-op lanes (geometry repeated so traced scalars stay in range) and
+def _mesh_shard_lanes(arrays, mesh, zero=(2, 3, 4)):
+    """Pad the lane axis to a multiple of the mesh size with no-op lanes
+    (geometry and metadata repeated so traced scalars stay in range, the
+    arrays at positions ``zero`` — counts and round plans — zeroed) and
     place every operand lane-sharded, so the jitted vmap runs one lane
     shard per device (computation follows data)."""
     from jax.sharding import NamedSharding, PartitionSpec
 
-    bases, strides, counts, r_needed, cold, sets, ways, blocks = (
-        np.asarray(a) for a in arrays)
+    arrays = [np.asarray(a) for a in arrays]
     n_dev = int(np.prod(list(mesh.shape.values())))
-    pad = (-bases.shape[0]) % n_dev
-
-    def rep(a):
-        return np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
-
-    def zero(a):
-        return np.concatenate(
-            [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
-
+    pad = (-arrays[0].shape[0]) % n_dev
     if pad:
-        bases, strides = rep(bases), rep(strides)
-        counts, r_needed, cold = zero(counts), zero(r_needed), zero(cold)
-        sets, ways, blocks = rep(sets), rep(ways), rep(blocks)
+        arrays = [np.concatenate(
+            [a, np.zeros((pad,) + a.shape[1:], a.dtype) if k in zero
+             else np.repeat(a[:1], pad, axis=0)])
+            for k, a in enumerate(arrays)]
     sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
-    return [jax.device_put(a, sharding)
-            for a in (bases, strides, counts, r_needed, cold,
-                      sets, ways, blocks)]
+    return [jax.device_put(a, sharding) for a in arrays]
 
 
 def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
@@ -1150,6 +1265,14 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     ``LaneMetrics`` is bit-identical to
     ``interference_lane_metrics`` for that lane — the executor
     journals batch results interchangeably with sequential ones.
+
+    Each unmasked lane is compacted first (``compact_lane``): where the
+    records shorten a bucket's padded scan by more than their members
+    cost (``_records_that_pay``), the bucket runs as one
+    ``record_lane_scan`` program instead, and its miss runs are decoded
+    in the uncompacted trace's order (``_record_miss_runs``) — the same
+    metrics, bit for bit.  The whole frame's lanes take it; the Fig. 6
+    windows, whose NVDLA chunks never continue one another, do not.
 
     ``mesh`` (a 1-D ``jax.sharding.Mesh``, see
     ``repro.launch.mesh.make_sweep_mesh``) shards the lane axis across
@@ -1192,127 +1315,325 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
             raise ValueError("way-masked batches do not support mesh "
                              "sharding yet — pass mesh=None")
         _check_lane_support_meta(lanes, llcs)
+    records = [None] * lanes_n
+    with tracing.span(tracing.COMPACT):
+        # way-masked lanes replay every segment in the round scan, so
+        # only unmasked batches compact; a record folds repeats of an
+        # NVDLA chunk that continues the one before, so a trace with
+        # none (the Fig. 6 windows) has nothing to fold
+        if not masked and _chunks_continue(chunks):
+            records = [compact_lane(*lanes[i], nv_masks[i], llcs[i],
+                                    1 + (0 if m.wss == "l1" else m.corunners))
+                       for i, m in enumerate(mixes)]
     out: list[LaneMetrics | None] = [None] * lanes_n
     for bucket in lane_buckets(llcs):
         with tracing.span(tracing.LANE_BATCH):
             with tracing.span(tracing.LANE_PLAN):
                 cfgs_b = [llcs[i] for i in bucket]
-                metas_b = [lanes[i] for i in bucket]
-                sets, ways, blocks, max_sets, max_ways = _geometry_arrays(
-                    cfgs_b)
-                s_pad = max(1, max(m[2].shape[0] for m in metas_b))
-                shape = (len(bucket), s_pad)
-                bases = np.zeros(shape, np.int32)
-                strides = np.ones(shape, np.int32)
-                counts = np.zeros(shape, np.int32)
-                r_needed = np.zeros(shape, np.int32)
-                way_sels = np.zeros(shape, np.int32)
-                suffix = "none"
-                live_per_round = 0
-                for row, ((b, s, c), cfg) in enumerate(zip(metas_b, cfgs_b)):
-                    k = c.shape[0]
-                    bases[row, :k], strides[row, :k], counts[row, :k] = b, s, c
-                    bb = cfg.block_bytes
-                    last = b + np.maximum(c - 1, 0) * s
-                    nb = np.where(c > 0, last // bb - b // bb + 1, 0)
-                    # a round retires at most one block per set
-                    live_per_round = max(
-                        live_per_round,
-                        int(np.minimum(nb, cfg.sets).max(initial=0)))
-                    sel = lane_sels[bucket[row]]
-                    if sel is not None:
-                        # way-partitioned lane: every segment retires
-                        # entirely in the round scan (no analytic suffix
-                        # for restricted allocation), so the plan is the
-                        # full ceil(nb / sets)
-                        way_sels[row, :k] = sel
-                        r_needed[row, :k] = (-(-nb // cfg.sets)).astype(
-                            np.int32)
-                        continue
-                    # per-lane tight plan: enough rounds to retire the
-                    # min(nb, ways*sets)-block prefix; no cold
-                    # short-circuit (conservative cold=False is exact
-                    # either way, and skipping the host-side interval
-                    # tracker keeps the plan O(numpy))
-                    r_needed[row, :k] = np.minimum(
-                        cfg.ways, -(-nb // cfg.sets)).astype(np.int32)
-                    overflow = nb - np.minimum(nb, cfg.ways * cfg.sets)
-                    if np.any(overflow > cfg.sets):
-                        suffix = "full"
-                    elif suffix == "none" and np.any(overflow > 0):
-                        suffix = "one"
-                cold = np.zeros(shape, bool)
-                # the static round-buffer depth only needs to cover this
-                # batch's actual plan, not max_ways — chunked
-                # interference traces need 1
-                r_pad = max(1, int(r_needed.max()))
-                rounds = r_needed.max(axis=0).sum()
-                width = _collect_width(live_per_round, max_sets)
-            with tracing.span(tracing.DISPATCH):
-                arrays = [jnp.asarray(bases), jnp.asarray(strides),
-                          jnp.asarray(counts), jnp.asarray(r_needed),
-                          jnp.asarray(cold), sets, ways, blocks]
-                if mesh is not None:
-                    arrays = _mesh_shard_lanes(arrays, mesh)
-                if masked:
-                    # the zero-mask sentinel keeps unpartitioned rows on
-                    # the standard plan inside the same compiled program
-                    arrays = arrays + [jnp.asarray(way_sels)]
-                engine = _lane_engine(max_sets, max_ways, r_pad, True,
-                                      collect=True, suffix=suffix,
-                                      masked=masked, collect_width=width)
-                hits_dev, miss_dev = engine(*arrays)
-            tracing.count(tracing.PROGRAMS, 1)
-            tracing.count(tracing.MISS_WIDTH, width)
-            tracing.count(tracing.SCAN_ROUNDS, rounds)
-            tracing.count(tracing.FETCH_BYTES,
-                          hits_dev.nbytes + miss_dev.nbytes)
-            with tracing.span(tracing.FETCH):
-                hits = np.asarray(hits_dev, np.int64)
-                miss_bits = np.asarray(miss_dev)
-            for row, i in enumerate(bucket):
-                b, s, c = lanes[i]
-                n_seg = c.shape[0]
-                lane_hits = int(hits[row, :n_seg].sum())
-                with tracing.span(tracing.MISS_RUNS):
-                    runs = _lane_miss_runs(
-                        b, s, c, llcs[i], cold[row], miss_bits[row],
-                        full_prefix=lane_sels[i] is not None)
-                accesses = int(c.sum())
-                run_total = int(runs[1].sum())
-                if run_total != accesses - lane_hits:
-                    raise RuntimeError(
-                        "lane miss-run reconstruction disagrees with the "
-                        f"kernel: {run_total} missed blocks vs "
-                        f"{accesses - lane_hits} misses (lane {i})")
-                nv = nv_masks[i]
-                with tracing.span(tracing.DRAM_ROWS):
-                    out[i] = _lane_metrics_from_runs(
-                        n_segments=n_seg, accesses=accesses, hits=lane_hits,
-                        runs=runs, bb=llcs[i].block_bytes, nv=nv,
-                        dram=drams[i], t_llc_hit=t_llc_hit,
-                        nv_acc=int(c[nv].sum()),
-                        nv_hits=int(hits[row, :n_seg][nv].sum()))
+                recs = _records_that_pay([records[i] for i in bucket],
+                                         [lanes[i] for i in bucket])
+                raw = [lanes[i][2].shape[0] for i in bucket]
+                tracing.count(tracing.LANE_SEGMENTS_RAW, sum(raw))
+                tracing.count(tracing.LANE_SEGMENTS, sum(
+                    raw if recs is None else [r.raw.shape[0] for r in recs]))
+                if recs is not None and max(r.members for r in recs) > 1:
+                    run = _record_program(recs, cfgs_b)
+                else:
+                    views = ([(*lanes[i], nv_masks[i]) for i in bucket]
+                             if recs is None else
+                             # one member each: plain segments
+                             [(r.bases[:, 0], r.strides[:, 0],
+                               r.counts[:, 0], r.nv[:, 0]) for r in recs])
+                    run = _segment_program(views, cfgs_b,
+                                           [lane_sels[i] for i in bucket],
+                                           masked)
+            got = run([drams[i] for i in bucket], t_llc_hit, mesh)
+        for i, n, m in zip(bucket, raw, got):
+            out[i] = dataclasses.replace(m, segments=n)
     return out
 
 
+def _chunks_continue(chunks) -> bool:
+    """Whether any of the NVDLA's arbiter chunks (``nvdla_chunks``)
+    continues the stride run of the chunk before it."""
+    b, s, c = chunks
+    return bool(np.any((s[1:] == s[:-1])
+                       & (b[1:] == b[:-1] + c[:-1] * s[:-1])))
+
+
+def _records_that_pay(recs: list, lanes: list) -> list | None:
+    """A bucket's records where they pay, else None.  A record of P
+    members costs up to P times a segment's step in the round scan, so
+    compaction must shorten the padded scan by more than that."""
+    if recs[0] is None:
+        return None
+    members = max(r.members for r in recs)
+    longest = max(r.raw.shape[0] for r in recs)
+    if longest * members >= max(c.shape[0] for _, _, c in lanes):
+        return None
+    return recs
+
+
+def _segment_program(views, cfgs_b, sels_b, masked: bool):
+    """The plan of one bucket of plain-segment lanes, ``(bases,
+    strides, counts, nvdla_mask)`` each, as one collecting
+    ``segment_lane_scan`` program; returns the function that runs it
+    and reduces each lane to its ``LaneMetrics``."""
+    sets, ways, blocks, max_sets, max_ways = _geometry_arrays(cfgs_b)
+    s_pad = max(1, max(v[2].shape[0] for v in views))
+    shape = (len(views), s_pad)
+    bases = np.zeros(shape, np.int32)
+    strides = np.ones(shape, np.int32)
+    counts = np.zeros(shape, np.int32)
+    r_needed = np.zeros(shape, np.int32)
+    way_sels = np.zeros(shape, np.int32)
+    suffix = "none"
+    live_per_round = 0
+    for row, ((b, s, c, _), cfg) in enumerate(zip(views, cfgs_b)):
+        k = c.shape[0]
+        bases[row, :k], strides[row, :k], counts[row, :k] = b, s, c
+        bb = cfg.block_bytes
+        last = b + np.maximum(c - 1, 0) * s
+        nb = np.where(c > 0, last // bb - b // bb + 1, 0)
+        # a round retires at most one block per set
+        live_per_round = max(live_per_round,
+                             int(np.minimum(nb, cfg.sets).max(initial=0)))
+        sel = sels_b[row]
+        if sel is not None:
+            # way-partitioned lane: every segment retires entirely in
+            # the round scan (no analytic suffix for restricted
+            # allocation), so the plan is the full ceil(nb / sets)
+            way_sels[row, :k] = sel
+            r_needed[row, :k] = (-(-nb // cfg.sets)).astype(np.int32)
+            continue
+        # per-lane tight plan: enough rounds to retire the
+        # min(nb, ways*sets)-block prefix; no cold short-circuit
+        # (conservative cold=False is exact either way, and skipping the
+        # host-side interval tracker keeps the plan O(numpy))
+        r_needed[row, :k] = np.minimum(
+            cfg.ways, -(-nb // cfg.sets)).astype(np.int32)
+        overflow = nb - np.minimum(nb, cfg.ways * cfg.sets)
+        if np.any(overflow > cfg.sets):
+            suffix = "full"
+        elif suffix == "none" and np.any(overflow > 0):
+            suffix = "one"
+    cold = np.zeros(shape, bool)
+    # the static round-buffer depth only needs to cover this batch's
+    # actual plan, not max_ways — chunked interference traces need 1
+    r_pad = max(1, int(r_needed.max()))
+    rounds = r_needed.max(axis=0).sum()
+    width = _collect_width(live_per_round, max_sets)
+
+    def run(drams_b, t_llc_hit, mesh) -> list[LaneMetrics]:
+        with tracing.span(tracing.DISPATCH):
+            arrays = [jnp.asarray(bases), jnp.asarray(strides),
+                      jnp.asarray(counts), jnp.asarray(r_needed),
+                      jnp.asarray(cold), sets, ways, blocks]
+            if mesh is not None:
+                arrays = _mesh_shard_lanes(arrays, mesh)
+            if masked:
+                # the zero-mask sentinel keeps unpartitioned rows on the
+                # standard plan inside the same compiled program
+                arrays = arrays + [jnp.asarray(way_sels)]
+            engine = _lane_engine(max_sets, max_ways, r_pad, True,
+                                  collect=True, suffix=suffix,
+                                  masked=masked, collect_width=width)
+            hits_dev, miss_dev = engine(*arrays)
+        tracing.count(tracing.PROGRAMS, 1)
+        tracing.count(tracing.MISS_WIDTH, width)
+        tracing.count(tracing.SCAN_ROUNDS, rounds)
+        tracing.count(tracing.FETCH_BYTES, hits_dev.nbytes + miss_dev.nbytes)
+        with tracing.span(tracing.FETCH):
+            hits = np.asarray(hits_dev, np.int64)
+            miss_bits = np.asarray(miss_dev)
+        out = []
+        for row, ((b, s, c, nv), cfg) in enumerate(zip(views, cfgs_b)):
+            n_seg = c.shape[0]
+            with tracing.span(tracing.MISS_RUNS):
+                runs = _lane_miss_runs(b, s, c, cfg, cold[row],
+                                       miss_bits[row],
+                                       full_prefix=sels_b[row] is not None)
+            out.append(_lane_metrics_checked(
+                runs, n_segments=n_seg, accesses=int(c.sum()),
+                hits=int(hits[row, :n_seg].sum()), bb=cfg.block_bytes,
+                nv=nv, dram=drams_b[row], t_llc_hit=t_llc_hit,
+                nv_acc=int(c[nv].sum()),
+                nv_hits=int(hits[row, :n_seg][nv].sum())))
+        return out
+    return run
+
+
+def _lane_metrics_checked(runs, *, accesses, hits, **kw) -> LaneMetrics:
+    """``_lane_metrics_from_runs`` after checking that the decoded miss
+    runs hold exactly the lane's misses."""
+    run_total = int(runs[1].sum())
+    if run_total != accesses - hits:
+        raise RuntimeError(
+            "lane miss-run reconstruction disagrees with the kernel: "
+            f"{run_total} missed blocks vs {accesses - hits} misses")
+    with tracing.span(tracing.DRAM_ROWS):
+        return _lane_metrics_from_runs(accesses=accesses, hits=hits,
+                                       runs=runs, **kw)
+
+
+def _record_program(recs, cfgs_b):
+    """The plan of one bucket of compacted lanes as one
+    ``record_lane_scan`` program; returns the function that runs it and
+    takes each lane's miss runs, in the uncompacted trace's order,
+    through the DRAM row model."""
+    sets, ways, blocks, max_sets, max_ways = _geometry_arrays(cfgs_b)
+    n_mem = max(r.members for r in recs)
+    s_pad = max(r.raw.shape[0] for r in recs)
+    shape = (len(recs), s_pad, n_mem)
+    arrays = [np.zeros(shape, np.int32), np.ones(shape, np.int32),
+              np.zeros(shape, np.int32), np.ones(shape, np.int32),
+              np.zeros(shape, np.int32), np.ones(shape[:2], np.int32),
+              np.zeros(shape[:2], np.int32)]
+    for row, (r, cfg) in enumerate(zip(recs, cfgs_b)):
+        k, p = r.counts.shape
+        for a, v in zip(arrays, (r.bases, r.strides, r.counts, r.chunks,
+                                 r.offsets)):
+            a[row, :k, :p] = v
+        arrays[5][row, :k] = r.periods
+        arrays[6][row, :k] = _record_rounds(r, cfg)
+    r_pad = max(1, int(arrays[6].max()))
+    rounds = arrays[6].max(axis=0).sum()
+
+    def run(drams_b, t_llc_hit, mesh) -> list[LaneMetrics]:
+        with tracing.span(tracing.DISPATCH):
+            dev = [jnp.asarray(a) for a in arrays] + [sets, ways, blocks]
+            if mesh is not None:
+                dev = _mesh_shard_lanes(dev, mesh, zero=(2, 6))
+            engine = _record_engine(max_sets, max_ways, r_pad)
+            hits_dev, codes_dev = engine(*dev)
+        tracing.count(tracing.PROGRAMS, 1)
+        tracing.count(tracing.SCAN_ROUNDS, rounds)
+        tracing.count(tracing.FETCH_BYTES, hits_dev.nbytes + codes_dev.nbytes)
+        with tracing.span(tracing.FETCH):
+            hits = np.asarray(hits_dev, np.int64)
+            codes = np.asarray(codes_dev)
+
+        def lane(row) -> LaneMetrics:
+            r, cfg = recs[row], cfgs_b[row]
+            k, p = r.counts.shape
+            h = hits[row, :k, :p]
+            with tracing.span(tracing.MISS_RUNS):
+                runs = _record_miss_runs(r, cfg, codes[row, :k], max_ways)
+            return _lane_metrics_checked(
+                runs, n_segments=int(r.raw.sum()),
+                accesses=int(r.counts.sum()), hits=int(h.sum()),
+                bb=cfg.block_bytes, nv=r.nv.reshape(-1), dram=drams_b[row],
+                t_llc_hit=t_llc_hit, nv_acc=int(r.counts[r.nv].sum()),
+                nv_hits=int(h[r.nv].sum()))
+
+        # a lane's decode and DRAM rows are numpy passes over millions of
+        # chunks, which run with the interpreter lock released: the lanes
+        # go in threads
+        with concurrent.futures.ThreadPoolExecutor() as pool:
+            return list(pool.map(lane, range(len(recs))))
+    return run
+
+
+def _record_rounds(r: LaneRecords, llc: LLCConfig) -> np.ndarray:
+    """Round-scan rounds per record: its first ``ways`` arrivals per
+    set, bounded by the arrivals its members bring to any one set."""
+    bb, sets = llc.block_bytes, llc.sets
+    last = r.bases + np.maximum(r.counts - 1, 0) * r.strides
+    nb = np.where(r.counts > 0, last // bb - r.bases // bb + 1, 0)
+    return np.minimum(llc.ways, (-(-nb // sets)).sum(axis=1))
+
+
+def _record_miss_runs(r: LaneRecords, llc: LLCConfig, codes: np.ndarray,
+                      max_ways: int) -> tuple:
+    """A compacted lane's missed-block runs in the order of its
+    uncompacted trace: every chunk's newly touched blocks (a block its
+    previous chunk already touched is a hit), less the round scan's
+    hits (``cache.record_lane_scan``'s codes).  Returns
+    ``(first_blocks, n_blocks, member)`` int64 arrays, ``member`` the
+    flat (record, member) index."""
+    bb, sets = llc.block_bytes, llc.sets
+    n_rec, p = r.counts.shape
+    base, stride, count, chunk = r.bases, r.strides, r.counts, r.chunks
+    b_first = base // bb
+    # chunks in trace order: per record, repeat-major over live members
+    live = (count > 0).sum(axis=1)
+    reps = np.where(live > 0, -(-count[:, 0] // np.maximum(chunk[:, 0], 1)),
+                    0)
+    per_rec = reps * live
+    q_start = np.cumsum(per_rec) - per_rec
+    n_q = int(per_rec.sum())
+    rec = np.repeat(np.arange(n_rec), per_rec)
+    q = np.arange(n_q) - q_start[rec]
+    rep, mem = q // live[rec], q % live[rec]
+    flat = rec * p + mem
+    base_q, stride_q = base.reshape(-1)[flat], stride.reshape(-1)[flat]
+    chunk_q, count_q = chunk.reshape(-1)[flat], count.reshape(-1)[flat]
+    j0 = rep * chunk_q
+    j1 = np.minimum(j0 + chunk_q, count_q) - 1
+    first_q = b_first.reshape(-1)[flat]
+    o0 = (base_q + j0 * stride_q) // bb - first_q
+    shared = (j0 > 0) & ((base_q + (j0 - 1) * stride_q) // bb - first_q == o0)
+    lo = o0 + shared
+    hi = (base_q + j1 * stride_q) // bb - first_q + 1
+    # the round scan's hits, each in the chunk of its block's first access
+    hr, hk, hs = np.nonzero(codes)
+    v = codes[hr, hk, hs].astype(np.int64) - 1
+    hm, hq = v // max_ways, v % max_ways
+    h_ord = (hs - b_first[hr, hm]) % sets + hq * sets
+    hf = hr * p + hm
+    h_base, h_stride = base.reshape(-1)[hf], stride.reshape(-1)[hf]
+    lo_b = (b_first.reshape(-1)[hf] + h_ord) * bb - h_base
+    j_first = np.where(lo_b <= 0, 0, -(-lo_b // h_stride))
+    h_q = (q_start[hr] + (j_first // chunk.reshape(-1)[hf]) * live[hr]
+           + hm)
+    order = np.lexsort((h_ord, h_q))
+    h_q, h_ord = h_q[order], h_ord[order]
+    # split each chunk's range at its hits: chunk q's pieces are
+    # [lo, h1), [h1+1, h2), ..., [hk+1, hi)
+    n_hits = np.bincount(h_q, minlength=n_q)
+    pieces = 1 + n_hits
+    at = np.cumsum(pieces) - pieces
+    rank = np.arange(h_q.shape[0]) - (np.cumsum(n_hits) - n_hits)[h_q]
+    start = np.empty(int(pieces.sum()), np.int64)
+    end = np.empty_like(start)
+    start[at] = lo
+    end[at + pieces - 1] = hi
+    start[at[h_q] + rank + 1] = h_ord + 1
+    end[at[h_q] + rank] = h_ord
+    owner = np.repeat(flat, pieces)
+    keep = end > start
+    return (b_first.reshape(-1)[owner[keep]] + start[keep],
+            end[keep] - start[keep], owner[keep])
+
+
+@functools.lru_cache(maxsize=32)
+def _record_engine(max_sets: int, max_ways: int, r_pad: int):
+    from repro.core.cache import record_lane_scan
+
+    return jax.jit(jax.vmap(functools.partial(
+        record_lane_scan, max_sets=max_sets, max_ways=max_ways,
+        r_pad=r_pad)))
+
+
 def sweep_interference(*, soc=None, corunners=(0, 1, 2, 3, 4),
-                       window_bursts: int = 4096,
+                       window_bursts: int | None = 4096,
                        chunk_bursts: int = 16) -> SweepGrid:
     """Fig. 6, batched: closed-form slowdown curves (``.slowdowns``)
     plus, per (wss, n), the *simulated* NVDLA LLC hit rate with
     co-runner write streams physically interleaved into the trace
     (``.sim_hit_rates``) — every lane a compressed segment stream,
     returned as a typed ``SweepGrid``.  All interference lanes share
-    one LLC geometry, so each lane runs one exact segment-engine pass
-    that yields per-segment hit attribution *and* the exact LLC-miss
-    runs together (the vmapped ``segment_lane_hit_counts`` engine is
-    the multi-*geometry* path; replaying here a second time just for
-    lane-parallel hit bits would double the simulation cost).  DRAM
-    row-hit rates come from the closed-form row model over each lane's
-    miss runs (misses of *all* masters mix in the banks, so co-runner
-    misses break the NVDLA stream's row locality — the FR-FCFS
-    disruption Fig. 6 attributes the "dram" slowdown to)."""
+    one LLC geometry and run as one ``interference_lane_metrics_batch``
+    call, which yields per-segment hit attribution *and* the exact
+    LLC-miss runs together.  DRAM row-hit rates come from the
+    closed-form row model over each lane's miss runs (misses of *all*
+    masters mix in the banks, so co-runner misses break the NVDLA
+    stream's row locality — the FR-FCFS disruption Fig. 6 attributes
+    the "dram" slowdown to).
+
+    ``window_bursts=None`` simulates the *entire* YOLOv3 frame: its
+    lanes compact into records (``compact_lane``), so the lane
+    program's scan length follows the records, not the chunks."""
     from repro.core.dram import DRAMConfig
     from repro.core.soc import SoCConfig, interference_sweep as _closed_form
 
@@ -1321,33 +1642,28 @@ def sweep_interference(*, soc=None, corunners=(0, 1, 2, 3, 4),
     llc = soc.mem.llc or LLCConfig()
     dram = soc.mem.dram or DRAMConfig()
     if window_bursts is None:
-        # full-frame chunk interleaving explodes to ~2M segments/lane —
-        # serially infeasible until segment-count compaction lands (see
-        # ROADMAP); refuse loudly rather than run for hours
-        raise NotImplementedError(
-            "full-frame interference sweeps need RLE segment compaction; "
-            "pass a window_bursts cap (the LLC sweep supports full "
-            "frames — its lanes stay at stream granularity)")
-    nvdla_segs = traces.default_dbb_window(max_bursts=window_bursts)
+        nvdla_segs = traces.network_trace()
+    else:
+        nvdla_segs = traces.default_dbb_window(max_bursts=window_bursts)
     # l1-fitting co-runners never reach the shared fabric, so every
     # ('l1', n) lane is the solo-NVDLA trace — simulate it once and fan
     # the result out to all n below
+    keys = [("l1", 0)] + [(wss, n) for wss in ("llc", "dram")
+                          for n in corunners]
+    lanes = interference_lane_metrics_batch(
+        nvdla_segs, llcs=[llc] * len(keys), drams=[dram] * len(keys),
+        mixes=[MixConfig(corunners=n, wss=wss) for wss, n in keys],
+        chunk_bursts=chunk_bursts)
     sim_hit_rates: dict = {}
     sim_row_hit_rates: dict = {}
-    for wss, ns in (("l1", (0,)), ("llc", corunners), ("dram", corunners)):
-        for n in ns:
-            m = interference_lane_metrics(
-                nvdla_segs, llc=llc, dram=dram,
-                mix=MixConfig(corunners=n, wss=wss),
-                chunk_bursts=chunk_bursts)
-            keys = ([(wss, n)] if wss != "l1"
-                    else [("l1", k) for k in corunners])
-            for key in keys:
-                sim_hit_rates[key] = m.nvdla_hit_rate
-                sim_row_hit_rates[key] = m.nvdla_miss_row_hit_rate
+    for (wss, n), m in zip(keys, lanes):
+        for key in ([(wss, n)] if wss != "l1"
+                    else [("l1", k) for k in corunners]):
+            sim_hit_rates[key] = m.nvdla_hit_rate
+            sim_row_hit_rates[key] = m.nvdla_miss_row_hit_rate
     return SweepGrid(
         kind="interference",
         slowdowns={wss: cf[wss] for wss in ("l1", "llc", "dram")},
         sim_hit_rates=sim_hit_rates,
         sim_row_hit_rates=sim_row_hit_rates,
-        window_bursts=window_bursts)
+        window_bursts=traces.total_bursts(nvdla_segs))

@@ -146,24 +146,29 @@ def op_segments(op: AccelOp, weight_base: int, ifmap_base: int,
     return segs
 
 
+REGIONS = (WEIGHT_REGION, FMAP_REGION_A, FMAP_REGION_B)
+
+
 def network_op_segments(stream: CommandStream | None = None,
-                        max_ops: int | None = None) -> list[list[Segment]]:
+                        max_ops: int | None = None,
+                        regions: tuple[int, int, int] = REGIONS
+                        ) -> list[list[Segment]]:
     """Per-AccelOp DBB streams over the shared address map — the same
     segments ``network_trace`` emits, kept grouped by op so per-layer
     consumers (the sim-driven ``repro.core.accelerator`` hit rates) can
     attribute hits to the op that issued them.
 
-    Weight regions are packed in layer order; feature maps ping-pong
-    between two regions so a consumer reads where its producer wrote.
+    Weight regions are packed in layer order from ``regions[0]``;
+    feature maps ping-pong between ``regions[1]`` and ``regions[2]`` so
+    a consumer reads where its producer wrote.
     """
     stream = stream or compile_network()
     ops = stream.accel_ops[:max_ops] if max_ops else stream.accel_ops
     per_op: list[list[Segment]] = []
-    w_cursor = WEIGHT_REGION
-    regions = (FMAP_REGION_A, FMAP_REGION_B)
+    w_cursor, *fmaps = regions
     for i, op in enumerate(ops):
-        ifmap_base = regions[i % 2]
-        ofmap_base = regions[(i + 1) % 2]
+        ifmap_base = fmaps[i % 2]
+        ofmap_base = fmaps[(i + 1) % 2]
         per_op.append(op_segments(op, w_cursor, ifmap_base, ofmap_base))
         passes = max(1, op.weight_passes)
         w_cursor += op.weight_traffic // passes
@@ -171,10 +176,11 @@ def network_op_segments(stream: CommandStream | None = None,
 
 
 def network_trace(stream: CommandStream | None = None,
-                  max_ops: int | None = None) -> list[Segment]:
+                  max_ops: int | None = None,
+                  regions: tuple[int, int, int] = REGIONS) -> list[Segment]:
     """The whole accelerated network's DBB stream, compressed (the
     flattened ``network_op_segments``)."""
-    return [seg for op_segs in network_op_segments(stream, max_ops)
+    return [seg for op_segs in network_op_segments(stream, max_ops, regions)
             for seg in op_segs]
 
 
@@ -237,11 +243,15 @@ def expand(segments: list[Segment]) -> np.ndarray:
 
 
 def default_dbb_window(max_bursts: int = 4096, chunk_bursts: int = 16,
-                       layer_index: int = 40) -> list[Segment]:
+                       layer_index: int = 40,
+                       regions: tuple[int, int, int] = REGIONS
+                       ) -> list[Segment]:
     """A representative DBB window for sweeps: a mid-network conv layer's
-    weight/ifmap/ofmap streams, arbiter-interleaved."""
+    weight/ifmap/ofmap streams, arbiter-interleaved, its weights at
+    ``regions[0]``, its ifmap at ``regions[1]`` and ofmap at
+    ``regions[2]``."""
     stream = compile_network()
     ops = stream.accel_ops
     op = ops[min(layer_index, len(ops) - 1)]
-    segs = op_segments(op, WEIGHT_REGION, FMAP_REGION_A, FMAP_REGION_B)
+    segs = op_segments(op, *regions)
     return window(interleave(segs, chunk_bursts), max_bursts)

@@ -14,7 +14,9 @@ snapshots is what ran between them.
 
 Leaf spans, in the order one lane batch runs them:
 ``LANE_PLAN`` -> ``DISPATCH`` -> ``FETCH`` -> ``MISS_RUNS`` ->
-``DRAM_ROWS``; a campaign then records its results (``RECORD``).
+``DRAM_ROWS``; a campaign then records its results (``RECORD``).  The
+interference path plans its lanes (``LANE_PLAN``) and compacts them
+(``COMPACT``) before its first lane batch.
 ``CAMPAIGN`` and ``LANE_BATCH`` are parents: they own only the time
 their leaves leave.
 """
@@ -30,18 +32,22 @@ CAMPAIGN = "repro.campaign.run"        # one run_campaign call
 LANE_BATCH = "repro.sweep.lane_batch"  # one lane bucket: one compiled program
 # leaf spans
 LANE_PLAN = "repro.sweep.lane_plan"    # numpy traces, round plans, padding
+COMPACT = "repro.sweep.compact"        # lanes compacted into records
 DISPATCH = "repro.sweep.dispatch"      # host-to-device copies, program enqueue
 FETCH = "repro.sweep.fetch"            # wait for the program, copy to host
 MISS_RUNS = "repro.sweep.miss_runs"    # one lane's missed-block runs
 DRAM_ROWS = "repro.sweep.dram_rows"    # one lane's DRAM rows and latency
 RECORD = "repro.campaign.record"       # guardrails, journal fsyncs, manifest
-LEAVES = (LANE_PLAN, DISPATCH, FETCH, MISS_RUNS, DRAM_ROWS, RECORD)
+LEAVES = (LANE_PLAN, COMPACT, DISPATCH, FETCH, MISS_RUNS, DRAM_ROWS,
+          RECORD)
 
 # counters
 FETCH_BYTES = "sweep.fetch_bytes"  # bytes of lane-program outputs fetched
 SCAN_ROUNDS = "sweep.scan_rounds"  # serial round-scan steps dispatched
 PROGRAMS = "sweep.programs"        # lane programs dispatched
 MISS_WIDTH = "sweep.miss_width"    # miss-bit widths W of collecting programs
+LANE_SEGMENTS = "sweep.lane_segments"          # records the programs scan
+LANE_SEGMENTS_RAW = "sweep.lane_segments_raw"  # the same, uncompacted
 
 _counts: dict[str, int] = {}
 _lock = threading.Lock()

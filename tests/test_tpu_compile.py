@@ -165,3 +165,35 @@ def test_full_frame_fig5_lane_engine_compiles(monkeypatch, one_chip,
     compiled = engine.lower(
         *(_on_chip(one_chip, s, d) for s, d in shapes)).compile()
     _fits(compiled, hbm_bytes)
+
+
+def test_full_frame_record_engine_compiles(monkeypatch, one_chip,
+                                           hbm_bytes):
+    """The whole YOLOv3 frame beside 0-4 dram-class co-runners, its five
+    lanes compacted into records: the program the ``fig6-frame-dram``
+    cell runs, at its real record count."""
+    from repro.campaign import CampaignSpec, GeometrySpec, MixSpec, ModelSpec
+    from repro.campaign.executor import run_batch
+
+    points = CampaignSpec(
+        name="frame", models=(ModelSpec(window_bursts=None),),
+        geometries=(GeometrySpec(size_kib=2048, block=64, ways=8),),
+        mixes=tuple(MixSpec(n, "dram") for n in range(5))).expand()
+    real, got = sweep._record_engine, {}
+
+    def engine(*static):
+        def call(*args):
+            got.update(static=static,
+                       shapes=[(np.shape(a), np.asarray(a).dtype)
+                               for a in args])
+            raise _Captured
+        return call
+
+    monkeypatch.setattr(sweep, "_record_engine", engine)
+    with pytest.raises(_Captured):
+        run_batch(points, points[0].model.trace())
+    lanes, records, members = got["shapes"][0][0]
+    assert (lanes, members) == (5, 5) and records < 1000
+    compiled = real(*got["static"]).lower(
+        *(_on_chip(one_chip, s, d) for s, d in got["shapes"])).compile()
+    _fits(compiled, hbm_bytes)

@@ -150,14 +150,19 @@ def test_campaign_counters_match_the_shapes(monkeypatch, tmp_path):
     assert res.completed == 4
     ((rounds, (hits, miss)),) = dispatched
     sets = max(p.geometry.llc().sets for p in spec.expand())
+    lane_segments = [len(sweep.corunner_meta(
+        p.model.trace(), llc=p.geometry.llc(), mix=p.mix.mix())[2])
+        for p in spec.expand()]
+    # the window's NVDLA chunks never continue one another: nothing
+    # compacts, so the lanes scan their uncompacted segments
     assert got == {tracing.PROGRAMS: 1, tracing.SCAN_ROUNDS: rounds,
                    tracing.FETCH_BYTES: _fetched(dispatched),
-                   tracing.MISS_WIDTH: sets}
+                   tracing.MISS_WIDTH: sets,
+                   tracing.LANE_SEGMENTS: sum(lane_segments),
+                   tracing.LANE_SEGMENTS_RAW: sum(lane_segments)}
     # hits (lanes, segments) int32, miss bits (lanes, segments, rounds,
     # ordinals) bool, as wide as the 64 sets: below the 128-lane width
-    segments = max(len(sweep.corunner_meta(
-        p.model.trace(), llc=p.geometry.llc(), mix=p.mix.mix())[2])
-        for p in spec.expand())
+    segments = max(lane_segments)
     assert hits.shape == (4, segments) and hits.dtype == np.int32
     assert miss.shape[:2] == (4, segments) and miss.shape[3] == sets == 64
     assert miss.dtype == bool
