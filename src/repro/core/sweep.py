@@ -60,7 +60,6 @@ count and exist only as a parity oracle for the segment-lane engine.
 """
 from __future__ import annotations
 
-import concurrent.futures
 import dataclasses
 import functools
 import warnings
@@ -789,9 +788,9 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
 
     A run of several members is kept only where the record engine's
     merge is exact at ``llc``: the members' block ranges are disjoint,
-    each chunk spans at least one block, and a block a member's chunks
-    share sees fewer than ``ways`` arrivals of the other members in its
-    set in between (at most one chunk of each).  The records replay
+    each chunk spans a whole number of blocks, and a block a member's
+    chunks share sees fewer than ``ways`` arrivals of the other members
+    in its set in between (at most one chunk of each).  The records replay
     exactly the lane's access order.  ``members`` is the lane's segments
     per round without a wrap: 1 + its co-runners."""
     b, s, c = (np.asarray(a, np.int64) for a in (b, s, c))
@@ -855,14 +854,16 @@ def _exact_runs(first, last, B, St, Cn, llc: LLCConfig):
     width = Cn[first] * St[first]                     # bytes per chunk
     lo = B[first] // bb
     hi = (B[last] + (Cn[last] - 1) * St[last]) // bb
-    ok = (width >= bb).all(1)
+    # whole blocks: the device's row reduction (``_record_rows``) lays a
+    # member's hits out a chunk at a time
+    ok = (width % bb == 0).all(1)
     p = B.shape[1]
     for m in range(p):
         for m2 in range(m + 1, p):
             ok &= (hi[:, m] < lo[:, m2]) | (hi[:, m2] < lo[:, m])
     # a shared boundary block waits out one chunk of every other member
     arrivals = -(-(-(-width // bb) + 1) // sets)
-    shares = ((B[first] % bb) != 0) | ((width % bb) != 0)
+    shares = (B[first] % bb) != 0
     others = arrivals.sum(1, keepdims=True) - arrivals
     ok &= ~(shares & (others >= ways)).any(1)
     return first[ok], last[ok]
@@ -873,9 +874,10 @@ def _lane_metrics_from_runs(*, n_segments, accesses, hits, runs, bb, nv,
     """The shared lane reduction: exact LLC counts + miss runs
     ((first_block, n_blocks, seg_idx) triples in access order, either a
     list of tuples or a tuple of three aligned int64 arrays) ->
-    closed-form DRAM row hits -> closed-form latency total -> the typed
-    record.  Both the sequential and the batched path end here, so
-    their metrics are bit-identical by construction."""
+    closed-form DRAM row hits -> ``_lane_metrics``.  The sequential and
+    the segment path end here; the record path reduces its lanes to the
+    same counts on the device (``_record_rows``) and ends in
+    ``_lane_metrics`` too."""
     from repro.core.dram import segment_row_hits
 
     if isinstance(runs, tuple):
@@ -886,17 +888,26 @@ def _lane_metrics_from_runs(*, n_segments, accesses, hits, runs, bb, nv,
     row = segment_row_hits((fb * bb, np.full(fb.shape[0], bb, np.int64),
                             nbk), dram)
     run_is_nv = np.asarray(nv, bool)[sidx]
-    nv_miss = int(nbk[run_is_nv].sum())
-    nv_row_hits = int(row.per_segment[run_is_nv].sum())
+    return _lane_metrics(
+        n_segments=n_segments, accesses=accesses, hits=hits,
+        row_hits=row.row_hits, dram=dram, t_llc_hit=t_llc_hit,
+        nv_acc=nv_acc, nv_hits=nv_hits, nv_miss=int(nbk[run_is_nv].sum()),
+        nv_row_hits=int(row.per_segment[run_is_nv].sum()))
+
+
+def _lane_metrics(*, n_segments, accesses, hits, row_hits, dram, t_llc_hit,
+                  nv_acc, nv_hits, nv_miss, nv_row_hits) -> LaneMetrics:
+    """A lane's counts -> closed-form latency total -> the typed record,
+    in Python ints."""
     misses = accesses - hits
-    row_misses = misses - row.row_hits
+    row_misses = misses - row_hits
     total = (accesses * t_llc_hit + misses * dram.t_cas_cycles
              + row_misses * (dram.t_rp_cycles + dram.t_rcd_cycles))
     return LaneMetrics(
         segments=n_segments,
         accesses=int(accesses),
         llc_hits=int(hits),
-        dram_row_hits=int(row.row_hits),
+        dram_row_hits=int(row_hits),
         t_llc_hit=int(t_llc_hit),
         total_cycles=int(total),
         hit_rate=hits / max(1, accesses),
@@ -1269,10 +1280,13 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     Each unmasked lane is compacted first (``compact_lane``): where the
     records shorten a bucket's padded scan by more than their members
     cost (``_records_that_pay``), the bucket runs as one
-    ``record_lane_scan`` program instead, and its miss runs are decoded
-    in the uncompacted trace's order (``_record_miss_runs``) — the same
-    metrics, bit for bit.  The whole frame's lanes take it; the Fig. 6
-    windows, whose NVDLA chunks never continue one another, do not.
+    ``record_lane_scan`` program instead (one per set count, where the
+    bucket mixes them), and a second device program
+    (``_record_rows``) reduces each lane's hit codes to its missed
+    blocks and DRAM row hits, so the host fetches counts, not codes —
+    the same metrics, bit for bit.  The whole frame's lanes take it;
+    the Fig. 6 windows, whose NVDLA chunks never continue one another,
+    do not.
 
     ``mesh`` (a 1-D ``jax.sharding.Mesh``, see
     ``repro.launch.mesh.make_sweep_mesh``) shards the lane axis across
@@ -1337,19 +1351,27 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                 tracing.count(tracing.LANE_SEGMENTS, sum(
                     raw if recs is None else [r.raw.shape[0] for r in recs]))
                 if recs is not None and max(r.members for r in recs) > 1:
-                    run = _record_program(recs, cfgs_b)
+                    # the row reduction lays out one set count's hit codes
+                    parts = [[j for j, c in enumerate(cfgs_b) if c.sets == n]
+                             for n in sorted({c.sets for c in cfgs_b},
+                                             reverse=True)]
+                    runs = [([bucket[j] for j in js], _record_program(
+                        [recs[j] for j in js], [cfgs_b[j] for j in js]))
+                        for js in parts]
                 else:
                     views = ([(*lanes[i], nv_masks[i]) for i in bucket]
                              if recs is None else
                              # one member each: plain segments
                              [(r.bases[:, 0], r.strides[:, 0],
                                r.counts[:, 0], r.nv[:, 0]) for r in recs])
-                    run = _segment_program(views, cfgs_b,
-                                           [lane_sels[i] for i in bucket],
-                                           masked)
-            got = run([drams[i] for i in bucket], t_llc_hit, mesh)
-        for i, n, m in zip(bucket, raw, got):
-            out[i] = dataclasses.replace(m, segments=n)
+                    runs = [(bucket, _segment_program(
+                        views, cfgs_b, [lane_sels[i] for i in bucket],
+                        masked))]
+            for part, run in runs:
+                got = run([drams[i] for i in part], t_llc_hit, mesh)
+                for i, m in zip(part, got):
+                    out[i] = dataclasses.replace(m, segments=lanes[i][2]
+                                                 .shape[0])
     return out
 
 
@@ -1466,23 +1488,35 @@ def _segment_program(views, cfgs_b, sels_b, masked: bool):
 def _lane_metrics_checked(runs, *, accesses, hits, **kw) -> LaneMetrics:
     """``_lane_metrics_from_runs`` after checking that the decoded miss
     runs hold exactly the lane's misses."""
-    run_total = int(runs[1].sum())
-    if run_total != accesses - hits:
-        raise RuntimeError(
-            "lane miss-run reconstruction disagrees with the kernel: "
-            f"{run_total} missed blocks vs {accesses - hits} misses")
+    _check_missed(int(runs[1].sum()), accesses, hits)
     with tracing.span(tracing.DRAM_ROWS):
         return _lane_metrics_from_runs(accesses=accesses, hits=hits,
                                        runs=runs, **kw)
 
 
+def _check_missed(missed: int, accesses: int, hits: int) -> None:
+    """The missed blocks a lane's reduction found must be exactly its
+    misses."""
+    if missed != accesses - hits:
+        raise RuntimeError(
+            "lane miss-run reconstruction disagrees with the kernel: "
+            f"{missed} missed blocks vs {accesses - hits} misses")
+
+
 def _record_program(recs, cfgs_b):
     """The plan of one bucket of compacted lanes as one
     ``record_lane_scan`` program; returns the function that runs it and
-    takes each lane's miss runs, in the uncompacted trace's order,
-    through the DRAM row model."""
+    reduces each lane to its ``LaneMetrics``.  The lanes share one set
+    count.  The hit codes stay on the device: a second program
+    (``_record_rows``) reduces them, with the records, to each lane's
+    missed blocks and DRAM row hits, and the host fetches those counts
+    and the per-record hits alone."""
     sets, ways, blocks, max_sets, max_ways = _geometry_arrays(cfgs_b)
     n_mem = max(r.members for r in recs)
+    # members past the bucket's widest record are dead in every record
+    recs = [dataclasses.replace(r, **{f: getattr(r, f)[:, :n_mem] for f in (
+        "bases", "strides", "counts", "chunks", "offsets", "nv")})
+        for r in recs]
     s_pad = max(r.raw.shape[0] for r in recs)
     shape = (len(recs), s_pad, n_mem)
     arrays = [np.zeros(shape, np.int32), np.ones(shape, np.int32),
@@ -1508,30 +1542,416 @@ def _record_program(recs, cfgs_b):
             hits_dev, codes_dev = engine(*dev)
         tracing.count(tracing.PROGRAMS, 1)
         tracing.count(tracing.SCAN_ROUNDS, rounds)
-        tracing.count(tracing.FETCH_BYTES, hits_dev.nbytes + codes_dev.nbytes)
         with tracing.span(tracing.FETCH):
             hits = np.asarray(hits_dev, np.int64)
-            codes = np.asarray(codes_dev)
-
-        def lane(row) -> LaneMetrics:
-            r, cfg = recs[row], cfgs_b[row]
-            k, p = r.counts.shape
-            h = hits[row, :k, :p]
-            with tracing.span(tracing.MISS_RUNS):
-                runs = _record_miss_runs(r, cfg, codes[row, :k], max_ways)
-            return _lane_metrics_checked(
-                runs, n_segments=int(r.raw.sum()),
-                accesses=int(r.counts.sum()), hits=int(h.sum()),
-                bb=cfg.block_bytes, nv=r.nv.reshape(-1), dram=drams_b[row],
-                t_llc_hit=t_llc_hit, nv_acc=int(r.counts[r.nv].sum()),
-                nv_hits=int(h[r.nv].sum()))
-
-        # a lane's decode and DRAM rows are numpy passes over millions of
-        # chunks, which run with the interpreter lock released: the lanes
-        # go in threads
-        with concurrent.futures.ThreadPoolExecutor() as pool:
-            return list(pool.map(lane, range(len(recs))))
+        with tracing.span(tracing.LANE_PLAN):
+            shards = 1 if mesh is None else int(np.prod(list(
+                mesh.shape.values())))
+            plan = _record_rows_plan(recs, cfgs_b, drams_b, hits, r_pad,
+                                     max_ways, shards)
+        with tracing.span(tracing.DISPATCH):
+            tables = plan.arrays
+            if mesh is not None:
+                tables = _mesh_shard_lanes(tables, mesh)
+            counts_dev = _rows_engine(*plan.static)(codes_dev, *tables)
+        with tracing.span(tracing.FETCH):
+            counts = np.asarray(counts_dev, np.int64)
+        tracing.count(tracing.FETCH_BYTES, hits_dev.nbytes + counts_dev.nbytes)
+        tracing.count(tracing.DEVICE_REDUCED_LANES, len(recs))
+        out = []
+        for row, r in enumerate(recs):
+            kw = _record_lane_kw(r, hits[row], drams_b[row], t_llc_hit)
+            missed, nv_miss, row_hits, nv_row_hits = map(int, counts[row])
+            _check_missed(missed, kw["accesses"], kw["hits"])
+            out.append(_lane_metrics(row_hits=row_hits, nv_miss=nv_miss,
+                                     nv_row_hits=nv_row_hits, **kw))
+        return out
     return run
+
+
+def _record_lane_kw(r: LaneRecords, hits, dram, t_llc_hit: int) -> dict:
+    """A compacted lane's counts that the host holds: its segments,
+    accesses, and hits from the record program's per-record ``hits``."""
+    k, p = r.counts.shape
+    h = hits[:k, :p]
+    return dict(n_segments=int(r.raw.sum()), accesses=int(r.counts.sum()),
+                hits=int(h.sum()), dram=dram, t_llc_hit=t_llc_hit,
+                nv_acc=int(r.counts[r.nv].sum()), nv_hits=int(h[r.nv].sum()))
+
+
+def _padded(n: int, least: int = 256) -> int:
+    """``n`` rounded up to one of eight steps per power of two (at least
+    ``least``), so that a new seed or campaign of about the same size
+    reuses the compiled program."""
+    step = max(least, 1 << max(0, n.bit_length() - 3))
+    return -(-max(n, 1) // step) * step
+
+
+@dataclasses.dataclass(frozen=True)
+class _RowsPlan:
+    """The host's part of ``_record_rows`` for one bucket: per-shard
+    arrays (shard axis first) and the static sizes that key its
+    compiled program."""
+    arrays: tuple
+    static: tuple
+
+
+_SEG = ("unit_start", "plain", "b_off", "stride", "chunk", "count", "blk_off",
+        "row0", "nv", "tk0", "tk_step", "nb", "lane", "bb", "bpr", "banks")
+_HIT = ("rec", "code", "bmod", "off", "blk_off", "nb", "units", "unit_start",
+        "nv", "lane", "bpr")
+
+
+def _record_rows_plan(recs, cfgs_b, drams_b, hits, r_pad: int,
+                      max_ways: int, shards: int = 1) -> _RowsPlan:
+    """Plan the device reduction of a bucket's compacted lanes from the
+    records and the fetched per-record ``hits``, in O(records x
+    members).  Each live record member is a segment of units: a plain
+    record's member (the record is one chunk) has one unit per DRAM row
+    it touches, a member of a several-member record one unit per chunk.
+    A unit's DRAM rows (at most ``T``) are its visits.  The lanes go in
+    ``shards`` runs of consecutive lanes (one per device of a mesh),
+    each laid out flat, so that no lane pays another's padding.
+
+    Per segment (``_SEG`` columns): where its units start, its stride
+    run, the offsets of its first block in its block and its row, where
+    its visits go in its lane's trace order (``tk0`` plus ``tk_step`` a
+    unit), its lane and the lane's geometry.  Per segment whose blocks
+    the round scan hit (``hits`` less every access after a block's
+    first), grouped by its unit length in blocks (``_HIT`` columns): its
+    record, the code of its arrival 0, its first block's set and where
+    its units start in its ordinals.  Per run of whole records that the
+    device sorts as one row (``_sort_rows``): its units and its lane.
+
+    The lanes share one set count, and a chunk of a several-member
+    record is a whole number of blocks (``compact_lane``), so a hit
+    segment's units are all alike.  Raises ``OverflowError`` where a
+    lane's sort key would not fit int32: past about 16 whole frames in
+    one lane beside four co-runners, at 32 banks.
+    """
+    sets = cfgs_b[0].sets
+    if any(c.sets != sets for c in cfgs_b):
+        raise ValueError("a record bucket's lanes share one set count")
+    lanes = []
+    width = 1                                   # T: rows a unit touches
+    for r, llc, dram in zip(recs, cfgs_b, drams_b):
+        bb, bpr = llc.block_bytes, dram.row_bytes // llc.block_bytes
+        on = r.counts > 0
+        if np.any(on & (r.counts * r.strides >= 2 ** 31)):
+            raise UnsupportedTraceError("a record member spans 2 GiB or more")
+        live = on.sum(axis=1)
+        plain = live == 1                       # one member, one chunk
+        chunk_blocks = -(-(r.chunks * r.strides) // bb) + 1
+        multi = on & ~plain[:, None]
+        if multi.any():
+            width = max(width, int(((chunk_blocks + bpr - 1) // bpr
+                                    + 1)[multi].max()))
+        lanes.append((r, dram, bb, bpr, on, live, plain))
+    per_shard = -(-len(recs) // shards)
+    segs = [[] for _ in range(shards)]
+    hit_rows = [{} for _ in range(shards)]
+    n_real = [0] * shards
+    starts = [[] for _ in range(shards)]
+    keyspan = 1
+    for lane, (r, dram, bb, bpr, on, live, plain) in enumerate(lanes):
+        sh = lane // per_shard
+        b_first = r.bases // bb
+        b_last = (r.bases + np.maximum(r.counts - 1, 0) * r.strides) // bb
+        nb = b_last - b_first + 1
+        blk_off = b_first % bpr
+        reps = -(-r.counts[:, :1] // np.maximum(r.chunks[:, :1], 1))
+        units = np.where(plain[:, None], (blk_off + nb - 1) // bpr + 1, reps)
+        cap = np.where(plain, units[:, 0], reps[:, 0] * live * width)
+        keyspan = max(keyspan, int(cap.sum()))
+        base = np.cumsum(cap) - cap
+        m = np.arange(r.counts.shape[1])[None, :]
+        tk0 = base[:, None] + np.where(plain[:, None], 0, m * width)
+        tk_step = np.where(plain, 1, live * width)[:, None] + 0 * m
+        rec, mem = np.nonzero(on)
+        u = units[rec, mem]
+        cols = dict(
+            unit_start=n_real[sh] + np.cumsum(u) - u, plain=plain[rec],
+            b_off=(r.bases - b_first * bb)[rec, mem],
+            stride=r.strides[rec, mem], chunk=r.chunks[rec, mem],
+            count=r.counts[rec, mem], blk_off=blk_off[rec, mem],
+            row0=(b_first // bpr)[rec, mem], nv=r.nv[rec, mem],
+            tk0=tk0[rec, mem], tk_step=tk_step[rec, mem], nb=nb[rec, mem],
+            lane=lane % per_shard, bb=bb, bpr=bpr, banks=dram.banks)
+        segs[sh].append(np.stack([np.broadcast_to(cols[c], rec.shape)
+                                  for c in _SEG], axis=1))
+        per_rec = np.bincount(rec, weights=u, minlength=r.counts.shape[0])
+        starts[sh].append((lane % per_shard, n_real[sh],
+                           n_real[sh] + np.cumsum(per_rec) - per_rec))
+        n_real[sh] += int(u.sum())
+        k, p = r.counts.shape
+        scanned = (hits[lane, :k, :p] - (r.counts - nb))[rec, mem] > 0
+        for i in np.flatnonzero(scanned):
+            if cols["plain"][i]:
+                unit, off = bpr, -cols["blk_off"][i]
+            else:
+                unit = cols["chunk"][i] * cols["stride"][i] // bb
+                off = int(cols["b_off"][i] >= cols["stride"][i])
+            hit_rows[sh].setdefault(int(unit), []).append(
+                (rec[i], mem[i] * max_ways + 1, b_first[rec[i], mem[i]] % sets,
+                 off, cols["blk_off"][i], cols["nb"][i], u[i],
+                 cols["unit_start"][i], cols["nv"][i], lane % per_shard, bpr))
+    banks = max(d.banks for d in drams_b)
+    # a key is its bank times the span, plus its place in its lane
+    keyspan = _padded(keyspan)
+    if (banks + 1) * keyspan >= 2 ** 31:
+        raise OverflowError(
+            f"a lane of {keyspan} DRAM row visits over {banks} banks "
+            "overflows the int32 sort key; split multi-frame sweeps into "
+            "per-frame lane calls")
+    segs = [np.concatenate(a) if a else np.zeros((0, len(_SEG)), np.int64)
+            for a in segs]
+    n_units = _padded(max(n_real))
+    n_seg = _padded(max(len(a) for a in segs), least=8)
+    seg = np.zeros((shards, n_seg, len(_SEG)), np.int64)
+    seg[:, :, 0] = n_units                      # padding: no units
+    for sh, a in enumerate(segs):
+        seg[sh, :len(a)] = a
+    pieces, row_len = _sort_rows(starts, n_real)
+    n_rows = _padded(max(len(a) for a in pieces), least=8)
+    rows = np.zeros((shards, n_rows, 3), np.int64)
+    rows[:, :, 2] = -1                          # padding: no lane
+    for sh, a in enumerate(pieces):
+        rows[sh, :len(a)] = a
+    arrays = [seg, np.asarray(n_real).reshape(shards, 1), rows]
+    groups = []
+    length = r_pad * sets                       # ordinals the codes cover
+    for unit in sorted({b for g in hit_rows for b in g}):
+        n_hit = _padded(max(len(g.get(unit, ())) for g in hit_rows), least=8)
+        table = np.zeros((shards, n_hit, len(_HIT)), np.int64)
+        table[:, :, 1] = -2 * max_ways * r_pad  # padding: matches no code
+        table[:, :, 7] = n_units
+        table[:, :, 10] = 1
+        for sh, g in enumerate(hit_rows):
+            a = np.asarray(g.get(unit, []), np.int64).reshape(-1, len(_HIT))
+            table[sh, :len(a)] = a
+        arrays.append(table)
+        groups.append((unit, -(-length // unit) + 2))
+    return _RowsPlan(
+        arrays=tuple(a.astype(np.int32) for a in arrays),
+        static=(n_units, width, keyspan, banks, per_shard, max_ways,
+                row_len, tuple(groups)))
+
+
+def _sort_rows(starts, n_real, rows: int = 128) -> tuple[list, int]:
+    """Cut each shard's units into about ``rows`` runs of whole records
+    of one lane, each at most ``row_len`` units: the rows the device
+    sorts side by side.  ``starts`` holds, per shard and lane, the lane's
+    first unit and its records' first units.  Returns per shard the
+    (first unit, end, lane) of each run, and ``row_len``."""
+    lanes = [[(lane, np.append(rec_at, per_shard[i + 1][1]
+                               if i + 1 < len(per_shard) else total))
+              for i, (lane, _, rec_at) in enumerate(per_shard)]
+             for per_shard, total in zip(starts, n_real)]
+    biggest = max([int(np.diff(b).max(initial=1)) for a in lanes
+                   for _, b in a], default=1)
+    row_len = _padded(max(biggest, -(-max(n_real) // rows)))
+    out = []
+    for per_shard in lanes:
+        cuts = []
+        for lane, bounds in per_shard:
+            at, end = int(bounds[0]), int(bounds[-1])
+            while at < end:
+                stop = bounds[np.searchsorted(bounds, at + row_len,
+                                              side="right") - 1]
+                cuts.append((at, int(stop), lane))
+                at = int(stop)
+        out.append(cuts)
+    return out, row_len
+
+
+@functools.lru_cache(maxsize=32)
+def _rows_engine(n_units: int, width: int, keyspan: int, banks: int,
+                 lanes: int, max_ways: int, row_len: int, groups: tuple):
+    body = jax.vmap(functools.partial(
+        _record_rows, n_units=n_units, width=width, keyspan=keyspan,
+        banks=banks, lanes=lanes, max_ways=max_ways, row_len=row_len,
+        groups=groups))
+
+    def run(codes, *plan):
+        # the record program's (lanes, records, rounds, sets) hit codes,
+        # split into the shards' runs of lanes
+        shards = plan[0].shape[0]
+        return body(codes.reshape(shards, -1, *codes.shape[1:]),
+                    *plan).reshape(-1, 4)
+    return jax.jit(run)
+
+
+def _record_rows(codes, seg, n_real, rows, *hit_tables, n_units: int,
+                 width: int, keyspan: int, banks: int, lanes: int,
+                 max_ways: int, row_len: int, groups: tuple):
+    """One shard of compacted lanes reduced to counts on the device: per
+    lane its missed blocks, the NVDLA's missed blocks, DRAM row hits,
+    and the row hits of the NVDLA's missed blocks, (lanes, 4) int32.
+
+    Row hits depend only on the sequence of missed blocks: a unit's
+    missed blocks in one DRAM row (a visit) are all row hits but the
+    first, which hits when its bank's last visit before it, in any
+    unit, was to the same row.  Sorting the visits by bank, then trace
+    order, puts each next to the one before it; the sort runs on rows
+    of whole records side by side, and a table of each row's first and
+    last visit per bank joins the rows.  A visit
+    whose blocks all hit in the LLC drops out: the round scan's hits
+    (``codes``, as ``cache.record_lane_scan`` gives them) are laid out
+    by member ordinal, unit by unit, for each segment they hit, and
+    each unit's fully hit visits added to the units as a bit mask.
+    Fields reach the units as cumulative sums of their changes at
+    segment starts, and the hits as windows a segment long: no gather
+    or scatter of one element per unit.  Exact as ``_record_miss_runs``
+    and ``_lane_metrics_from_runs`` give it (tests/test_device_rows.py).
+    """
+    i32 = jnp.int32
+    # each unit's segment fields: their changes at segment starts, summed
+    change = seg - jnp.concatenate([jnp.zeros_like(seg[:1]), seg[:-1]])
+    (start, plain, b_off, stride, chunk, count, blk_off, row0, nv, tk0,
+     tk_step, nb, lane, bb, bpr, n_banks) = jnp.cumsum(
+        jnp.zeros((seg.shape[1], n_units), i32).at[:, seg[:, 0]].add(
+            change.T, mode="drop"), axis=1)
+    i = jnp.arange(n_units, dtype=i32)
+    u = i - start
+    plain = plain > 0
+
+    def block(j):                    # the member ordinal of access j
+        return (b_off + j * stride) // bb
+
+    j0 = u * chunk
+    lo = jnp.where(plain, jnp.maximum(0, u * bpr - blk_off),
+                   block(j0) + ((j0 > 0) & (block(j0 - 1) == block(j0))))
+    hi = jnp.where(plain, jnp.minimum(nb, (u + 1) * bpr - blk_off),
+                   block(jnp.minimum(j0 + chunk, count) - 1) + 1)
+    hi = jnp.where(i < n_real[0], hi, lo)
+    r_lo = (blk_off + lo) // bpr
+    t = jnp.arange(width, dtype=i32)[:, None]       # visits: (T, units)
+    rr = r_lo + t
+    blocks = jnp.maximum(0, jnp.minimum(hi, (rr + 1) * bpr - blk_off)
+                         - jnp.maximum(lo, rr * bpr - blk_off))
+    # the round scan's hits: fully hit visits, and how many hits
+    mask = jnp.zeros(n_units + max([n for _, n in groups], default=0), i32)
+    found = jnp.zeros((lanes, 2), i32)
+    for (unit, n_u), table in zip(groups, hit_tables):
+        got, n_hit = _hit_units(codes, table, unit, n_u, width, lanes)
+        mask = jax.lax.scatter_add(
+            mask, table[:, 7:8], got, jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1,), inserted_window_dims=(),
+                scatter_dims_to_operand_dims=(0,)))
+        found = found + n_hit
+    seen = (blocks > 0) & ((mask[None, :n_units] >> t) & 1 == 0)
+    is_nv = jnp.broadcast_to(nv > 0, seen.shape)
+    # the bank carry.  Each row (``rows``: a run of whole records of one
+    # lane, in trace order) is sorted by bank, then trace order, so each
+    # visit follows the one before it in its bank; a bank's first visit
+    # in a row takes the bank's last one from the rows before
+    big = jnp.iinfo(jnp.int32).max
+    row = row0 + rr
+    key = jnp.where(seen, (row % n_banks) * keyspan + tk0 + u * tk_step + t,
+                    big)
+    both = jnp.pad(jnp.concatenate([key, row * 2 + is_nv]),
+                   ((0, 0), (0, row_len)), constant_values=big)
+
+    def cut(first, end):
+        w = jax.lax.dynamic_slice(both, (0, first), (2 * width, row_len))
+        live = jnp.arange(row_len, dtype=i32) < end - first
+        return (jnp.where(live, w[:width], big).reshape(-1),
+                w[width:].reshape(-1))
+
+    key, packed = jax.lax.sort(jax.vmap(cut)(rows[:, 0], rows[:, 1]),
+                               num_keys=1)
+    same = ((key[:, 1:] < big) & (key[:, 1:] // keyspan
+                                  == key[:, :-1] // keyspan)
+            & (packed[:, 1:] // 2 == packed[:, :-1] // 2))
+    carry = jnp.sum(same, axis=1, dtype=i32)
+    carry_nv = jnp.sum(same & (packed[:, 1:] % 2 == 1), axis=1, dtype=i32)
+    edges = jax.vmap(lambda k: jnp.searchsorted(
+        k, jnp.arange(banks + 1, dtype=i32) * keyspan))(key)
+    there = edges[:, 1:] > edges[:, :-1]                 # (rows, banks)
+    first = jnp.take_along_axis(packed, edges[:, :-1], axis=1)
+    last = jnp.take_along_axis(packed, jnp.maximum(edges[:, 1:] - 1, 0),
+                               axis=1)
+    def join(state, at):
+        held, prev_lane = state
+        lane_k, there_k, first_k, last_k = at
+        held = jnp.where(lane_k == prev_lane, held, -1)
+        same = there_k & (held >= 0) & (first_k // 2 == held // 2)
+        return ((jnp.where(there_k, last_k, held), lane_k),
+                (jnp.sum(same, dtype=i32),
+                 jnp.sum(same & (first_k % 2 == 1), dtype=i32)))
+
+    _, (extra, extra_nv) = jax.lax.scan(
+        join, (jnp.full((banks,), -1, i32), jnp.int32(-1)),
+        (rows[:, 2], there, first, last))
+    carry = carry + extra
+    carry_nv = carry_nv + extra_nv
+
+    def per_lane(x, at):
+        return jnp.stack([jnp.sum(jnp.where(at == k, x, 0), dtype=i32)
+                          for k in range(lanes)])
+
+    lanes_of = jnp.broadcast_to(lane, seen.shape)
+    total = per_lane(blocks, lanes_of) - found[:, 0]
+    nv_total = per_lane(jnp.where(is_nv, blocks, 0), lanes_of) - found[:, 1]
+    return jnp.stack([
+        total, nv_total,
+        total - per_lane(seen, lanes_of) + per_lane(carry, rows[:, 2]),
+        nv_total - per_lane(seen & is_nv, lanes_of)
+        + per_lane(carry_nv, rows[:, 2])], axis=1)
+
+
+def _hit_units(codes, table, unit: int, n_u: int, width: int, lanes: int):
+    """The round scan's hits in one group of segments, each laid out by
+    its ordinals, ``unit`` blocks to a unit, ``n_u`` units from its
+    first: per segment and unit the bit mask of its fully hit visits,
+    (segments, n_u); and per lane the hits, all and the NVDLA's,
+    (lanes, 2)."""
+    i32 = jnp.int32
+    code, bmod = table[:, 1, None, None], table[:, 2, None, None]
+    r_pad, sets = codes.shape[2], codes.shape[3]
+    c = codes[table[:, 9], table[:, 0]].astype(i32)     # (S, r_pad, sets)
+    arrival = code + jnp.arange(r_pad, dtype=i32)[None, :, None]
+    flags = functools.reduce(jnp.logical_or, [
+        c[:, k:k + 1, :] == arrival for k in range(r_pad)])
+    # ordinal order: arrival q in set s is ordinal q*sets + (s - bmod) %
+    # sets, so the sets below bmod follow the arrival after
+    s = jnp.arange(sets, dtype=i32)[None, None, :]
+    zero = jnp.zeros_like(flags[:, :1])
+    line = jnp.where(s >= bmod, jnp.concatenate([flags, zero], axis=1),
+                     jnp.concatenate([zero, flags], axis=1))
+    line = line.reshape(line.shape[0], -1)                # ordinal + bmod
+    line = jnp.pad(line, ((0, 0), (unit, n_u * unit + 1)))
+    grid = jax.vmap(lambda row, a: jax.lax.dynamic_slice(
+        row, (a,), (n_u * unit,)))(line, unit + table[:, 2] + table[:, 3])
+    first = jax.vmap(lambda row, a: row[a])(line, unit + table[:, 2])
+    off, blk_off, nb, units, nv, bpr = (table[:, c, None]
+                                        for c in (3, 4, 5, 6, 8, 10))
+    pos = jnp.arange(n_u * unit, dtype=i32)[None, :]
+    g = pos // unit
+    o = pos + off
+    real = (o >= 0) & (o < nb) & (g < units)
+    hit = grid & real
+    # a chunk starting mid-block takes its first block in with unit 0
+    extra = (off[:, 0] > 0) & (nb[:, 0] > 0) & (units[:, 0] > 0)
+    lo = jnp.where(g == 0, 0, jnp.maximum(0, g * unit + off))
+    t_o = (blk_off + o) // bpr - (blk_off + lo) // bpr
+    lead = (jnp.arange(n_u)[None, :] == 0) & extra[:, None]
+    mask = jnp.zeros((grid.shape[0], n_u), i32)
+    for t in range(width):
+        here = real & (t_o == t)
+        some = jnp.any(here.reshape(-1, n_u, unit), axis=-1)
+        full = ~jnp.any((here & ~hit).reshape(-1, n_u, unit), axis=-1)
+        if t == 0:
+            some = some | lead
+            full = full & (~lead | first[:, None])
+        mask = mask + ((some & full).astype(i32) << t)
+    n_hit = jnp.sum(hit, axis=1, dtype=i32) + (extra & first).astype(i32)
+    at = table[:, 9]
+    return mask, jnp.stack([
+        jnp.stack([jnp.sum(jnp.where(at == k, n_hit, 0), dtype=i32),
+                   jnp.sum(jnp.where((at == k) & (nv[:, 0] > 0), n_hit, 0),
+                           dtype=i32)])
+        for k in range(lanes)])
 
 
 def _record_rounds(r: LaneRecords, llc: LLCConfig) -> np.ndarray:
@@ -1545,12 +1965,14 @@ def _record_rounds(r: LaneRecords, llc: LLCConfig) -> np.ndarray:
 
 def _record_miss_runs(r: LaneRecords, llc: LLCConfig, codes: np.ndarray,
                       max_ways: int) -> tuple:
-    """A compacted lane's missed-block runs in the order of its
-    uncompacted trace: every chunk's newly touched blocks (a block its
-    previous chunk already touched is a hit), less the round scan's
-    hits (``cache.record_lane_scan``'s codes).  Returns
-    ``(first_blocks, n_blocks, member)`` int64 arrays, ``member`` the
-    flat (record, member) index."""
+    """The host oracle of ``_record_rows``: a compacted lane's
+    missed-block runs in the order of its uncompacted trace: every
+    chunk's newly touched blocks (a block its previous chunk already
+    touched is a hit), less the round scan's hits
+    (``cache.record_lane_scan``'s codes).  Returns ``(first_blocks,
+    n_blocks, member)`` int64 arrays, ``member`` the flat (record,
+    member) index, for ``_lane_metrics_from_runs``.  The record path
+    itself never calls it (tests/test_device_rows.py)."""
     bb, sets = llc.block_bytes, llc.sets
     n_rec, p = r.counts.shape
     base, stride, count, chunk = r.bases, r.strides, r.counts, r.chunks

@@ -16,7 +16,10 @@ Leaf spans, in the order one lane batch runs them:
 ``LANE_PLAN`` -> ``DISPATCH`` -> ``FETCH`` -> ``MISS_RUNS`` ->
 ``DRAM_ROWS``; a campaign then records its results (``RECORD``).  The
 interference path plans its lanes (``LANE_PLAN``) and compacts them
-(``COMPACT``) before its first lane batch.
+(``COMPACT``) before its first lane batch.  A batch of compacted lanes
+runs ``LANE_PLAN`` -> ``DISPATCH`` -> ``FETCH`` -> ``DISPATCH`` ->
+``FETCH``: the record program, its hits, then the device reduction of
+its lanes and their counts, with no ``MISS_RUNS`` or ``DRAM_ROWS``.
 ``CAMPAIGN`` and ``LANE_BATCH`` are parents: they own only the time
 their leaves leave.
 """
@@ -48,6 +51,7 @@ PROGRAMS = "sweep.programs"        # lane programs dispatched
 MISS_WIDTH = "sweep.miss_width"    # miss-bit widths W of collecting programs
 LANE_SEGMENTS = "sweep.lane_segments"          # records the programs scan
 LANE_SEGMENTS_RAW = "sweep.lane_segments_raw"  # the same, uncompacted
+DEVICE_REDUCED_LANES = "sweep.device_reduced_lanes"  # lanes counted on device
 
 _counts: dict[str, int] = {}
 _lock = threading.Lock()

@@ -197,3 +197,48 @@ def test_full_frame_record_engine_compiles(monkeypatch, one_chip,
     compiled = real(*got["static"]).lower(
         *(_on_chip(one_chip, s, d) for s, d in got["shapes"])).compile()
     _fits(compiled, hbm_bytes)
+
+
+def test_full_frame_row_reduction_compiles(monkeypatch, one_chip,
+                                           hbm_bytes):
+    """The device reduction of the same five compacted frame lanes
+    (``sweep._record_rows``) at their real unit and visit counts, fed
+    the record engine's hit codes.  Every member is taken to have round
+    scan hits, more than the frame has (about 1.05 M of them, all but
+    about 0.4 M in the solo lane's plain records)."""
+    from repro.campaign import CampaignSpec, GeometrySpec, MixSpec, ModelSpec
+    from repro.campaign.executor import run_batch
+    from repro.core.dram import DRAMConfig
+
+    points = CampaignSpec(
+        name="frame", models=(ModelSpec(window_bursts=None),),
+        geometries=(GeometrySpec(size_kib=2048, block=64, ways=8),),
+        mixes=tuple(MixSpec(n, "dram") for n in range(5))).expand()
+    got = {}
+
+    def program(recs, cfgs_b):
+        got.update(recs=recs, cfgs=cfgs_b)
+        raise _Captured
+
+    monkeypatch.setattr(sweep, "_record_program", program)
+    with pytest.raises(_Captured):
+        run_batch(points, points[0].model.trace())
+    recs, cfgs = got["recs"], got["cfgs"]
+    s_pad = max(r.raw.shape[0] for r in recs)
+    n_mem = max(r.members for r in recs)
+    r_pad = max(int(sweep._record_rounds(r, c).max()) for r, c
+                in zip(recs, cfgs))
+    hits = np.zeros((len(recs), s_pad, n_mem), np.int64)
+    for row, r in enumerate(recs):
+        k, p = r.counts.shape
+        hits[row, :k, 0] = r.counts[:, 0]     # every NVDLA member hit
+    plan = sweep._record_rows_plan(recs, cfgs, [DRAMConfig()] * len(recs),
+                                   hits, r_pad, 8)
+    n_units, width = plan.static[:2]
+    assert n_units >= 1923892 and width == 2
+    codes = (len(recs), s_pad, r_pad, max(c.sets for c in cfgs))
+    compiled = sweep._rows_engine(*plan.static).lower(
+        _on_chip(one_chip, codes, jnp.int8),
+        *(_on_chip(one_chip, a.shape, a.dtype) for a in plan.arrays)
+    ).compile()
+    _fits(compiled, hbm_bytes)

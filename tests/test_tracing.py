@@ -231,3 +231,65 @@ def test_counts_from_many_threads_are_not_lost():
         sys.setswitchinterval(switch)
     assert (tracing.counters()["test.threads"] - before
             == threads * per_thread)
+
+
+@pytest.mark.parametrize("frame", [True, False], ids=["frame", "window"])
+def test_record_path_reduces_lanes_on_the_device(monkeypatch, tmp_path,
+                                                 frame):
+    """A whole-frame campaign (cut to the frame's first op) runs its
+    lanes compacted, and the device reduces each to counts: the host
+    fetches the per-record hits and four counts a lane, and opens no
+    miss-run or DRAM-row span.  The windows never reach the record
+    path."""
+    from repro.campaign import CampaignSpec, GeometrySpec, MixSpec, ModelSpec
+    from repro.campaign import spec as spec_module
+
+    full = traces.network_trace
+    monkeypatch.setattr(traces, "network_trace",
+                        lambda regions=traces.REGIONS:
+                        full(max_ops=1, regions=regions))
+    spec_module._model_trace.cache_clear()
+    fetched, opened = [], []
+    for name in ("_record_engine", "_rows_engine"):
+        made = getattr(sweep, name)
+
+        def engine(*static, made=made):
+            program = made(*static)
+
+            def run(*arrays):
+                out = program(*arrays)
+                fetched.append(jax.tree.leaves(out))
+                return out
+            return run
+        monkeypatch.setattr(sweep, name, engine)
+    span = tracing.span
+
+    def recorded(name):
+        opened.append(name)
+        return span(name)
+    monkeypatch.setattr(tracing, "span", recorded)
+    if frame:
+        spec = CampaignSpec(
+            name="frame", models=(ModelSpec(window_bursts=None),),
+            geometries=(GeometrySpec(size_kib=32, block=64, ways=8),),
+            mixes=tuple(MixSpec(k, "dram") for k in (0, 2, 4)))
+    else:
+        spec = example_spec(points=4, window_bursts=256)
+    before = tracing.counters()
+    try:
+        res = run_campaign(spec, str(tmp_path))
+    finally:
+        spec_module._model_trace.cache_clear()
+    got = _delta(before, tracing.counters())
+    assert res.completed == len(spec.expand())
+    if not frame:
+        assert tracing.DEVICE_REDUCED_LANES not in got and not fetched
+        assert tracing.MISS_RUNS in opened
+        return
+    (hits, _), (counts,) = fetched       # the codes stay on the device
+    assert got[tracing.DEVICE_REDUCED_LANES] == 3
+    assert hits.shape[0] == counts.shape[0] == 3 and counts.shape[1] == 4
+    assert got[tracing.FETCH_BYTES] == hits.nbytes + counts.nbytes
+    assert tracing.MISS_RUNS not in opened
+    assert tracing.DRAM_ROWS not in opened
+    assert opened.count(tracing.FETCH) == 2
