@@ -19,7 +19,8 @@ Emits ``BENCH_noc.json`` (override with ``BENCH_NOC_JSON``) and
 asserts the acceptance properties inline: p99 degrades superlinearly
 in node count, partitioning strictly recovers p99 at max contention,
 the solo-farm lane record is bit-identical to
-``interference_lane_metrics``, and the token-bundle switch matches the
+``interference_lane_metrics`` and to the per-access
+``simulate_dbb_stream``, and the token-bundle switch matches the
 per-cycle reference on this suite's own schedules.
 """
 from __future__ import annotations
@@ -34,7 +35,10 @@ from repro.core.dram import DRAMConfig
 from repro.core.farm import (FarmConfig, farm_schedule, simulate_farm,
                              victim_window)
 from repro.core.noc import NoCConfig, NoCSwitch, simulate_reference
-from repro.core.sweep import MixConfig, interference_lane_metrics
+from repro.core import traces
+from repro.core.socsim import simulate_dbb_stream
+from repro.core.sweep import (MixConfig, interference_lane_metrics,
+                              nvdla_chunks)
 from repro.utils.stats import latency_summary
 
 # edge-node shared LLC: small enough that "llc"-sized co-runner working
@@ -83,6 +87,28 @@ def _check_bundle_parity(counts, *, max_bursts: int) -> int:
     return checked
 
 
+def _check_solo_per_access(solo, lane_segs, *, llc: LLCConfig,
+                           dram: DRAMConfig, t_llc_hit: int = 20) -> None:
+    """The solo farm lane against ``simulate_dbb_stream``, which replays
+    the lane access by access: its per-access latencies (a hit costs
+    ``t_llc_hit``, a row hit ``+ tCAS``) give the lane's counters, and
+    summed over each victim chunk, the farm's request latencies."""
+    lats = np.asarray(simulate_dbb_stream(traces.expand(lane_segs),
+                                          llc=llc, dram=dram).latencies,
+                      np.int64)
+    per_chunk = nvdla_chunks(lane_segs)[2]
+    want = np.add.reduceat(lats, np.cumsum(per_chunk) - per_chunk)
+    m = solo.metrics
+    got = (m.accesses, m.llc_hits, m.dram_row_hits, m.total_cycles)
+    ref = (lats.shape[0], int((lats == t_llc_hit).sum()),
+           int((lats == t_llc_hit + dram.t_cas_cycles).sum()),
+           int(lats.sum()))
+    if got != ref or not np.array_equal(solo.mem_latency, want):
+        raise AssertionError(
+            f"solo farm lane diverged from the per-access pipeline: "
+            f"(accesses, hits, row hits, cycles) {got} vs {ref}")
+
+
 def run(smoke: bool = False) -> list[tuple]:
     dram = DRAMConfig()
     counts = (0, 1, 2) if smoke else (0, 1, 2, 4)
@@ -104,7 +130,8 @@ def run(smoke: bool = False) -> list[tuple]:
         raise AssertionError(
             f"way partitioning did not recover p99 at n={nmax}: "
             f"{part[nmax][0]['p99']} vs {p99[nmax]}")
-    # … the solo farm's lane record is exactly the Fig. 6 solo lane …
+    # … the solo farm's lane is exactly the Fig. 6 solo lane, and both
+    # agree with the per-access pipeline, access by access …
     solo = base[0][1]
     lane_segs = victim_window("nvdla", max_bursts=max_bursts) * 2
     ref = interference_lane_metrics(lane_segs, llc=llc, dram=dram,
@@ -112,6 +139,7 @@ def run(smoke: bool = False) -> list[tuple]:
     if solo.metrics != ref:
         raise AssertionError("solo farm lane diverged from "
                              "interference_lane_metrics")
+    _check_solo_per_access(solo, lane_segs, llc=llc, dram=dram)
     # … and the token-bundle switch is bit-identical to per-cycle.
     parity = _check_bundle_parity((counts[0], nmax),
                                   max_bursts=512 if smoke else 1024)

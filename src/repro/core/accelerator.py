@@ -149,23 +149,17 @@ def op_stream_hit_rates(stream: CommandStream, mem: MemSystemConfig,
                         max_ops: int | None = None
                         ) -> list[tuple[float, float, float]]:
     """Exact per-op (weight, ifmap, ofmap) LLC hit rates from the
-    compressed segment engine, one pass over the whole network's DBB
-    trace with LLC state carried across ops — so an op's ifmap reads
-    really do hit on its producer's still-resident ofmap blocks, and a
+    segment-lane engine, one pass over the whole network's DBB trace
+    with LLC state carried across ops — so an op's ifmap reads really
+    do hit on its producer's still-resident ofmap blocks, and a
     restreamed weight region really is warm.  This is what
     ``mode="simulated"`` feeds into ``op_cycles`` in place of the
-    closed-form stream model (the ROADMAP item: the sim no longer just
-    validates the closed form, it can drive it)."""
-    from repro.core import traces
-    from repro.core.cache import simulate_segments
-
-    ops = stream.accel_ops[:max_ops] if max_ops else stream.accel_ops
+    closed-form stream model: ``op_stream_hit_rates_grid`` over one
+    geometry."""
     if mem.llc is None:
+        ops = stream.accel_ops[:max_ops] if max_ops else stream.accel_ops
         return [(0.0, 0.0, 0.0)] * len(ops)
-    per_op = traces.network_op_segments(stream, max_ops)
-    flat = [s for segs in per_op for s in segs]
-    res = simulate_segments(flat, mem.llc, per_segment=True)
-    return _fold_op_stream_rates(per_op, res.per_segment_hits)
+    return op_stream_hit_rates_grid(stream, [mem.llc], max_ops)[0]
 
 
 def _fold_op_stream_rates(per_op, per_segment_hits
@@ -193,9 +187,8 @@ def op_stream_hit_rates_grid(stream: CommandStream,
     """``op_stream_hit_rates`` for a whole geometry grid at once: the
     full-network trace replays through the bucketed vmapped segment-lane
     engine (``repro.core.sweep.segment_lane_hit_counts``), so an N-point
-    simulated Fig. 5 sweep costs a handful of compiled lane programs
-    instead of N serial whole-frame passes.  ``max_ops`` truncates the
-    stream like the pointwise function's parameter (prefix replay —
+    simulated Fig. 5 sweep costs a handful of compiled lane programs.
+    ``max_ops`` truncates the stream to its first ops (prefix replay —
     smoke-scale grids).  Returns one per-op rate list per config,
     exactly what each ``accel_time_s(hit_rates=...)`` call needs."""
     from repro.core import traces

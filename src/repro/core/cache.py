@@ -1,42 +1,34 @@
 """Set-associative LLC simulator — exact, vectorized, runtime-configurable.
 
 The FireSim LLC model is runtime-configurable in sets/ways/block size
-without an FPGA rebuild; this is the same knob set, as pure JAX.  State
-is (tags, age) of shape (sets, ways); each access updates one set with
-true LRU.  Three execution paths, all bit-identical in final state and
-hit counts (tests/test_traces.py proves parity):
+without an FPGA rebuild; this is the same knob set, as pure JAX.  Every
+path is true LRU, allocate-on-miss, and all three give bit-identical
+hit counts (tests/test_traces.py, tests/test_sweep.py):
 
 * **exact per-access scan** (``simulate_trace``): one ``lax.scan`` step
-  per access — the reference semantics, used on unit-test traces and as
-  the parity oracle;
-* **compressed segment engine** (``simulate_segments``): a DBB stream is
+  per access on (tags, age) state of shape (sets, ways) — the reference
+  semantics and the test oracle.  It alone replays a trace of any
+  stride;
+* **segment lane** (``segment_lane_scan``): a DBB stream
   run-length-compressed into ``(base, stride, count)`` segments
-  (``repro.core.traces``).  A sequential segment is analytically
-  predictable under LRU, so it is retired either
+  (``repro.core.traces``) replays one segment per step, a per-set round
+  scan (one block per set per round, intra-block repeats folded into
+  one LRU touch) plus a closed-form suffix for the blocks past
+  ``ways`` arrivals per set, so serial depth is O(segments * ways), not
+  O(accesses);
+* **record lane** (``record_lane_scan``): an interference lane
+  compacted into records of the arbiter's repeating pattern replays
+  one record per step.
 
-  - in **O(1) serial steps** (closed form): when the segment sweeps every
-    set at least ``ways`` times and none of its blocks are already
-    resident, every first touch misses, victims cycle through the ways in
-    prior-LRU order, and the final (tags, age) state and hit count are
-    written directly with no scan at all; or
-  - by the **per-set round scan**: one scan step retires one block *per
-    set* (``sets`` blocks at once, each with all its intra-block burst
-    repeats folded in), so serial depth drops from O(accesses) to
-    O(blocks / sets) — exact for warm/overlapping/partial segments where
-    the closed form does not apply.
+The lane kernels take the geometry as traced scalars on state padded to
+the largest geometry, so ``repro.core.sweep`` ``jax.vmap``-s them over
+a whole grid of (sets, ways, block_bytes) lanes as one compiled device
+program; a single lane is a batch of one.  They need every stride in
+``(0, block_bytes]`` (every DBB trace is 32 B-stride).
 
-  The exact per-access scan remains the fallback at segment boundaries
-  that compression cannot express (stride > block size).
-* **batched multi-geometry scan** (``repro.core.sweep``): (tags, age)
-  padded to the largest geometry in a sweep and ``jax.vmap``-ed over
-  (sets, ways, block_bytes) so a whole Fig. 5 grid compiles once and
-  runs as a single device program (``segment_lane_scan``); an
-  interference lane compacted into records of the arbiter's repeating
-  pattern runs one record per step (``record_lane_scan``).
-
-Used two ways: exactly, on sampled windows of the NVDLA DBB stream (the
-per-stream hit rates feed the accelerator timing model); and as the
-reference that validates the closed-form stream-locality model in
+Used two ways: exactly, on the NVDLA DBB stream (the per-stream hit
+rates feed the accelerator timing model); and as the reference that
+validates the closed-form stream-locality model in
 ``repro.core.accelerator`` (sequential-burst hit rate = 1 - 32/B).
 """
 from __future__ import annotations
@@ -118,7 +110,7 @@ def sequential_burst_trace(n_bursts: int, burst_bytes: int,
 
 
 # --------------------------------------------------------------------------
-# compressed segment engine
+# lane engines: compressed segments, geometry as *traced* operands
 # --------------------------------------------------------------------------
 def _first_access(blocks, base, stride, block_bytes):
     """Index (within the segment) of the first access landing in each of
@@ -133,137 +125,6 @@ def _last_access(blocks, base, stride, count, block_bytes):
     return jnp.minimum(count - 1, (lo + block_bytes - 1) // stride)
 
 
-def _block_counts(blocks, base, stride, count, block_bytes):
-    """Exact number of segment accesses landing in each block of `blocks`
-    (accesses are base + j*stride for j in [0, count))."""
-    return (_last_access(blocks, base, stride, count, block_bytes)
-            - _first_access(blocks, base, stride, block_bytes)
-            + 1).astype(jnp.int32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("sets", "ways", "m_pad", "collect"))
-def _segment_rounds_grouped(state, b_firsts, n_blockss, bases, strides,
-                            counts, block_bytes,
-                            *, sets: int, ways: int, m_pad: int,
-                            collect: bool = False):
-    """Per-set round scan over a *group* of segments (one device program
-    per group, no per-segment dispatch).  Within a segment, round k
-    retires, for every set at once, that set's k-th arriving block, with
-    all its intra-block burst repeats folded into one LRU update
-    (touched way -> age 0, other ways += accesses).  Sets are
-    independent under LRU, so this is bit-identical to the per-access
-    scan while cutting serial depth from O(count) to
-    O(segments * n_blocks / sets).  Padding segments have count == 0 and
-    update nothing.
-
-    Returns per-segment hit counts; with ``collect`` it also returns the
-    per-(segment, round, set) miss bits, from which the caller
-    reconstructs the exact missed-block runs the DRAM model consumes."""
-    s_idx = jnp.arange(sets)
-
-    def per_segment(carry, meta):
-        b_first, n_blocks, base, stride, count = meta
-        off = (s_idx - b_first) % sets   # ordinal of a set's first block
-
-        def round_k(inner, k):
-            tags, age, hits = inner
-            i = off + k * sets           # block ordinal within segment
-            valid = i < n_blocks
-            blocks = b_first + i
-            t = (blocks // sets).astype(jnp.int32)
-            a = _block_counts(blocks, base, stride, count, block_bytes)
-            a = jnp.where(valid, a, 0)
-            match = tags == t[:, None]
-            hit = jnp.any(match, axis=1)
-            way = jnp.where(hit, jnp.argmax(match, axis=1),
-                            jnp.argmax(age, axis=1))
-            touched = jnp.arange(ways)[None, :] == way[:, None]
-            upd = valid[:, None]
-            tags = jnp.where(upd & touched, t[:, None], tags)
-            age = jnp.where(upd,
-                            jnp.where(touched, 0, age + a[:, None]), age)
-            hits = hits + jnp.sum(jnp.where(valid, a - 1 + hit, 0),
-                                  dtype=jnp.int32)
-            miss = (valid & ~hit) if collect else None
-            return (tags, age, hits), miss
-
-        tags, age = carry
-        (tags, age, hits), miss = jax.lax.scan(
-            round_k, (tags, age, jnp.int32(0)), jnp.arange(m_pad))
-        return (tags, age), (hits, miss)
-
-    state, (hits, miss) = jax.lax.scan(
-        per_segment, state,
-        (b_firsts, n_blockss, bases, strides, counts))
-    return state, hits, miss
-
-
-class _TouchedBlocks:
-    """Host-side conservative residency tracker: the union of block
-    intervals any earlier segment touched.  A segment disjoint from
-    every touched interval provably has no resident blocks, so its
-    disjointness can be decided without a device sync (the price of
-    conservatism: a revisit of a long-evicted range still takes the
-    round-scan path — exact either way)."""
-
-    def __init__(self):
-        self._iv: list[tuple[int, int]] = []   # merged, sorted
-
-    def overlaps(self, lo: int, hi: int) -> bool:
-        return any(a <= hi and lo <= b for a, b in self._iv)
-
-    def add(self, lo: int, hi: int) -> None:
-        merged = [(lo, hi)]
-        for a, b in self._iv:
-            if a <= merged[0][1] + 1 and merged[0][0] <= b + 1:
-                merged[0] = (min(a, merged[0][0]), max(b, merged[0][1]))
-            else:
-                merged.append((a, b))
-        self._iv = sorted(merged)
-
-
-@functools.partial(jax.jit, static_argnames=("sets", "ways"))
-def _segment_closed_form(state, b_first, n_blocks, a_interior, a_last,
-                         *, sets: int, ways: int):
-    """O(1)-serial state update for a full-sweep disjoint segment.
-
-    Preconditions (checked by the caller): every set receives >= ways
-    arrivals (n_blocks >= ways * sets), no segment block is resident
-    beforehand, and interior block access counts are uniform (stride
-    divides block size).  Then every first touch misses, so victims
-    cycle through the ways in prior-LRU order: arrival j of a set lands
-    on way rho[(j-1) % ways] where rho orders ways by descending prior
-    age (stable — matching argmax's first-index tie-break).  The final
-    occupants are each set's last `ways` arrivals and their ages are the
-    access counts of the arrivals after them.
-    """
-    tags, age = state
-    s_idx = jnp.arange(sets)
-    off = (s_idx - b_first) % sets
-    m_s = (n_blocks - off + sets - 1) // sets        # arrivals per set
-    rho = jnp.argsort(-age, axis=1, stable=True)     # (S, W) victim order
-    q = jnp.arange(ways)[None, :]
-    jstar = m_s[:, None] - ((m_s[:, None] - 1 - q) % ways)   # 1-indexed
-    i_star = off[:, None] + (jstar - 1) * sets
-    new_tag = ((b_first + i_star) // sets).astype(jnp.int32)
-    # age of the way holding arrival j* = accesses of arrivals after it;
-    # all interior blocks count a_interior, except the segment's very
-    # last block (partial) — in its set's suffix unless it *is* j*.
-    s_last = (b_first + n_blocks - 1) % sets
-    in_suffix_last = (s_idx[:, None] == s_last) & (jstar < m_s[:, None])
-    new_age = ((m_s[:, None] - jstar) * a_interior
-               + jnp.where(in_suffix_last, a_last - a_interior, 0)
-               ).astype(jnp.int32)
-    # scatter rank-ordered results back to way positions
-    tags = jnp.zeros_like(tags).at[s_idx[:, None], rho].set(new_tag)
-    age = jnp.zeros_like(age).at[s_idx[:, None], rho].set(new_age)
-    return (tags, age)
-
-
-# --------------------------------------------------------------------------
-# segment-lane engine: geometry as *traced* operands
-# --------------------------------------------------------------------------
 def _shift_down(x, r, n: int):
     """``y[i] = x[i + r]`` (False past the end of ``x``) for ``i < n``,
     with a traced ``0 <= r < 2**k`` where ``len(x) <= 2**k``.  A barrel
@@ -319,9 +180,8 @@ def segment_lane_scan(bases, strides, counts, r_needed, cold,
     be wrong) and whether the segment's byte range is provably disjoint
     from everything replayed before it.
 
-    Per segment the update is the same exact decomposition the
-    single-geometry engine uses, expressed uniformly so every lane runs
-    the same program:
+    Per segment the update is an exact decomposition, expressed
+    uniformly so every lane runs the same program:
 
     * a per-set round scan retires the first min(n_blocks, ways*sets)
       blocks (one block per set per round, all intra-block burst repeats
@@ -697,230 +557,3 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
         per_record, init, (bases, strides, counts, chunks, offsets,
                            periods, r_needed))
     return hits, codes
-
-
-@dataclasses.dataclass
-class SegmentSimResult:
-    hits: int
-    accesses: int
-    state: tuple                 # final (tags, age)
-    closed_form_segments: int    # retired with the O(1) analytic update
-    round_scanned_segments: int  # retired with the per-set round scan
-    expanded_segments: int       # fell back to the exact per-access scan
-    per_segment_hits: np.ndarray | None = None   # (n_segments,) int64
-    miss_runs: list | None = None  # [(first_block, n_blocks, seg_idx)]
-
-    @property
-    def hit_rate(self) -> float:
-        return self.hits / max(1, self.accesses)
-
-
-def _next_pow2(n: int) -> int:
-    return 1 << max(0, n - 1).bit_length()
-
-
-def _append_block_runs(runs: list, blocks: np.ndarray, idx: int) -> None:
-    """Compress a sorted array of distinct block indices into maximal
-    consecutive (first_block, n_blocks, segment_idx) runs."""
-    if blocks.size == 0:
-        return
-    cut = np.nonzero(np.diff(blocks) != 1)[0]
-    starts = np.concatenate([[0], cut + 1])
-    ends = np.concatenate([cut, [blocks.size - 1]])
-    for a, b in zip(starts, ends):
-        runs.append((int(blocks[a]), int(b - a + 1), idx))
-
-
-def simulate_segments(segments, cfg: LLCConfig, state=None, *,
-                      per_segment: bool = False,
-                      collect_miss_runs: bool = False) -> SegmentSimResult:
-    """Replay a compressed DBB trace (iterable of objects/tuples with
-    ``base, stride, count`` in bytes/bursts, stride > 0) through the
-    LLC, optionally continuing from a prior (tags, age) ``state``.
-
-    Dispatches each segment to the cheapest exact path: closed form when
-    it fully sweeps a provably non-resident region, per-set round scan
-    otherwise, exact per-access scan only when compression cannot
-    express the segment (stride > block size).  Consecutive round-scan
-    segments with the same round budget are fused into one device
-    program, and hit counters stay on device until the end, so the hot
-    loop performs no per-segment synchronization.  Hit counts and final
-    state are bit-identical to expanding the segments and running
-    ``simulate_trace`` on the concatenation.
-
-    ``per_segment`` additionally attributes hits to each input segment
-    (``result.per_segment_hits``, aligned with the input order — the
-    sim-driven accelerator model sums these by stream).
-    ``collect_miss_runs`` reconstructs the exact LLC-miss stream as
-    maximal runs of consecutive missed blocks in access order
-    (``result.miss_runs``) — the compressed currency of the closed-form
-    DRAM row model in ``repro.core.dram.segment_row_hits``.
-    """
-    sets, ways, bb = cfg.sets, cfg.ways, cfg.block_bytes
-    collect = collect_miss_runs
-    touched = _TouchedBlocks()
-    if state is None:
-        state = cold_state(sets, ways)
-    else:
-        # arbitrary warm state: anything may be resident, so no segment
-        # is provably disjoint (long ones still split fast — the
-        # prefix/suffix proof is dynamic and needs no tracker)
-        touched.add(-(1 << 62), 1 << 62)
-    accesses = 0
-    n_cf = n_rs = n_ex = 0
-    n_input = 0
-    # replay log, resolved to host values once at the end (device arrays
-    # are only synced after the whole trace is dispatched):
-    #   ("group", idxs, metas, hits_dev, miss_dev)
-    #   ("cf",    idx, first_block, n_blocks, hits_int)
-    #   ("ex",    idx, hit_bits_dev, blocks_dev)
-    order_log: list[tuple] = []
-    pending: list[tuple] = []  # (idx, (b_first, n_blocks, base, stride, cnt))
-    pending_m = 0
-
-    def flush():
-        nonlocal state, pending, pending_m
-        if not pending:
-            return
-        idxs = [i for i, _ in pending]
-        metas = [m for _, m in pending]
-        k_pad = _next_pow2(len(metas))
-        metas_p = metas + [(0, 0, 0, 1, 0)] * (k_pad - len(metas))
-        cols = list(np.asarray(metas_p, np.int32).T)
-        state, h, miss = _segment_rounds_grouped(
-            state, *cols, bb, sets=sets, ways=ways, m_pad=pending_m,
-            collect=collect)
-        order_log.append(("group", idxs, metas, h, miss))
-        pending, pending_m = [], 0
-
-    from repro.core.traces import segment_tuple
-
-    for idx, seg in enumerate(segments):
-        n_input = idx + 1
-        base, stride, count = segment_tuple(seg)
-        if count <= 0:
-            continue
-        if stride <= 0:
-            raise ValueError(
-                f"segment stride must be positive, got {stride} "
-                "(a repeated single address is not a compressible "
-                "sequential burst stream)")
-        accesses += count
-        if stride > bb:
-            # blocks are non-contiguous: expand and scan exactly
-            flush()
-            addrs = (base + jnp.arange(count) * stride) // bb
-            blocks_dev = addrs.astype(jnp.int32)
-            state, h = _scan_trace(state, blocks_dev,
-                                   sets=sets, ways=ways)
-            order_log.append(("ex", idx, h, blocks_dev))
-            touched.add(base // bb, (base + (count - 1) * stride) // bb)
-            n_ex += 1
-            continue
-        b_first = base // bb
-        b_last = (base + (count - 1) * stride) // bb
-        n_blocks = b_last - b_first + 1
-        uniform = bb % stride == 0
-        disjoint = not touched.overlaps(b_first, b_last)
-        if uniform and not disjoint and n_blocks >= 2 * (ways + 1) * sets:
-            # long warm segment: once every set has seen >= ways arrivals
-            # the cache holds exactly those arrivals (LRU always evicts a
-            # pre-segment resident before any arrival), so everything
-            # past a (ways+1)*sets-block prefix is provably non-resident
-            # no matter what was cached before.  Round-scan the prefix,
-            # closed-form the suffix.
-            split_block = b_first + (ways + 1) * sets
-            j_split = -(-(split_block * bb - base) // stride)
-            m = _next_pow2(ways + 1)
-            if pending and m != pending_m:
-                flush()
-            pending.append((idx, (b_first, split_block - b_first, base,
-                                  stride, j_split)))
-            pending_m = m
-            flush()
-            n_rs += 1
-            suf_base = base + j_split * stride
-            suf_count = count - j_split
-            n_blocks_suf = b_last - split_block + 1
-            lo = b_last * bb - suf_base
-            a_last = suf_count - (0 if lo <= 0 else -(-lo // stride))
-            state = _segment_closed_form(
-                state, split_block, n_blocks_suf, bb // stride, a_last,
-                sets=sets, ways=ways)
-            order_log.append(("cf", idx, split_block, n_blocks_suf,
-                              suf_count - n_blocks_suf))
-            n_cf += 1
-            touched.add(b_first, b_last)
-            continue
-        if n_blocks >= ways * sets and uniform and disjoint:
-            flush()
-            a_int = bb // stride
-            lo = b_last * bb - base
-            j_lo = 0 if lo <= 0 else -(-lo // stride)
-            a_last = count - j_lo
-            state = _segment_closed_form(
-                state, b_first, n_blocks, a_int, a_last,
-                sets=sets, ways=ways)
-            order_log.append(("cf", idx, b_first, n_blocks,
-                              count - n_blocks))
-            n_cf += 1
-        else:
-            m = _next_pow2(-(-n_blocks // sets))
-            if pending and m != pending_m:
-                flush()
-            pending.append((idx, (b_first, n_blocks, base, stride, count)))
-            pending_m = m
-            n_rs += 1
-        touched.add(b_first, b_last)
-    flush()
-
-    # resolve the log: total hits, optional per-segment attribution and
-    # miss-run reconstruction — device arrays sync here, once
-    hits = 0
-    per_seg = np.zeros(n_input, np.int64) if per_segment else None
-    miss_runs: list | None = [] if collect else None
-    for entry in order_log:
-        if entry[0] == "group":
-            _, idxs, metas, h_dev, miss_dev = entry
-            h = np.asarray(h_dev)
-            hits += int(h[:len(idxs)].sum())
-            if per_seg is not None:
-                for j, i in enumerate(idxs):
-                    per_seg[i] += int(h[j])
-            if collect:
-                mb = np.asarray(miss_dev)        # (k_pad, m_pad, sets)
-                for j, (b_first, n_blocks, _b, _s, _c) in enumerate(metas):
-                    k_idx, s_np = np.nonzero(mb[j])
-                    if k_idx.size == 0:
-                        continue
-                    off = (s_np - b_first) % sets
-                    blocks = b_first + np.sort(off + k_idx * sets)
-                    _append_block_runs(miss_runs, blocks, idxs[j])
-        elif entry[0] == "cf":
-            _, i, first_block, n_blocks, h_int = entry
-            hits += h_int
-            if per_seg is not None:
-                per_seg[i] += h_int
-            if collect:
-                miss_runs.append((first_block, n_blocks, i))
-        else:                                    # "ex"
-            _, i, h_dev, blocks_dev = entry
-            h = np.asarray(h_dev)
-            hits += int(h.sum())
-            if per_seg is not None:
-                per_seg[i] += int(h.sum())
-            if collect:
-                _append_block_runs(miss_runs,
-                                   np.asarray(blocks_dev)[~h], i)
-    return SegmentSimResult(hits=hits, accesses=accesses, state=state,
-                            closed_form_segments=n_cf,
-                            round_scanned_segments=n_rs,
-                            expanded_segments=n_ex,
-                            per_segment_hits=per_seg,
-                            miss_runs=miss_runs)
-
-
-def hit_rate_segments(segments, cfg: LLCConfig) -> float:
-    """LLC hit rate of a compressed trace (exact, never expands unless a
-    segment's stride exceeds the block size)."""
-    return simulate_segments(segments, cfg).hit_rate

@@ -493,19 +493,19 @@ def op_stream_hit_rates(ops, cfg: NPUConfig, mem: MemSystemConfig,
                         max_ops: int | None = None
                         ) -> list[tuple[float, float, float]]:
     """Exact per-op (weight, ifmap, ofmap) LLC hit rates of the NPU
-    workload from the segment engine — one pass over the whole
+    workload from the segment-lane engine — one pass over the whole
     workload trace with LLC state carried across ops, folded by stream
     exactly like the NVDLA path (this is what ``mode="simulated"``
     feeds ``op_cycles``)."""
-    from repro.core.cache import simulate_segments
+    from repro.core.sweep import segment_lane_hit_counts
 
     ops = tuple(ops)[:max_ops] if max_ops else tuple(ops)
     if mem.llc is None:
         return [(0.0, 0.0, 0.0)] * len(ops)
     per_op = workload_op_segments(ops, cfg)
     flat = [s for segs in per_op for s in segs]
-    res = simulate_segments(flat, mem.llc, per_segment=True)
-    return _fold_op_stream_rates(per_op, res.per_segment_hits)
+    return _fold_op_stream_rates(
+        per_op, segment_lane_hit_counts(flat, [mem.llc])[0])
 
 
 def npu_time_s(ops, *, npu: NPUConfig | None = None,

@@ -17,9 +17,10 @@ FAME-1 semantics on the paper's own topology.
 Performance: replay rides the chunked early-exit FAME-1 scheduler (the
 host-cycle scan stops as soon as the sink drains the trace, and all-stall
 host cycles are pre-compacted away — see ``repro.core.fame1``); for
-hit-rate-only questions the compressed segment engine in
-``repro.core.cache``/``repro.core.traces`` avoids per-access replay, and
-for latency *totals* ``simulate_dbb_segments`` composes it with the
+hit-rate-only questions the segment-lane engine
+(``repro.core.sweep``) replays the compressed trace of
+``repro.core.traces`` without per-access replay, and for latency
+*totals* ``simulate_dbb_segments`` runs it as a solo lane with the
 closed-form DRAM row model (``repro.core.dram.segment_row_hits``) so the
 whole pipeline result comes out of segment-level arithmetic — bit
 -identical to the per-access pipeline.  Address arrays go through
@@ -290,22 +291,25 @@ def simulate_dbb_segments(segments, *legacy, llc: LLCConfig | None = None,
     """Latency totals of the LLC -> DRAM pipeline over a *compressed*
     DBB trace, with no per-access replay on either side.
 
-    The segment LLC engine classifies hits and emits the exact miss
-    stream as runs of consecutive blocks; the closed-form DRAM row model
-    counts row hits over those runs with per-bank open-row carry.  Since
-    every per-access latency is determined by (llc hit?, dram row hit?),
-    the totals are bit-identical to ``simulate_dbb_stream`` on the
-    expanded trace (tests/test_socsim.py):
+    The trace runs as a solo interference lane (no co-runners) through
+    ``sweep.interference_lane_metrics_batch``: the segment LLC engine
+    classifies hits and emits the exact miss stream as runs of
+    consecutive blocks, and the closed-form DRAM row model counts row
+    hits over those runs with per-bank open-row carry.  Since every
+    per-access latency is determined by (llc hit?, dram row hit?), the
+    totals are bit-identical to ``simulate_dbb_stream`` on the expanded
+    trace (tests/test_dram_segments.py):
 
         total = T*t_llc_hit + misses*tCAS + row_misses*(tRP + tRCD)
 
     Requires ``dram.row_bytes % llc.block_bytes == 0`` (every standard
     geometry) so a missed block's row is independent of which burst in
-    the block missed.  Configs are keyword-only (``llc=``, ``dram=``);
-    positional use warns for one release.
+    the block missed, and every stride in ``(0, block_bytes]``
+    (``sweep.UnsupportedTraceError`` otherwise: ``simulate_dbb_stream``
+    replays such a trace).  Configs are keyword-only (``llc=``,
+    ``dram=``); positional use warns for one release.
     """
-    from repro.core.cache import simulate_segments
-    from repro.core.dram import segment_row_hits
+    from repro.core.sweep import MixConfig, interference_lane_metrics_batch
 
     if legacy:
         warnings.warn(_positional_config_warning("simulate_dbb_segments"),
@@ -317,15 +321,10 @@ def simulate_dbb_segments(segments, *legacy, llc: LLCConfig | None = None,
         raise ValueError(
             f"row_bytes {dram.row_bytes} not a multiple of block_bytes "
             f"{bb}: a block could straddle rows; use simulate_dbb_stream")
-    res = simulate_segments(segments, llc, collect_miss_runs=True)
-    row = segment_row_hits([(b * bb, bb, c) for b, c, _ in res.miss_runs],
-                           dram)
-    misses = res.accesses - res.hits
-    row_misses = misses - row.row_hits
-    total = (res.accesses * t_llc_hit
-             + misses * dram.t_cas_cycles
-             + row_misses * (dram.t_rp_cycles + dram.t_rcd_cycles))
-    return SegmentPipelineResult(total_cycles=int(total),
-                                 accesses=res.accesses,
-                                 llc_hits=res.hits,
-                                 dram_row_hits=row.row_hits)
+    (lane,) = interference_lane_metrics_batch(
+        segments, llcs=[llc], drams=[dram], mixes=[MixConfig()],
+        t_llc_hit=t_llc_hit)
+    return SegmentPipelineResult(total_cycles=lane.total_cycles,
+                                 accesses=lane.accesses,
+                                 llc_hits=lane.llc_hits,
+                                 dram_row_hits=lane.dram_row_hits)

@@ -17,6 +17,11 @@ grid compiles once and runs as a single device program:
   O(segments * max_ways) and full-frame multi-config sweeps (the trace
   lengths Fig. 5/6 actually need) fit in one program.
 
+Every interference lane runs through one entry point,
+``interference_lane_metrics_batch`` (whole-frame lanes compacted into
+records for ``cache.record_lane_scan``); a single lane is a batch of
+one.
+
 Padded ways are masked out of both tag match and victim selection, so
 each lane is bit-identical to the unbatched simulator at that geometry
 (tests/test_sweep.py).
@@ -30,15 +35,17 @@ Public API:
                             lane (``to_record``/``from_record`` for
                             JSON journaling);
 * ``SweepGrid``           — frozen typed result of the figure sweeps;
-* ``interference_lane_metrics``       — one lane -> ``LaneMetrics``,
-                            optionally LLC way-partitioned
-                            (``way_mask=``);
-* ``interference_lane_metrics_batch`` — many lanes as vmapped lane
+* ``interference_lane_metrics_batch`` — the one interference entry
+                            point: many lanes as vmapped lane
                             programs, optionally sharded over a
                             ``jax.sharding`` mesh (the campaign
                             executor's data-parallel path) and
                             optionally per-lane way-partitioned
                             (``way_masks=``);
+* ``interference_lane_metrics``       — one lane -> ``LaneMetrics``,
+                            the batch of one, optionally LLC
+                            way-partitioned (``way_mask=``);
+* ``step_lane_metrics``   — a serving step's marginal lane record;
 * ``compact_lane``        — one interleaved lane as ``LaneRecords``:
                             repeats of the arbiter's pattern, each run
                             in one step of ``cache.record_lane_scan``;
@@ -69,7 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import traces
-from repro.core.cache import LLCConfig, _append_block_runs
+from repro.core.cache import LLCConfig
 from repro.utils import tracing
 from repro.utils.env import as_address_array
 
@@ -277,18 +284,6 @@ def batched_hit_rates(byte_addrs, configs: list[LLCConfig]) -> jax.Array:
                     axis=1)
 
 
-def segment_sweep_hit_rates(segments, configs: list[LLCConfig]
-                            ) -> np.ndarray:
-    """(n_cfg,) exact hit rates of one *compressed* trace — each config
-    replayed through the segment engine (closed form / per-set rounds),
-    so whole-network windows are feasible where per-access expansion is
-    not.  Exactly ``hit_rate`` of the expanded trace, per config."""
-    from repro.core.cache import simulate_segments
-
-    return np.asarray([simulate_segments(segments, c).hit_rate
-                       for c in configs], np.float64)
-
-
 # --------------------------------------------------------------------------
 # segment-lane engine: vmapped segment replay over runtime geometry
 # --------------------------------------------------------------------------
@@ -347,9 +342,9 @@ def _lane_plan(trace: list, configs: list[LLCConfig]
     bucket's geometries — extra rounds in other lanes are masked no-ops)
     and whether the segment is provably cold (byte range disjoint, with
     block-alignment slack, from every earlier segment — all its arrivals
-    miss in every lane, so the closed form needs no rounds at all)."""
-    from repro.core.cache import _TouchedBlocks
-
+    miss in every lane, so the closed form needs no rounds at all).
+    The test is conservative: a revisit of a long-evicted range still
+    takes rounds, exact either way."""
     metas = [_segment_tuple(s) for s in trace]
     base = np.asarray([m[0] for m in metas], np.int64)
     stride = np.asarray([m[1] for m in metas], np.int64)
@@ -357,14 +352,18 @@ def _lane_plan(trace: list, configs: list[LLCConfig]
     live = count > 0
     last = base + np.maximum(count - 1, 0) * stride
     slack = max(c.block_bytes for c in configs) - 1
-    touched = _TouchedBlocks()
+    touched: list[tuple[int, int]] = []   # merged, sorted byte intervals
     cold = np.zeros(len(metas), bool)
-    for j in range(len(metas)):
-        if not live[j]:
-            continue
+    for j in np.flatnonzero(live):
         lo, hi = int(base[j] - slack), int(last[j] + slack)
-        cold[j] = not touched.overlaps(lo, hi)
-        touched.add(lo, hi)
+        cold[j] = not any(a <= hi and lo <= b for a, b in touched)
+        kept = []
+        for a, b in touched:
+            if a <= hi + 1 and lo <= b + 1:
+                lo, hi = min(a, lo), max(b, hi)
+            else:
+                kept.append((a, b))
+        touched = sorted(kept + [(lo, hi)])
     r = np.zeros(len(metas), np.int64)
     for c in configs:
         nb = last // c.block_bytes - base // c.block_bytes + 1
@@ -391,42 +390,24 @@ def _lane_meta_arrays(lanes: list[list]) -> tuple:
 
 
 class UnsupportedTraceError(ValueError):
-    """A lane trace the segment-lane engine cannot replay (a segment
-    stride outside ``(0, block_bytes]``).  The only batch failure the
-    campaign executor answers with the sequential path, which expands
-    such segments exactly."""
+    """A lane trace the lane engines cannot replay: a segment stride
+    outside ``(0, block_bytes]``.  Only the per-access paths
+    (``socsim.simulate_dbb_stream``, ``cache.simulate_trace``) replay
+    such a trace."""
 
 
 def _check_lane_support(lanes, configs) -> None:
-    int32_max = np.iinfo(np.int32).max
-    min_block = min(c.block_bytes for c in configs)
-    for trace in lanes:
-        total = 0
-        for seg in trace:
-            base, stride, count = _segment_tuple(seg)
-            if count <= 0:
-                continue
-            total += count
-            if stride <= 0 or stride > min_block:
-                raise UnsupportedTraceError(
-                    f"segment stride {stride} outside (0, {min_block}] — "
-                    "the segment-lane engine needs stride <= block_bytes "
-                    "in every lane; use segment_sweep_hit_rates for "
-                    "sparse-stride traces")
-            if base + count * stride > int32_max:
-                raise OverflowError(
-                    "segment addresses exceed int32 — the lane engine "
-                    "keeps metadata in 32-bit; rebase the trace")
-        if total > int32_max:
-            raise OverflowError(
-                f"lane trace has {total} accesses — the lane engine's "
-                "global LRU timestamp is int32; split multi-frame sweeps "
-                "into per-frame lane calls")
+    """`_check_lane_support_meta` over lanes of ``Segment``/tuples."""
+    _check_lane_support_meta(
+        [np.asarray([_segment_tuple(s) for s in trace],
+                    np.int64).reshape(-1, 3).T for trace in lanes], configs)
 
 
 def _check_lane_support_meta(lanes_meta, configs) -> None:
-    """`_check_lane_support` over (bases, strides, counts) array lanes —
-    the same constraints, vectorized."""
+    """Raise unless every lane of (bases, strides, counts) arrays is in
+    the lane engines' support: strides in ``(0, block_bytes]`` of the
+    smallest block (``UnsupportedTraceError``), addresses and the
+    lane's access total in int32 (``OverflowError``)."""
     int32_max = np.iinfo(np.int32).max
     min_block = min(c.block_bytes for c in configs)
     for base, stride, count in lanes_meta:
@@ -435,9 +416,10 @@ def _check_lane_support_meta(lanes_meta, configs) -> None:
         if np.any(bad):
             raise UnsupportedTraceError(
                 f"segment stride {int(stride[bad][0])} outside "
-                f"(0, {min_block}] — the segment-lane engine needs "
-                "stride <= block_bytes in every lane; use "
-                "segment_sweep_hit_rates for sparse-stride traces")
+                f"(0, {min_block}] — the lane engines need stride <= "
+                "block_bytes in every lane; replay sparse-stride traces "
+                "access by access (socsim.simulate_dbb_stream, "
+                "cache.simulate_trace)")
         if np.any(live & (base + count * stride > int32_max)):
             raise OverflowError(
                 "segment addresses exceed int32 — the lane engine "
@@ -491,7 +473,7 @@ def segment_lane_hit_counts(segments, configs: list[LLCConfig]
         raise ValueError(f"{len(lanes)} lane traces for "
                          f"{len(configs)} configs")
     with tracing.span(tracing.LANE_PLAN):
-        _check_lane_support(lanes, configs)
+        _check_lane_support(lanes if per_lane else lanes[:1], configs)
     n_seg = max((len(t) for t in lanes), default=0)
     out = np.zeros((len(configs), max(1, n_seg)), np.int64)
     for bucket in lane_buckets(configs):
@@ -751,6 +733,24 @@ def corunner_meta(nvdla_segs: list, *, llc: LLCConfig, mix: MixConfig,
     return bases[order], strides[order], counts[order], nv[order]
 
 
+def _split_sparse_corunners(b, s, c, nv, llc: LLCConfig) -> tuple:
+    """A lane's arrays as the lane engines take them.  Co-runners write
+    64 B lines, a stride past an LLC block narrower than a line, which
+    the engines do not replay as a run: each such line becomes a
+    one-access segment of stride ``block_bytes`` (a stride no record
+    continues).  The accesses and their order stay; only the segment
+    count grows, so callers report the unsplit lane's segments."""
+    split = ~nv & (s > llc.block_bytes)
+    if not split.any():
+        return b, s, c, nv
+    reps = np.where(split, np.maximum(c, 1), 1)
+    idx = np.repeat(np.arange(c.shape[0]), reps)
+    k = np.arange(idx.shape[0]) - np.repeat(np.cumsum(reps) - reps, reps)
+    one = split[idx]
+    return (b[idx] + k * s[idx], np.where(one, llc.block_bytes, s[idx]),
+            np.where(one, np.minimum(c[idx], 1), c[idx]), nv[idx])
+
+
 @dataclasses.dataclass(frozen=True)
 class LaneRecords:
     """One lane compacted into records (``cache.record_lane_scan``):
@@ -872,19 +872,14 @@ def _exact_runs(first, last, B, St, Cn, llc: LLCConfig):
 def _lane_metrics_from_runs(*, n_segments, accesses, hits, runs, bb, nv,
                             dram, t_llc_hit, nv_acc, nv_hits) -> LaneMetrics:
     """The shared lane reduction: exact LLC counts + miss runs
-    ((first_block, n_blocks, seg_idx) triples in access order, either a
-    list of tuples or a tuple of three aligned int64 arrays) ->
-    closed-form DRAM row hits -> ``_lane_metrics``.  The sequential and
-    the segment path end here; the record path reduces its lanes to the
-    same counts on the device (``_record_rows``) and ends in
-    ``_lane_metrics`` too."""
+    (``(first_blocks, n_blocks, seg_idx)``, three aligned int64 arrays
+    in access order) -> closed-form DRAM row hits -> ``_lane_metrics``.
+    The segment path and the farm's lanes end here; the record path
+    reduces its lanes to the same counts on the device
+    (``_record_rows``) and ends in ``_lane_metrics`` too."""
     from repro.core.dram import segment_row_hits
 
-    if isinstance(runs, tuple):
-        fb, nbk, sidx = (np.asarray(a, np.int64) for a in runs)
-    else:
-        arr = np.asarray(runs, np.int64).reshape(-1, 3)
-        fb, nbk, sidx = arr[:, 0], arr[:, 1], arr[:, 2]
+    fb, nbk, sidx = (np.asarray(a, np.int64) for a in runs)
     row = segment_row_hits((fb * bb, np.full(fb.shape[0], bb, np.int64),
                             nbk), dram)
     run_is_nv = np.asarray(nv, bool)[sidx]
@@ -984,16 +979,9 @@ def interference_lane_metrics(nvdla_segs: list, *, llc: LLCConfig,
                               chunk_bursts: int = 16,
                               t_llc_hit: int = 20,
                               way_mask: int | None = None) -> LaneMetrics:
-    """One interference lane, simulated exactly and reduced to the typed
-    ``LaneMetrics`` record a campaign point journals
-    (``repro.campaign``): the co-runner-interleaved compressed trace
-    goes once through the exact segment LLC engine (per-segment hit
-    attribution + exact miss runs), the miss runs through the
-    closed-form DRAM row model, and the latency total through the same
-    closed form as ``socsim.simulate_dbb_segments`` — so every field is
-    deterministic and internally consistent (the executor's guardrails
-    recompute the total from the counts and reject any record where
-    they disagree).
+    """One interference lane reduced to the typed ``LaneMetrics`` record
+    a campaign point journals (``repro.campaign``):
+    ``interference_lane_metrics_batch`` as a batch of one.
 
     ``mix.corunners=0`` (or ``mix.wss="l1"``) is the solo-NVDLA lane.
 
@@ -1001,40 +989,10 @@ def interference_lane_metrics(nvdla_segs: list, *, llc: LLCConfig,
     victim segments allocate only into ``way_mask``'s ways, co-runners
     into the complement.  The full mask is bit-exactly the
     unpartitioned lane."""
-    from repro.core.cache import simulate_segments
-
-    bb = llc.block_bytes
-    _check_row_block(llc, dram)
-    if way_mask is not None:
-        b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
-                                    chunk_bursts=chunk_bursts)
-        _check_lane_support_meta([(b, s, c)], [llc])
-        way_sels = partition_way_sels(nv, llc, way_mask)
-        hits, runs = _masked_lane_run(b, s, c, llc, way_sels)
-        n_seg = c.shape[0]
-        accesses = int(c.sum())
-        lane_hits = int(hits[:n_seg].sum())
-        if int(runs[1].sum()) != accesses - lane_hits:
-            raise RuntimeError(
-                "masked lane miss-run reconstruction disagrees with the "
-                f"kernel: {int(runs[1].sum())} missed blocks vs "
-                f"{accesses - lane_hits} misses")
-        return _lane_metrics_from_runs(
-            n_segments=n_seg, accesses=accesses, hits=lane_hits,
-            runs=runs, bb=bb, nv=nv, dram=dram, t_llc_hit=t_llc_hit,
-            nv_acc=int(c[nv].sum()),
-            nv_hits=int(hits[:n_seg][nv].sum()))
-    segs, nv = corunner_segments(nvdla_segs, llc=llc, mix=mix,
-                                 chunk_bursts=chunk_bursts)
-    res = simulate_segments(segs, llc, per_segment=True,
-                            collect_miss_runs=True)
-    counts = np.asarray([s.count for s in segs], np.int64)
-    return _lane_metrics_from_runs(
-        n_segments=len(segs), accesses=int(res.accesses),
-        hits=int(res.hits), runs=res.miss_runs, bb=bb,
-        nv=nv, dram=dram, t_llc_hit=t_llc_hit,
-        nv_acc=int(counts[nv].sum()),
-        nv_hits=int(res.per_segment_hits[nv].sum()))
+    return interference_lane_metrics_batch(
+        nvdla_segs, llcs=[llc], drams=[dram], mixes=[mix],
+        chunk_bursts=chunk_bursts, t_llc_hit=t_llc_hit,
+        way_masks=None if way_mask is None else [way_mask])[0]
 
 
 def lane_request_latencies(nvdla_segs: list, *, llc: LLCConfig, dram,
@@ -1050,41 +1008,32 @@ def lane_request_latencies(nvdla_segs: list, *, llc: LLCConfig, dram,
     counters (``accesses * t_llc_hit + misses * tCAS + row_misses *
     (tRP + tRCD)``), so it distributes exactly over segments: each
     segment's share uses its own access/hit counts plus its row hits
-    (attributed from the lane's miss runs).  ``corunner_segments``
-    emits exactly one victim segment per ``chunk_bursts``-burst chunk,
-    so the victim rows *are* the per-chunk service latencies — returned
-    in stream order alongside the lane's ``LaneMetrics``.  The
-    per-chunk latencies provably sum to ``metrics.total_cycles`` (the
-    identity's linearity; asserted here).
+    (attributed from the lane's miss runs).  ``corunner_meta`` emits
+    exactly one victim segment per ``chunk_bursts``-burst chunk, so the
+    victim rows *are* the per-chunk service latencies — returned in
+    stream order alongside the lane's ``LaneMetrics``.  The per-chunk
+    latencies provably sum to ``metrics.total_cycles`` (the identity's
+    linearity; asserted here).
 
-    ``way_mask`` partitions the LLC as in
-    ``interference_lane_metrics``."""
-    from repro.core.cache import simulate_segments
+    The lane runs through the way-masked kernel (``_masked_lane_run``),
+    which hands back per-segment hits and miss runs; ``way_mask``
+    partitions the LLC as in ``interference_lane_metrics``, and None is
+    the full mask, bit-exactly the unpartitioned lane."""
     from repro.core.dram import segment_row_hits
 
     bb = llc.block_bytes
     _check_row_block(llc, dram)
-    if way_mask is not None:
-        b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
-                                    chunk_bursts=chunk_bursts)
-        _check_lane_support_meta([(b, s, c)], [llc])
-        way_sels = partition_way_sels(nv, llc, way_mask)
-        hits, runs = _masked_lane_run(b, s, c, llc, way_sels)
-        counts = np.asarray(c, np.int64)
-        hits = np.asarray(hits[:counts.shape[0]], np.int64)
-    else:
-        segs, nv = corunner_segments(nvdla_segs, llc=llc, mix=mix,
-                                     chunk_bursts=chunk_bursts)
-        res = simulate_segments(segs, llc, per_segment=True,
-                                collect_miss_runs=True)
-        counts = np.asarray([sg.count for sg in segs], np.int64)
-        hits = np.asarray(res.per_segment_hits, np.int64)
-        runs = res.miss_runs
-    if isinstance(runs, tuple):
-        fb, nbk, sidx = (np.asarray(a, np.int64) for a in runs)
-    else:
-        arr = np.asarray(runs, np.int64).reshape(-1, 3)
-        fb, nbk, sidx = arr[:, 0], arr[:, 1], arr[:, 2]
+    b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
+                                chunk_bursts=chunk_bursts)
+    n_segments = c.shape[0]
+    b, s, c, nv = _split_sparse_corunners(b, s, c, nv, llc)
+    _check_lane_support_meta([(b, s, c)], [llc])
+    way_sels = partition_way_sels(
+        nv, llc, (1 << llc.ways) - 1 if way_mask is None else way_mask)
+    hits, (fb, nbk, sidx) = _masked_lane_run(b, s, c, llc, way_sels)
+    counts = np.asarray(c, np.int64)
+    hits = np.asarray(hits[:counts.shape[0]], np.int64)
+    _check_missed(int(nbk.sum()), int(counts.sum()), int(hits.sum()))
     row = segment_row_hits((fb * bb, np.full(fb.shape[0], bb, np.int64),
                             nbk), dram)
     seg_row = np.zeros(counts.shape[0], np.int64)
@@ -1094,7 +1043,7 @@ def lane_request_latencies(nvdla_segs: list, *, llc: LLCConfig, dram,
                + (misses - seg_row) * (dram.t_rp_cycles
                                        + dram.t_rcd_cycles))
     metrics = _lane_metrics_from_runs(
-        n_segments=counts.shape[0], accesses=int(counts.sum()),
+        n_segments=n_segments, accesses=int(counts.sum()),
         hits=int(hits.sum()), runs=(fb, nbk, sidx), bb=bb, nv=nv,
         dram=dram, t_llc_hit=t_llc_hit, nv_acc=int(counts[nv].sum()),
         nv_hits=int(hits[nv].sum()))
@@ -1176,9 +1125,8 @@ def _lane_miss_runs(base, stride, count, llc: LLCConfig, cold: np.ndarray,
     kernel's round-scan miss bits plus the analytically-known suffix
     (every block past the round-scanned prefix misses; a cold segment
     is all suffix).  Runs come out in segment order with blocks
-    ascending within a segment — the same access order
-    ``simulate_segments(collect_miss_runs=True)`` emits, up to
-    adjacent-run splits *within* a segment, which the closed-form row
+    ascending within a segment — the order of first access, up to how a
+    segment's missed blocks split into runs, which the closed-form row
     model is invariant to (identical expanded access sequence).
 
     ``miss_bits`` is the lane's (S, r_pad, W) ordinal layout
@@ -1271,11 +1219,11 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     program: the geometry-traced segment kernel with miss-bit
     collection (``segment_lane_scan(collect=True)``), vmapped over
     lanes.  Per lane, the host reconstructs the exact missed-block runs
-    (``_lane_miss_runs``) and finishes with the same closed-form
-    DRAM/latency reduction as the sequential path, so every
-    ``LaneMetrics`` is bit-identical to
-    ``interference_lane_metrics`` for that lane — the executor
-    journals batch results interchangeably with sequential ones.
+    (``_lane_miss_runs``) and finishes with the closed-form DRAM/latency
+    reduction (``_lane_metrics_from_runs``), so every ``LaneMetrics``
+    is bit-identical to the plain per-access reference of the same lane
+    (``bench/reference/lane.py``), whatever lanes share its batch.  A
+    single lane is a batch of one (``interference_lane_metrics``).
 
     Each unmasked lane is compacted first (``compact_lane``): where the
     records shorten a bucket's padded scan by more than their members
@@ -1292,10 +1240,12 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     ``repro.launch.mesh.make_sweep_mesh``) shards the lane axis across
     devices; ``mesh=None`` runs the same program on one device.
 
-    Raises ``UnsupportedTraceError`` (a ``ValueError``) if any lane's
-    trace falls outside the segment engine's support (stride >
-    block_bytes) — callers fall back to the sequential path, which
-    expands such segments exactly.
+    Raises ``UnsupportedTraceError`` (a ``ValueError``) if the NVDLA
+    trace falls outside a lane's support (a stride outside ``(0,
+    block_bytes]`` of that lane's LLC); only the per-access paths
+    replay such a trace (``socsim.simulate_dbb_stream``).  Co-runner
+    lines past a narrower block run as one-access segments
+    (``_split_sparse_corunners``).
 
     ``way_masks`` is an equal-length sequence of per-lane LLC way
     partitions (``int`` victim masks, or ``None`` for unpartitioned
@@ -1313,12 +1263,15 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
         return []
     with tracing.span(tracing.LANE_PLAN):
         chunks = nvdla_chunks(nvdla_segs, chunk_bursts)
-        lanes, nv_masks, lane_sels = [], [], []
+        lanes, nv_masks, lane_sels, n_segments = [], [], [], []
         for i, (llc, dram, mix) in enumerate(zip(llcs, drams, mixes)):
             _check_row_block(llc, dram)
             b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
                                         chunk_bursts=chunk_bursts,
                                         _chunks=chunks)
+            n_segments.append(c.shape[0])
+            b, s, c, nv = _split_sparse_corunners(b, s, c, nv, llc)
+            _check_lane_support_meta([(b, s, c)], [llc])
             lanes.append((b, s, c))
             nv_masks.append(nv)
             wm = way_masks[i] if way_masks is not None else None
@@ -1328,7 +1281,6 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
         if masked and mesh is not None:
             raise ValueError("way-masked batches do not support mesh "
                              "sharding yet — pass mesh=None")
-        _check_lane_support_meta(lanes, llcs)
     records = [None] * lanes_n
     with tracing.span(tracing.COMPACT):
         # way-masked lanes replay every segment in the round scan, so
@@ -1370,8 +1322,8 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
             for part, run in runs:
                 got = run([drams[i] for i in part], t_llc_hit, mesh)
                 for i, m in zip(part, got):
-                    out[i] = dataclasses.replace(m, segments=lanes[i][2]
-                                                 .shape[0])
+                    out[i] = dataclasses.replace(m,
+                                                 segments=n_segments[i])
     return out
 
 
@@ -1402,7 +1354,9 @@ def _segment_program(views, cfgs_b, sels_b, masked: bool):
     ``segment_lane_scan`` program; returns the function that runs it
     and reduces each lane to its ``LaneMetrics``."""
     sets, ways, blocks, max_sets, max_ways = _geometry_arrays(cfgs_b)
-    s_pad = max(1, max(v[2].shape[0] for v in views))
+    # at least 64 segments: the serving oracle's lanes, a few dozen
+    # segments each, then share one compiled program
+    s_pad = max(64, max(v[2].shape[0] for v in views))
     shape = (len(views), s_pad)
     bases = np.zeros(shape, np.int32)
     strides = np.ones(shape, np.int32)

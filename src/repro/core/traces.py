@@ -16,11 +16,11 @@ addresses — generated straight from the command stream that
   ofmap region is the consumer's ifmap region);
 * the DBB arbiter interleaves the three streams; ``interleave`` models
   that by splitting segments into round-robin chunks at a configurable
-  burst granularity (the compressed simulator falls back from the
-  closed form to its per-set scan exactly at these interleave points).
+  burst granularity (each chunk one segment of the lane engines).
 
-``repro.core.cache.simulate_segments`` consumes these directly;
-``expand`` materializes the identical per-access byte trace for parity
+The lane engines (``repro.core.cache.segment_lane_scan`` and
+``record_lane_scan``, driven by ``repro.core.sweep``) consume these
+directly; ``expand`` materializes the identical per-access byte trace for parity
 testing and for the vmapped window sweeps in ``repro.core.sweep``.
 """
 from __future__ import annotations

@@ -19,7 +19,7 @@ and honest about being a simulator everywhere else:
   DBB trace so admission contends with in-flight requests;
 * **simulated clock** — every step's latency comes from the SoC memory
   pipeline (``serve.oracle`` -> ``sweep.step_lane_metrics`` ->
-  ``socsim.simulate_dbb_segments`` physics), so tokens/s and per-request
+  ``sweep.interference_lane_metrics_batch``), so tokens/s and per-request
   p50/p99 are reported in simulated SoC time and LLC contention from
   slot occupancy shows up in the serving tail (the Fig. 6 effect).
 

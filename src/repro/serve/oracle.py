@@ -13,7 +13,8 @@ trace from the model's decode working set (``models.decode_working_set``):
 
 Decode steps are charged their *steady-state marginal* cost: the step
 trace is its own warm prefix (``sweep.step_lane_metrics(...,
-warm_prefix=step)``), so working sets that fit the LLC re-hit across
+warm_prefix=step)``, each lane a batch of one of
+``sweep.interference_lane_metrics_batch``), so working sets that fit the LLC re-hit across
 steps and each admitted co-resident request grows the cyclic
 re-reference distance — occupancy degrades hit rate exactly the way
 Fig. 6's co-runners do, and the tail of the latency distribution

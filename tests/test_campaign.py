@@ -281,16 +281,24 @@ def test_quarantine_mid_batch_stays_per_point(tmp_path):
     assert "finite" in info["error"]
 
 
-@pytest.mark.parametrize("error,falls_back", [
-    ("unsupported", True),
-    ("device", False),
-    ("value", False),
-], ids=["unsupported_trace", "device_error", "other_value_error"])
-def test_batch_falls_back_only_on_unsupported_trace(tmp_path, monkeypatch,
-                                                    error, falls_back):
-    """Only a trace the lane engine cannot replay sends a batch down the
-    sequential path; a device failure, or any other error from the batch
-    engine, fails the campaign instead of hiding as a slow run."""
+def _journaled_results(out_dir) -> list:
+    from repro.campaign.manifest import JOURNAL_NAME
+
+    path = os.path.join(out_dir, JOURNAL_NAME)
+    if not os.path.exists(path):
+        return []
+    return [r for r in Journal(path).replay()[0]
+            if r["kind"] in ("point", "failed")]
+
+
+@pytest.mark.parametrize("error", ["unsupported", "device", "value"],
+                         ids=["unsupported_trace", "device_error",
+                              "other_value_error"])
+def test_every_batch_error_fails_the_campaign(tmp_path, monkeypatch, error):
+    """No batch error falls back to another engine: a trace the lane
+    engines cannot replay, a device failure, or any other error from the
+    batch engine fails the campaign instead of hiding as a slow run, and
+    no point of the batch is journaled."""
     from repro.campaign import executor
     from repro.core.sweep import UnsupportedTraceError
 
@@ -302,17 +310,63 @@ def test_batch_falls_back_only_on_unsupported_trace(tmp_path, monkeypatch,
         raise exc
 
     monkeypatch.setattr(executor, "run_batch", broken)
+    out = str(tmp_path / "bat")
+    with pytest.raises(type(exc), match=str(exc)):
+        run_campaign(tiny_spec(4), out)
+    assert _journaled_results(out) == []
+
+
+@pytest.mark.parametrize("entry", ["run_point", "run_campaign",
+                                   "one_point_chunks"])
+def test_sparse_stride_point_is_refused(tmp_path, monkeypatch, entry):
+    """An NVDLA trace with a stride past the LLC block is refused with
+    the stride named, one point, a whole batch or one-point chunks: no
+    engine replays it here, so neither a result nor a quarantined
+    failure is journaled."""
+    from repro.campaign import executor
+    from repro.campaign import spec as spec_module
+    from repro.core.sweep import UnsupportedTraceError
+    from repro.core.traces import Segment
+
+    sparse = [Segment(0x1000_0000, 4096, 64, "w")]
+    monkeypatch.setattr(spec_module, "_model_trace", lambda *a: sparse)
     spec = tiny_spec(4)
-    notes: list[str] = []
-    if not falls_back:
-        with pytest.raises(type(exc), match=str(exc)):
-            run_campaign(spec, str(tmp_path / "bat"), progress=notes.append)
-        assert not any("fell back" in n for n in notes)
-        return
-    res = run_campaign(spec, str(tmp_path / "bat"), progress=notes.append)
-    assert sum("fell back to sequential" in n for n in notes) == 1
+    out = str(tmp_path / "sparse")
+    with pytest.raises(UnsupportedTraceError, match="stride 4096"):
+        if entry == "run_point":
+            executor.run_point(spec.expand()[0], spec.models[0].trace())
+        else:
+            run_campaign(spec, out,
+                         batch_points=1 if entry == "one_point_chunks"
+                         else 32)
+    assert _journaled_results(out) == []
+
+
+@pytest.mark.parametrize("wss", ["llc", "dram"])
+def test_narrow_block_beside_corunners_runs(tmp_path, wss):
+    """A 32 B LLC block beside co-runners, whose 64 B lines stride past
+    it, runs batched and in one-point chunks to the same manifest, and
+    every point equals the per-access reference, 64 B lanes of the same
+    batch included."""
+    import lane_reference
+    from repro.core.sweep import LaneMetrics
+
+    spec = CampaignSpec(
+        name="narrow", models=(ModelSpec(window_bursts=256),),
+        geometries=(GeometrySpec(8, block=32, ways=4),
+                    GeometrySpec(16, block=64, ways=4)),
+        mixes=(MixSpec(2, wss),))
     seq = run_campaign(spec, str(tmp_path / "seq"), batch_points=1)
-    assert canon(res.manifest) == canon(seq.manifest)
+    bat = run_campaign(spec, str(tmp_path / "bat"))
+    assert seq.completed == bat.completed == 2
+    assert canon(seq.manifest) == canon(bat.manifest)
+    nv = spec.models[0].trace()
+    got = {p["point_id"]: LaneMetrics.from_record(p["result"])
+           for p in bat.manifest["points"]}
+    for point in spec.expand():
+        assert got[point.point_id] == lane_reference.lane_metrics(
+            nv, llc=point.geometry.llc(), dram=point.dram.dram(),
+            mix=point.mix.mix(), chunk_bursts=point.model.chunk_bursts)
 
 
 def test_crash_mid_batch_resume_bit_identical_property():
@@ -413,9 +467,11 @@ def test_backend_axis_validation():
 def test_npu_points_trace_through_executor(tmp_path):
     """A pure-NPU campaign runs the unchanged executor + guardrails and
     its journaled counters replay the NPU window exactly."""
+    import numpy as np
+
     from repro.campaign.spec import mixed_backend_spec
-    from repro.core import npu
-    from repro.core.cache import simulate_segments
+    from repro.core import npu, traces
+    from repro.core.cache import simulate_trace
 
     spec = mixed_backend_spec(4, window_bursts=128)
     res = run_campaign(spec, str(tmp_path))
@@ -429,9 +485,11 @@ def test_npu_points_trace_through_executor(tmp_path):
     for p in npu_points:
         geo = p["params"]["geometry"]
         llc = GeometrySpec(**geo).llc()
-        ref = simulate_segments(window, llc)
-        assert p["result"]["nvdla_accesses"] == ref.accesses
-        assert p["result"]["nvdla_hits"] == ref.hits
+        blocks = traces.expand(window) // llc.block_bytes
+        hits = simulate_trace(blocks.astype(np.int32), sets=llc.sets,
+                              ways=llc.ways)
+        assert p["result"]["nvdla_accesses"] == blocks.shape[0]
+        assert p["result"]["nvdla_hits"] == int(hits.sum())
 
 
 def test_mixed_backend_campaign_crash_resume_bit_identical(tmp_path):

@@ -53,9 +53,31 @@ def test_warm_revisit_and_disjoint_banks():
 
 
 def test_sparse_stride_fallback():
+    from repro.core.cache import LLCConfig, simulate_trace
+    from repro.core.socsim import simulate_dbb_segments, simulate_dbb_stream
+    from repro.core.sweep import UnsupportedTraceError
+
     cfg = DRAMConfig(banks=8, row_bytes=512)
     # stride > row_bytes: gappy rows, replayed exactly
-    _assert_parity([Segment(0, 4096, 100), Segment(17, 640, 333)], cfg)
+    segs = [Segment(0, 4096, 100), Segment(17, 640, 333)]
+    _assert_parity(segs, cfg)
+    # strides past the LLC block: the segment pipeline's lane engine
+    # refuses them, naming the stride, and the per-access pipeline
+    # replays them — its total is the per-access LLC scan's hits plus
+    # the DRAM latencies of its misses
+    llc = LLCConfig()
+    with pytest.raises(UnsupportedTraceError, match="stride 4096"):
+        simulate_dbb_segments(segs, llc=llc, dram=cfg)
+    addrs = traces.expand(segs)
+    hit = np.asarray(simulate_trace(
+        jnp.asarray((addrs // llc.block_bytes).astype(np.int32)),
+        sets=llc.sets, ways=llc.ways))
+    dram_lats = np.asarray(access_latencies(
+        jnp.asarray(addrs[~hit]), banks=cfg.banks, row_bytes=cfg.row_bytes,
+        t_cas=cfg.t_cas_cycles, t_rcd=cfg.t_rcd_cycles, t_rp=cfg.t_rp_cycles))
+    got = simulate_dbb_stream(addrs, llc=llc, dram=cfg)
+    assert 0 < int(hit.sum()) < addrs.shape[0]
+    assert int(got.total_cycles) == 20 * addrs.shape[0] + int(dram_lats.sum())
 
 
 def test_unaligned_bases():

@@ -5,7 +5,8 @@ block ordinal and as wide as a round's live ordinals rounded up to 128
 Each case checks the narrowed program and its decode against the full
 width (``collect_width = max_sets``) decoded through the set-indexed
 layout the program used to return, kept here as the reference, and the
-batched lanes' ``LaneMetrics`` against the sequential engine's."""
+batched lanes' ``LaneMetrics`` against the per-access reference's
+(``lane_reference``)."""
 from __future__ import annotations
 
 import jax
@@ -13,11 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lane_reference
 from repro.core import sweep, traces
 from repro.core.cache import LLCConfig
 from repro.core.dram import DRAMConfig
-from repro.core.sweep import (MixConfig, interference_lane_metrics,
-                              interference_lane_metrics_batch)
+from repro.core.sweep import MixConfig, interference_lane_metrics_batch
 
 
 def _dense_miss_runs(base, stride, count, llc: LLCConfig, cold, dense, *,
@@ -161,7 +162,7 @@ def test_narrow_miss_bits_decode_like_the_set_layout(monkeypatch, case):
         nv, llcs=llcs, drams=drams, mixes=mixes, chunk_bursts=chunk,
         way_masks=way_masks)
     for i, (llc, dram, mix) in enumerate(zip(llcs, drams, mixes)):
-        ref = interference_lane_metrics(
+        ref = lane_reference.lane_metrics(
             nv, llc=llc, dram=dram, mix=mix, chunk_bursts=chunk,
             way_mask=None if way_masks is None else way_masks[i])
         assert batch[i].to_record() == ref.to_record(), f"lane {i}"

@@ -12,13 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lane_reference
 from repro.core.cache import LLCConfig
 from repro.core.dram import DRAMConfig
 from repro.core.fame1 import chunked_scan
 from repro.core.farm import FarmConfig, farm_schedule, simulate_farm
 from repro.core.noc import (NoCConfig, NoCOverflowError, NoCSwitch,
                             simulate_reference)
-from repro.core.sweep import MixConfig, interference_lane_metrics
+from repro.core.sweep import MixConfig
 
 GEOMETRIES = ((3, 40, 0), (4, 33, 2))        # (ports, T, link_latency)
 BUNDLES = (1, 7, 64)                          # 7 divides nothing here
@@ -142,7 +143,7 @@ class TestFarmTail:
             if n == 0:
                 from repro.core.farm import victim_window
 
-                ref = interference_lane_metrics(
+                ref = lane_reference.lane_metrics(
                     victim_window("nvdla", max_bursts=512) * 2,
                     llc=llc, dram=dram, mix=MixConfig(0, "l1"))
                 assert res.metrics == ref

@@ -16,7 +16,8 @@ import pytest
 
 from repro.core import npu, traces
 from repro.core.accelerator import MemSystemConfig
-from repro.core.cache import LLCConfig, simulate_segments, simulate_trace
+from repro.core.cache import LLCConfig, simulate_trace
+from repro.core.sweep import segment_lane_hit_counts
 from repro.core.traces import BURST_BYTES
 
 # the (rows, cols, ifm, wgt, acc) config grid: square/rectangular PE
@@ -42,10 +43,17 @@ LLC_SMALL = LLCConfig(size_bytes=4096, ways=4, block_bytes=32)
 
 def _scan_reference(segs, llc):
     """The naive per-access reference: expand every segment to byte
-    addresses and replay them one at a time through the serial LRU."""
+    addresses and replay them one at a time through the serial LRU.
+    Returns the per-segment hit counts."""
     blocks = (traces.expand(segs) // llc.block_bytes).astype(np.int32)
-    hits = simulate_trace(blocks, sets=llc.sets, ways=llc.ways)
-    return int(hits.sum()), int(len(blocks))
+    bits = np.asarray(simulate_trace(blocks, sets=llc.sets, ways=llc.ways))
+    bounds = np.cumsum([0] + [s.count for s in segs])
+    return [int(bits[a:b].sum()) for a, b in zip(bounds[:-1], bounds[1:])]
+
+
+def _lane_hits(segs, llc):
+    """Per-segment hit counts of the segment lane engine, one lane."""
+    return segment_lane_hit_counts(segs, [llc])[0, :len(segs)].tolist()
 
 
 def _stream_bursts(segs, stream):
@@ -66,17 +74,16 @@ class TestSegmentParity:
     @pytest.mark.parametrize("op", OPS, ids=lambda o: o.name)
     def test_replay_bit_identical_to_per_access_scan(self, cfg, op):
         segs = npu.op_segments(op, cfg, 0, 1 << 20, 2 << 20)
-        res = simulate_segments(segs, LLC_SMALL)
-        ref_hits, ref_accesses = _scan_reference(segs, LLC_SMALL)
-        assert (res.hits, res.accesses) == (ref_hits, ref_accesses)
+        assert _lane_hits(segs, LLC_SMALL) == _scan_reference(segs,
+                                                              LLC_SMALL)
 
     @pytest.mark.parametrize("cfg", CONFIG_GRID[:2],
                              ids=lambda c: f"{c.rows}x{c.cols}")
     def test_interleaved_workload_parity(self, cfg):
         ops = [npu.GemmOp("a", 9, 8, 7), npu.GemmOp("b", 7, 7, 9)]
         chunks = npu.npu_chunks(ops, cfg, chunk_bursts=4)
-        res = simulate_segments(chunks, LLC_SMALL)
-        assert (res.hits, res.accesses) == _scan_reference(chunks, LLC_SMALL)
+        assert _lane_hits(chunks, LLC_SMALL) == _scan_reference(chunks,
+                                                                LLC_SMALL)
 
     def test_window_is_exact_prefix(self):
         cfg = CONFIG_GRID[0]
@@ -246,12 +253,12 @@ class TestTiming:
         assert len(rates) == 2
         per_op = npu.workload_op_segments(ops, cfg)
         flat = [s for segs in per_op for s in segs]
-        res = simulate_segments(flat, LLC_SMALL, per_segment=True)
+        per_segment_hits = _scan_reference(flat, LLC_SMALL)
         k = 0
         for segs, op_rates in zip(per_op, rates):
             tot = {"weight": [0, 0], "ifmap": [0, 0], "ofmap": [0, 0]}
             for s in segs:
-                tot[s.stream][0] += int(res.per_segment_hits[k])
+                tot[s.stream][0] += per_segment_hits[k]
                 tot[s.stream][1] += s.count
                 k += 1
             for (h, a), r in zip(

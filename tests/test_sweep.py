@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lane_reference
 from repro.core import traces
 from repro.core.cache import LLCConfig, simulate_trace
 from repro.core.fame1 import Component, FAME1Pipeline
@@ -32,7 +33,6 @@ from repro.core.sweep import (
     step_lane_metrics,
     segment_lane_hit_counts,
     segment_lane_hit_rates,
-    segment_sweep_hit_rates,
     sweep_interference,
     sweep_llc,
 )
@@ -73,7 +73,7 @@ def test_segment_sweep_matches_expanded_scans():
     segs = traces.window(traces.network_trace(max_ops=3), 30_000)
     addrs = traces.expand(segs)
     configs = list(grid_configs((0.5, 64), (32, 128)).values())
-    got = segment_sweep_hit_rates(segs, configs)
+    got = segment_lane_hit_rates(segs, configs)
     for i, c in enumerate(configs):
         blocks = jnp.asarray((addrs // c.block_bytes).astype(np.int32))
         ref = float(jnp.mean(simulate_trace(
@@ -222,7 +222,8 @@ def test_sweep_grid_record_round_trip():
 def test_batched_lane_metrics_bit_identical_to_sequential():
     """The tentpole parity requirement: one vmapped lane program over a
     mixed bucket of geometries/mixes/DRAM specs returns *exactly* the
-    LaneMetrics the sequential engine computes, field for field."""
+    LaneMetrics the per-access reference computes for each lane alone,
+    field for field."""
     from repro.core.dram import DRAMConfig
 
     nv = traces.default_dbb_window(max_bursts=512)
@@ -240,8 +241,30 @@ def test_batched_lane_metrics_bit_identical_to_sequential():
     batch = interference_lane_metrics_batch(nv, llcs=llcs, drams=drams,
                                             mixes=mixes)
     for i, (llc, dram, mix) in enumerate(zip(llcs, drams, mixes)):
-        ref = interference_lane_metrics(nv, llc=llc, dram=dram, mix=mix)
+        ref = lane_reference.lane_metrics(nv, llc=llc, dram=dram, mix=mix)
         assert batch[i] == ref, f"lane {i}: {llc} {mix}"
+
+
+@pytest.mark.parametrize("way_mask", [None, 0x3], ids=["shared", "masked"])
+def test_narrow_block_corunner_lane_matches_reference(way_mask):
+    """Co-runners write 64 B lines; beside a 32 B LLC block each line is
+    a sparse stride, which the lane entry points replay one access a
+    segment: the lane and the farm's per-chunk latencies equal the
+    per-access reference, and the lane keeps the unsplit segment count."""
+    from repro.core.dram import DRAMConfig
+    from repro.core.sweep import lane_request_latencies, nvdla_chunks
+
+    nv = traces.default_dbb_window(max_bursts=256)
+    llc, dram = LLCConfig(8192, 4, 32), DRAMConfig()
+    for mix in (MixConfig(2, "llc"), MixConfig(1, "dram")):
+        ref = lane_reference.lane_metrics(nv, llc=llc, dram=dram, mix=mix,
+                                          way_mask=way_mask)
+        assert interference_lane_metrics(nv, llc=llc, dram=dram, mix=mix,
+                                         way_mask=way_mask) == ref, mix
+        lat, got = lane_request_latencies(nv, llc=llc, dram=dram, mix=mix,
+                                          way_mask=way_mask)
+        assert got == ref, mix
+        assert lat.shape[0] == nvdla_chunks(nv)[0].shape[0]
 
 
 def test_corunner_meta_matches_corunner_segments():
@@ -361,7 +384,9 @@ def test_step_lane_metrics_cold_is_interference_lane():
     dram = DRAMConfig()
     assert (step_lane_metrics(segs, llc=LLC, dram=dram)
             == interference_lane_metrics(segs, llc=LLC, dram=dram,
-                                         mix=MixConfig()))
+                                         mix=MixConfig())
+            == lane_reference.lane_metrics(segs, llc=LLC, dram=dram,
+                                           mix=MixConfig()))
 
 
 def test_step_lane_metrics_marginal_matches_warmed_pipeline():

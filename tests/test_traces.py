@@ -1,20 +1,32 @@
-"""Compressed DBB traces + segment engine: bit-exact parity with the
-per-access reference simulator on every dispatch path (closed form,
-per-set round scan, prefix/suffix split, expand fallback)."""
+"""Compressed DBB traces + the segment lane engine: bit-exact parity
+with the per-access reference simulator on every path of one lane (a
+cold segment's closed form, the per-set round scan, the warm prefix and
+its closed-form suffix), in hit counts per segment and in the final
+LLC state."""
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import traces
+from repro.core import sweep, traces
 from repro.core.cache import (
     LLCConfig,
     _scan_trace,
     cold_state,
     hit_rate,
-    hit_rate_segments,
-    simulate_segments,
+    segment_lane_scan,
+    simulate_trace,
+)
+from repro.core.sweep import (
+    UnsupportedTraceError,
+    _masked_lane_run,
+    partition_way_sels,
+    segment_lane_hit_counts,
+    segment_lane_hit_rates,
 )
 from repro.core.traces import Segment
 from repro.utils.env import as_address_array, x64_enabled
@@ -22,48 +34,69 @@ from repro.utils.env import as_address_array, x64_enabled
 CFG = LLCConfig(size_bytes=4096, ways=4, block_bytes=64)   # 16 sets
 
 
-def _assert_parity(segs, cfg, *, expect=None):
-    """Compressed result must equal expanding + exact scanning: same hit
-    count AND bit-identical (tags, age) state."""
-    res = simulate_segments(segs, cfg)
+@functools.lru_cache(maxsize=8)
+def _one_lane(sets: int, ways: int):
+    return jax.jit(functools.partial(
+        segment_lane_scan, max_sets=sets, max_ways=ways, r_pad=ways,
+        return_state=True))
+
+
+def _by_recency(tags, newest_first_key):
+    """Each set's tags, most recently used first (never-filled ways,
+    tag -1, last)."""
+    order = np.argsort(newest_first_key, axis=1, kind="stable")
+    return np.take_along_axis(np.asarray(tags), order, axis=1)
+
+
+def _per_segment(bits, segs):
+    bounds = np.cumsum([0] + [s.count for s in segs])
+    return [int(bits[a:b].sum()) for a, b in zip(bounds[:-1], bounds[1:])]
+
+
+def _assert_parity(segs, cfg, *, cold=None):
+    """One lane, planned as the lane engines plan it (``_lane_plan``),
+    must equal expanding + exact scanning: the same hits per segment,
+    the same line in every way of every set, in the same LRU order
+    (the lane keeps last-touch stamps where the reference keeps ages)."""
+    r_needed, plan_cold = sweep._lane_plan(segs, [cfg])
+    meta = np.asarray([traces.segment_tuple(s) for s in segs], np.int32)
+    hits, (tags, ts) = _one_lane(cfg.sets, cfg.ways)(
+        *(jnp.asarray(meta[:, k]) for k in range(3)),
+        jnp.asarray(r_needed), jnp.asarray(plan_cold),
+        cfg.sets, cfg.ways, cfg.block_bytes)
     blocks = (traces.expand(segs) // cfg.block_bytes).astype(np.int32)
-    state, hits = _scan_trace(cold_state(cfg.sets, cfg.ways),
-                              jnp.asarray(blocks),
-                              sets=cfg.sets, ways=cfg.ways)
-    assert res.accesses == len(blocks)
-    assert res.hits == int(hits.sum())
-    np.testing.assert_array_equal(np.asarray(res.state[0]),
-                                  np.asarray(state[0]))
-    np.testing.assert_array_equal(np.asarray(res.state[1]),
-                                  np.asarray(state[1]))
-    if expect is not None:
-        for key, val in expect.items():
-            assert getattr(res, key) == val, (key, getattr(res, key), val)
-    return res
+    (ref_tags, ref_age), bits = _scan_trace(
+        cold_state(cfg.sets, cfg.ways), jnp.asarray(blocks),
+        sets=cfg.sets, ways=cfg.ways)
+    assert np.asarray(hits).tolist() == _per_segment(np.asarray(bits), segs)
+    np.testing.assert_array_equal(np.asarray(tags).T, ref_tags)
+    np.testing.assert_array_equal(
+        _by_recency(np.asarray(tags).T, -np.asarray(ts).T),
+        _by_recency(ref_tags, ref_age))
+    if cold is not None:
+        assert plan_cold.tolist() == cold
+    return int(np.asarray(hits).sum())
 
 
 def test_closed_form_cold_sweep():
-    # disjoint full sweep -> O(1) analytic path, hits = n - blocks
-    res = _assert_parity([Segment(0, 32, 20_000)], CFG,
-                         expect={"closed_form_segments": 1,
-                                 "round_scanned_segments": 0})
-    assert res.hits == 20_000 - 10_000
+    # disjoint full sweep -> no rounds, the cold closed form alone;
+    # hits = n - blocks
+    hits = _assert_parity([Segment(0, 32, 20_000)], CFG, cold=[True])
+    assert hits == 20_000 - 10_000
 
 
 def test_restreamed_region_splits_prefix_suffix():
     # second pass over the same bytes: warm prefix round-scanned, the
     # provably-evicted suffix closed-formed
-    res = _assert_parity([Segment(0, 32, 20_000), Segment(0, 32, 20_000)],
-                         CFG)
-    assert res.closed_form_segments >= 2
-    assert res.round_scanned_segments >= 1
+    segs = [Segment(0, 32, 20_000), Segment(0, 32, 20_000)]
+    _assert_parity(segs, CFG, cold=[True, False])
+    r_needed, _ = sweep._lane_plan(segs, [CFG])
+    assert r_needed.tolist() == [0, CFG.ways]
 
 
 def test_small_warm_segments_round_scan():
     _assert_parity([Segment(0, 32, 40), Segment(64, 32, 10),
-                    Segment(0, 32, 40)], CFG,
-                   expect={"closed_form_segments": 0,
-                           "round_scanned_segments": 3})
+                    Segment(0, 32, 40)], CFG, cold=[True, False, False])
 
 
 def test_unaligned_bases_and_strides():
@@ -71,8 +104,14 @@ def test_unaligned_bases_and_strides():
 
 
 def test_stride_above_block_expands():
-    _assert_parity([Segment(0, 256, 500)], CFG,
-                   expect={"expanded_segments": 1})
+    # blocks a stride apart: only the per-access scan replays them; the
+    # lane engine refuses the trace, naming the stride
+    segs = [Segment(0, 256, 500)]
+    with pytest.raises(UnsupportedTraceError, match="stride 256"):
+        segment_lane_hit_counts(segs, [CFG])
+    blocks = (traces.expand(segs) // CFG.block_bytes).astype(np.int32)
+    assert int(simulate_trace(jnp.asarray(blocks), sets=CFG.sets,
+                              ways=CFG.ways).sum()) == 0
 
 
 def test_single_set_geometry():
@@ -95,7 +134,7 @@ def test_network_trace_prefix_parity():
 
 def test_hit_rate_segments_matches_hit_rate():
     segs = [Segment(0, 32, 5000), Segment(1 << 20, 32, 3000)]
-    hr_seg = hit_rate_segments(segs, CFG)
+    (hr_seg,) = segment_lane_hit_rates(segs, [CFG])
     blocks = (traces.expand(segs) // CFG.block_bytes).astype(np.int32)
     assert abs(hr_seg - hit_rate(blocks, CFG)) < 1e-9
 
@@ -150,24 +189,25 @@ def test_split_never_emits_zero_count_chunks():
 
 
 def test_per_segment_hits_and_miss_runs():
+    # the last segment: one access per block, from an unaligned base
     segs = [Segment(0, 32, 3000), Segment(0, 32, 500),
-            Segment(1 << 18, 32, 64), Segment(5000, 256, 100)]
-    res = simulate_segments(segs, CFG, per_segment=True,
-                            collect_miss_runs=True)
+            Segment(1 << 18, 32, 64), Segment(5000, 64, 100)]
     blocks = (traces.expand(segs) // CFG.block_bytes).astype(np.int32)
-    _, bits = _scan_trace(cold_state(CFG.sets, CFG.ways),
-                          jnp.asarray(blocks), sets=CFG.sets,
-                          ways=CFG.ways)
-    bits = np.asarray(bits)
-    o, ref = 0, []
-    for s in segs:
-        ref.append(int(bits[o:o + s.count].sum()))
-        o += s.count
-    assert res.per_segment_hits.tolist() == ref
-    # miss runs expand to exactly the missed blocks, in access order
-    got = np.concatenate([np.arange(n) + b for b, n, _ in res.miss_runs])
+    bits = np.asarray(simulate_trace(jnp.asarray(blocks), sets=CFG.sets,
+                                     ways=CFG.ways))
+    ref = _per_segment(bits, segs)
+    assert segment_lane_hit_counts(segs, [CFG])[0].tolist() == ref
+    # the collecting lane's miss runs expand to exactly the missed
+    # blocks, in access order
+    b, s, c = (np.asarray(a, np.int64)
+               for a in zip(*map(traces.segment_tuple, segs)))
+    sels = partition_way_sels(np.ones(len(segs), bool), CFG,
+                              (1 << CFG.ways) - 1)
+    hits, (first, n_blocks, idx) = _masked_lane_run(b, s, c, CFG, sels)
+    assert hits.tolist() == ref
+    got = np.concatenate([np.arange(n) + f for f, n in zip(first, n_blocks)])
     np.testing.assert_array_equal(got, blocks[~bits])
-    assert all(0 <= idx < len(segs) for _, _, idx in res.miss_runs)
+    assert all(0 <= i < len(segs) for i in idx)
 
 
 def test_network_op_segments_flatten_to_network_trace():
@@ -178,29 +218,12 @@ def test_network_op_segments_flatten_to_network_trace():
                for segs in per_op for s in segs)
 
 
-def test_warm_initial_state_disables_closed_form():
-    # a passed-in state may hold anything: the engine must not assume
-    # segment disjointness it can only prove within one call
-    warm = simulate_segments([Segment(0, 32, 4096)], CFG)
-    seg2 = [Segment(0, 64, CFG.sets * CFG.ways)]   # re-reads resident blocks
-    res = simulate_segments(seg2, CFG, state=warm.state)
-    blocks1 = (traces.expand([Segment(0, 32, 4096)])
-               // CFG.block_bytes).astype(np.int32)
-    blocks2 = (traces.expand(seg2) // CFG.block_bytes).astype(np.int32)
-    both = np.concatenate([blocks1, blocks2])
-    state, hits = _scan_trace(cold_state(CFG.sets, CFG.ways),
-                              jnp.asarray(both), sets=CFG.sets,
-                              ways=CFG.ways)
-    assert warm.hits + res.hits == int(hits.sum())
-    np.testing.assert_array_equal(np.asarray(res.state[0]),
-                                  np.asarray(state[0]))
-    np.testing.assert_array_equal(np.asarray(res.state[1]),
-                                  np.asarray(state[1]))
-
-
 def test_zero_stride_rejected():
     with pytest.raises(ValueError, match="stride"):
-        simulate_segments([Segment(0, 0, 10)], CFG)
+        Segment(0, 0, 10)
+    # a raw tuple skips the constructor; the lane engine refuses it
+    with pytest.raises(UnsupportedTraceError, match="stride 0"):
+        segment_lane_hit_counts([(0, 0, 10)], [CFG])
 
 
 def test_segment_constructor_validation():
@@ -230,8 +253,8 @@ def test_tuple_segments_bypass_unchanged():
     """Raw (base, stride, count) tuples are still accepted by the
     engines (the hypothesis strategies build them) — constructor
     validation applies to ``Segment`` objects only."""
-    res = simulate_segments([(0, 32, 64)], CFG)
-    assert res.accesses == 64
+    assert segment_lane_hit_counts([(0, 32, 64)], [CFG]).tolist() == [[32]]
+    assert segment_lane_hit_rates([(0, 32, 64)], [CFG]).tolist() == [0.5]
 
 
 def test_address_array_guards_overflow():

@@ -161,10 +161,12 @@ def test_campaign_counters_match_the_shapes(monkeypatch, tmp_path):
                    tracing.LANE_SEGMENTS: sum(lane_segments),
                    tracing.LANE_SEGMENTS_RAW: sum(lane_segments)}
     # hits (lanes, segments) int32, miss bits (lanes, segments, rounds,
-    # ordinals) bool, as wide as the 64 sets: below the 128-lane width
+    # ordinals) bool, as wide as the 64 sets: below the 128-lane width;
+    # the segment axis padded to at least 64
     segments = max(lane_segments)
-    assert hits.shape == (4, segments) and hits.dtype == np.int32
-    assert miss.shape[:2] == (4, segments) and miss.shape[3] == sets == 64
+    padded = max(64, segments)
+    assert hits.shape == (4, padded) and hits.dtype == np.int32
+    assert miss.shape[:2] == (4, padded) and miss.shape[3] == sets == 64
     assert miss.dtype == bool
     assert segments <= rounds <= segments * miss.shape[2]
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+import lane_reference
 from repro.core.cache import LLCConfig
 from repro.core.dram import DRAMConfig
 from repro.core.sweep import (MixConfig, interference_lane_metrics,
@@ -111,8 +112,8 @@ class TestSentinelIdentity:
             segs, llcs=[llc] * 4, drams=[dram] * 4, mixes=mixes,
             way_masks=masks)
         for got, mix_i, mask_i in zip(batch, mixes, masks):
-            ref = interference_lane_metrics(segs, llc=llc, dram=dram,
-                                            mix=mix_i, way_mask=mask_i)
+            ref = lane_reference.lane_metrics(segs, llc=llc, dram=dram,
+                                              mix=mix_i, way_mask=mask_i)
             assert got == ref
 
     def test_request_latencies_sum_to_lane_total(self):
@@ -125,7 +126,7 @@ class TestSentinelIdentity:
         for mask in (None, 0x0F):
             lat, metrics = lane_request_latencies(
                 segs, llc=llc, dram=dram, mix=mix, way_mask=mask)
-            assert metrics == interference_lane_metrics(
+            assert metrics == lane_reference.lane_metrics(
                 segs, llc=llc, dram=dram, mix=mix, way_mask=mask)
             # victim chunks carry the victim's share; the co-runner
             # share is the rest — both sides of the identity are exact
