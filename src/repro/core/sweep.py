@@ -49,6 +49,8 @@ Public API:
 * ``compact_lane``        — one interleaved lane as ``LaneRecords``:
                             repeats of the arbiter's pattern, each run
                             in one step of ``cache.record_lane_scan``;
+* ``lane_records``        — the same records built from the NVDLA
+                            chunks, the lane never expanded;
 * ``partition_way_sels``  — victim/co-runner allocation masks for an
                             Intel-CAT-style two-class way partition;
 * ``lane_request_latencies`` — per-victim-chunk memory latencies (the
@@ -408,7 +410,6 @@ def _check_lane_support_meta(lanes_meta, configs) -> None:
     the lane engines' support: strides in ``(0, block_bytes]`` of the
     smallest block (``UnsupportedTraceError``), addresses and the
     lane's access total in int32 (``OverflowError``)."""
-    int32_max = np.iinfo(np.int32).max
     min_block = min(c.block_bytes for c in configs)
     for base, stride, count in lanes_meta:
         live = count > 0
@@ -420,15 +421,40 @@ def _check_lane_support_meta(lanes_meta, configs) -> None:
                 "block_bytes in every lane; replay sparse-stride traces "
                 "access by access (socsim.simulate_dbb_stream, "
                 "cache.simulate_trace)")
-        if np.any(live & (base + count * stride > int32_max)):
-            raise OverflowError(
-                "segment addresses exceed int32 — the lane engine "
-                "keeps metadata in 32-bit; rebase the trace")
-        if int(count[live].sum()) > int32_max:
-            raise OverflowError(
-                f"lane trace has {int(count[live].sum())} accesses — "
-                "the lane engine's global LRU timestamp is int32; split "
-                "multi-frame sweeps into per-frame lane calls")
+        _check_int32(int((base + count * stride)[live].max(initial=0)),
+                     int(count[live].sum()))
+
+
+def _check_int32(end: int, accesses: int) -> None:
+    """Raise ``OverflowError`` unless a lane's highest segment end
+    address and its access total fit int32."""
+    int32_max = np.iinfo(np.int32).max
+    if end > int32_max:
+        raise OverflowError(
+            "segment addresses exceed int32 — the lane engine "
+            "keeps metadata in 32-bit; rebase the trace")
+    if accesses > int32_max:
+        raise OverflowError(
+            f"lane trace has {accesses} accesses — "
+            "the lane engine's global LRU timestamp is int32; split "
+            "multi-frame sweeps into per-frame lane calls")
+
+
+def _check_records_support(chunks, llc: LLCConfig, mix: MixConfig,
+                           checked: set) -> None:
+    """``_check_lane_support_meta`` of the lane of ``mix`` at ``llc``
+    over these NVDLA chunks, without expanding it: the chunks once per
+    block size (``checked`` holds those done), then each co-runner's
+    highest line end (its span's end once its cursor reaches it) and
+    the lane's access total."""
+    if llc.block_bytes not in checked:
+        _check_lane_support_meta([chunks], [llc])
+        checked.add(llc.block_bytes)
+    total = int(chunks[2].sum())
+    spans = _corunner_spans(llc, mix)
+    _check_int32(max((region + min(span, total) * 64
+                      for span, region in spans), default=0),
+                 total * (1 + len(spans)))
 
 
 def lane_buckets(configs: list[LLCConfig], waste: int = 2) -> list[list[int]]:
@@ -792,7 +818,8 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
     chunks share sees fewer than ``ways`` arrivals of the other members
     in its set in between (at most one chunk of each).  The records replay
     exactly the lane's access order.  ``members`` is the lane's segments
-    per round without a wrap: 1 + its co-runners."""
+    per round without a wrap: 1 + its co-runners.  ``lane_records``
+    builds the same records without the expanded lane where it can."""
     b, s, c = (np.asarray(a, np.int64) for a in (b, s, c))
     nv = np.asarray(nv, bool)
     n_seg = c.shape[0]
@@ -845,6 +872,122 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
                   )[order],
         nv=stack(np.arange(p)[None, :].repeat(first.size, 0) == 0,
                  (one > 0) & nv[plain, None])[order])
+
+
+def _direct_records(chunks: tuple, llc: LLCConfig, mix: MixConfig) -> bool:
+    """Whether ``lane_records`` builds the lane of ``mix`` at ``llc``
+    over these NVDLA chunks: the trace has a chunk, the co-runners' 64 B
+    lines are no wider than the LLC block (``_split_sparse_corunners``
+    leaves them whole), and no co-runner's span is shorter than a chunk
+    (a chunk wraps it at most once)."""
+    spans = _corunner_spans(llc, mix)
+    cc = chunks[2]
+    return cc.shape[0] > 0 and (not spans or (
+        llc.block_bytes >= 64 and min(s for s, _ in spans) >= cc.max()))
+
+
+def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig
+                 ) -> LaneRecords:
+    """``compact_lane`` of the lane ``corunner_meta`` interleaves, record
+    for record, built from the NVDLA chunks (``nvdla_chunks``) without
+    expanding the lane.  Round k is chunk k and a piece of each
+    co-runner's as many lines; every co-runner's cursor before it is the
+    chunks' prefix sum, so arithmetic on it finds the rounds where a
+    co-runner's chunk wraps its span (two pieces) or ends on its end
+    (the next round restarts the span).  The runs ``compact_lane`` folds
+    follow from the chunks and those rounds, ``_exact_runs`` filters
+    them on their first and last rounds, and only the rounds outside
+    every kept run are expanded, into plain segments.
+
+    Takes the lanes ``_direct_records`` admits; ``compact_lane`` of the
+    expanded lane is the general fold, and this one's oracle
+    (tests/test_compaction.py)."""
+    if not _direct_records(chunks, llc, mix):
+        raise ValueError("lane_records needs a chunk, co-runner lines no "
+                         "wider than the LLC block and spans no shorter "
+                         "than a chunk; fold the expanded lane instead")
+    cb, cs, cc = chunks
+    spans = _corunner_spans(llc, mix)
+    p = 1 + len(spans)
+    n_ch = cc.shape[0]
+    pre = np.cumsum(cc) - cc           # each co-runner's lines before round k
+    wrap = np.zeros(n_ch, bool)        # a co-runner's chunk wraps its span
+    ends = np.zeros(n_ch, bool)        # a co-runner's chunk ends on its end
+    for span in {s for s, _ in spans}:
+        e = pre % span + cc
+        wrap |= e > span
+        ends |= e == span
+    # ``compact_lane``'s links between round k and k + 1
+    cont = ((cs[1:] == cs[:-1]) & (cb[1:] == cb[:-1] + cc[:-1] * cs[:-1])
+            & ~(wrap[:-1] | wrap[1:] | ends[:-1]))
+    eq = cont & (cc[1:] == cc[:-1])
+    tail = cont & (cc[1:] < cc[:-1]) & ~np.append(eq[1:], False)
+    link = eq | tail
+    link[1:] &= ~tail[:-1]
+    edge = np.diff(np.concatenate([[0], link.astype(np.int8), [0]]))
+    first, last = np.flatnonzero(edge == 1), np.flatnonzero(edge == -1)
+
+    def members(k):
+        """Rounds ``k`` (none of them a wrap) as (bases, strides,
+        counts), a column per member."""
+        B = np.empty((k.shape[0], p), np.int64)
+        B[:, 0] = cb[k]
+        for w, (span, region) in enumerate(spans):
+            B[:, 1 + w] = region + pre[k] % span * 64
+        St = np.full((k.shape[0], p), 64, np.int64)
+        St[:, 0] = cs[k]
+        return B, St, np.repeat(cc[k][:, None], p, axis=1)
+
+    if p > 1 and first.size:
+        k = np.arange(first.size)
+        keep, _ = _exact_runs(k, k + first.size,
+                              *members(np.concatenate([first, last])), llc)
+        first, last = first[keep], last[keep]
+    B, St, Cn = members(first)
+    r_count = np.repeat((pre[last] + cc[last] - pre[first])[:, None], p,
+                        axis=1)
+    # the rounds outside every run, expanded as ``corunner_meta`` orders
+    # their segments: the NVDLA chunk, then each co-runner's pieces
+    gap = np.append(0, last + 1)
+    n_gap = np.append(first, n_ch) - gap
+    k = (np.repeat(gap - (np.cumsum(n_gap) - n_gap), n_gap)
+         + np.arange(int(n_gap.sum())))
+    # per segment: base, stride, count, round, slot in the round
+    cols = [[cb[k]], [cs[k]], [cc[k]], [k], [np.zeros_like(k)]]
+    for w, (span, region) in enumerate(spans):
+        start = pre[k] % span
+        take = np.minimum(cc[k], span - start)
+        j = take < cc[k]                          # the wrap's second piece
+        for col, v in zip(cols, (
+                region + start * 64, np.full_like(k, 64), take, k,
+                np.full_like(k, 1 + 2 * w))):
+            col.append(v)
+        for col, v in zip(cols, (
+                np.full_like(k[j], region), np.full_like(k[j], 64),
+                (cc[k] - take)[j], k[j], np.full_like(k[j], 2 + 2 * w))):
+            col.append(v)
+    pb, ps, pc, pr, pslot = (np.concatenate(c) for c in cols)
+
+    def plain(v, fill):
+        """Plain segments as one-member records."""
+        out = np.full((v.shape[0], p), fill, v.dtype)
+        out[:, 0] = v
+        return out
+
+    order = np.lexsort((np.append(np.zeros_like(first), pslot),
+                        np.append(first, pr)))
+    chunk = np.concatenate([Cn if p > 1 else r_count, plain(pc, 0)])[order]
+    return LaneRecords(
+        bases=np.concatenate([B, plain(pb, 0)])[order],
+        strides=np.concatenate([St, plain(ps, 1)])[order],
+        counts=np.concatenate([r_count, plain(pc, 0)])[order],
+        chunks=chunk,
+        offsets=np.cumsum(chunk, axis=1) - chunk,
+        periods=chunk.sum(axis=1),
+        raw=np.concatenate([(last - first + 1) * p,
+                            np.ones(pc.shape[0], np.int64)])[order],
+        nv=np.concatenate([np.arange(p)[None, :].repeat(first.size, 0) == 0,
+                           plain(pslot == 0, False)])[order])
 
 
 def _exact_runs(first, last, B, St, Cn, llc: LLCConfig):
@@ -1225,16 +1368,20 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     (``bench/reference/lane.py``), whatever lanes share its batch.  A
     single lane is a batch of one (``interference_lane_metrics``).
 
-    Each unmasked lane is compacted first (``compact_lane``): where the
-    records shorten a bucket's padded scan by more than their members
-    cost (``_records_that_pay``), the bucket runs as one
-    ``record_lane_scan`` program instead (one per set count, where the
-    bucket mixes them), and a second device program
+    Each unmasked lane is compacted first: ``lane_records`` builds its
+    records from the NVDLA chunks without expanding it, where its
+    co-runner lines fit the LLC block and its spans hold a chunk
+    (``_direct_records``), and ``compact_lane`` folds the expanded lane
+    otherwise.  Where the records shorten a bucket's padded scan by
+    more than their members cost (``_records_that_pay``), the bucket
+    runs as one ``record_lane_scan`` program instead (one per set
+    count, where the bucket mixes them), and a second device program
     (``_record_rows``) reduces each lane's hit codes to its missed
     blocks and DRAM row hits, so the host fetches counts, not codes —
-    the same metrics, bit for bit.  The whole frame's lanes take it;
-    the Fig. 6 windows, whose NVDLA chunks never continue one another,
-    do not.
+    the same metrics, bit for bit.  A lane is expanded only where it
+    runs as segments or has no direct records.  The whole frame's lanes
+    compact; the Fig. 6 windows, whose NVDLA chunks never continue one
+    another, do not.
 
     ``mesh`` (a 1-D ``jax.sharding.Mesh``, see
     ``repro.launch.mesh.make_sweep_mesh``) shards the lane axis across
@@ -1261,44 +1408,63 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
             f"way_masks length {len(way_masks)} != lanes {lanes_n}")
     if lanes_n == 0:
         return []
-    with tracing.span(tracing.LANE_PLAN):
-        chunks = nvdla_chunks(nvdla_segs, chunk_bursts)
-        lanes, nv_masks, lane_sels, n_segments = [], [], [], []
-        for i, (llc, dram, mix) in enumerate(zip(llcs, drams, mixes)):
-            _check_row_block(llc, dram)
-            b, s, c, nv = corunner_meta(nvdla_segs, llc=llc, mix=mix,
+    masked = way_masks is not None
+    if masked and mesh is not None:
+        raise ValueError("way-masked batches do not support mesh "
+                         "sharding yet — pass mesh=None")
+    expanded: list = [None] * lanes_n
+
+    def lane(i):
+        """Lane ``i`` expanded, as the lane engines take it, and its
+        segment count before ``_split_sparse_corunners``."""
+        if expanded[i] is None:
+            b, s, c, nv = corunner_meta(nvdla_segs, llc=llcs[i],
+                                        mix=mixes[i],
                                         chunk_bursts=chunk_bursts,
                                         _chunks=chunks)
-            n_segments.append(c.shape[0])
-            b, s, c, nv = _split_sparse_corunners(b, s, c, nv, llc)
-            _check_lane_support_meta([(b, s, c)], [llc])
-            lanes.append((b, s, c))
-            nv_masks.append(nv)
-            wm = way_masks[i] if way_masks is not None else None
-            lane_sels.append(None if wm is None
-                             else partition_way_sels(nv, llc, wm))
-        masked = way_masks is not None
-        if masked and mesh is not None:
-            raise ValueError("way-masked batches do not support mesh "
-                             "sharding yet — pass mesh=None")
-    records = [None] * lanes_n
-    with tracing.span(tracing.COMPACT):
+            n_seg = c.shape[0]
+            b, s, c, nv = _split_sparse_corunners(b, s, c, nv, llcs[i])
+            _check_lane_support_meta([(b, s, c)], [llcs[i]])
+            expanded[i] = (b, s, c, nv), n_seg
+        return expanded[i]
+
+    with tracing.span(tracing.LANE_PLAN):
+        chunks = nvdla_chunks(nvdla_segs, chunk_bursts)
         # way-masked lanes replay every segment in the round scan, so
         # only unmasked batches compact; a record folds repeats of an
         # NVDLA chunk that continues the one before, so a trace with
         # none (the Fig. 6 windows) has nothing to fold
-        if not masked and _chunks_continue(chunks):
-            records = [compact_lane(*lanes[i], nv_masks[i], llcs[i],
-                                    1 + (0 if m.wss == "l1" else m.corunners))
-                       for i, m in enumerate(mixes)]
+        compact = not masked and _chunks_continue(chunks)
+        direct = [compact and _direct_records(chunks, llc, mix)
+                  for llc, mix in zip(llcs, mixes)]
+        checked: set = set()
+        for i, (llc, dram) in enumerate(zip(llcs, drams)):
+            _check_row_block(llc, dram)
+            if direct[i]:
+                _check_records_support(chunks, llc, mixes[i], checked)
+            else:
+                lane(i)
+        lane_sels = [None if not masked or way_masks[i] is None
+                     else partition_way_sels(lane(i)[0][3], llcs[i],
+                                             way_masks[i])
+                     for i in range(lanes_n)]
+    records = [None] * lanes_n
+    with tracing.span(tracing.COMPACT):
+        if compact:
+            records = [
+                lane_records(chunks, llc, mix) if direct[i]
+                else compact_lane(*lane(i)[0], llc, 1 + (
+                    0 if mix.wss == "l1" else mix.corunners))
+                for i, (llc, mix) in enumerate(zip(llcs, mixes))]
+            tracing.count(tracing.DIRECT_RECORD_LANES, sum(direct))
     out: list[LaneMetrics | None] = [None] * lanes_n
     for bucket in lane_buckets(llcs):
         with tracing.span(tracing.LANE_BATCH):
             with tracing.span(tracing.LANE_PLAN):
                 cfgs_b = [llcs[i] for i in bucket]
-                recs = _records_that_pay([records[i] for i in bucket],
-                                         [lanes[i] for i in bucket])
-                raw = [lanes[i][2].shape[0] for i in bucket]
+                recs = _records_that_pay([records[i] for i in bucket])
+                raw = [lane(i)[0][2].shape[0] if records[i] is None
+                       else int(records[i].raw.sum()) for i in bucket]
                 tracing.count(tracing.LANE_SEGMENTS_RAW, sum(raw))
                 tracing.count(tracing.LANE_SEGMENTS, sum(
                     raw if recs is None else [r.raw.shape[0] for r in recs]))
@@ -1311,7 +1477,7 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                         [recs[j] for j in js], [cfgs_b[j] for j in js]))
                         for js in parts]
                 else:
-                    views = ([(*lanes[i], nv_masks[i]) for i in bucket]
+                    views = ([lane(i)[0] for i in bucket]
                              if recs is None else
                              # one member each: plain segments
                              [(r.bases[:, 0], r.strides[:, 0],
@@ -1322,8 +1488,9 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
             for part, run in runs:
                 got = run([drams[i] for i in part], t_llc_hit, mesh)
                 for i, m in zip(part, got):
-                    out[i] = dataclasses.replace(m,
-                                                 segments=n_segments[i])
+                    out[i] = dataclasses.replace(m, segments=(
+                        int(records[i].raw.sum()) if direct[i]
+                        else lane(i)[1]))
     return out
 
 
@@ -1335,15 +1502,16 @@ def _chunks_continue(chunks) -> bool:
                        & (b[1:] == b[:-1] + c[:-1] * s[:-1])))
 
 
-def _records_that_pay(recs: list, lanes: list) -> list | None:
+def _records_that_pay(recs: list) -> list | None:
     """A bucket's records where they pay, else None.  A record of P
     members costs up to P times a segment's step in the round scan, so
-    compaction must shorten the padded scan by more than that."""
+    compaction must shorten the padded scan, the most segments a lane
+    of the bucket has uncompacted (``raw.sum()``), by more than that."""
     if recs[0] is None:
         return None
     members = max(r.members for r in recs)
     longest = max(r.raw.shape[0] for r in recs)
-    if longest * members >= max(c.shape[0] for _, _, c in lanes):
+    if longest * members >= max(int(r.raw.sum()) for r in recs):
         return None
     return recs
 

@@ -20,6 +20,8 @@ interference path plans its lanes (``LANE_PLAN``) and compacts them
 runs ``LANE_PLAN`` -> ``DISPATCH`` -> ``FETCH`` -> ``DISPATCH`` ->
 ``FETCH``: the record program, its hits, then the device reduction of
 its lanes and their counts, with no ``MISS_RUNS`` or ``DRAM_ROWS``.
+``COMPACT`` builds most such lanes' records from the NVDLA chunks
+(``DIRECT_RECORD_LANES``), so ``LANE_PLAN`` expands only the rest.
 ``CAMPAIGN`` and ``LANE_BATCH`` are parents: they own only the time
 their leaves leave.
 """
@@ -52,6 +54,7 @@ MISS_WIDTH = "sweep.miss_width"    # miss-bit widths W of collecting programs
 LANE_SEGMENTS = "sweep.lane_segments"          # records the programs scan
 LANE_SEGMENTS_RAW = "sweep.lane_segments_raw"  # the same, uncompacted
 DEVICE_REDUCED_LANES = "sweep.device_reduced_lanes"  # lanes counted on device
+DIRECT_RECORD_LANES = "sweep.direct_record_lanes"  # records, trace unexpanded
 
 _counts: dict[str, int] = {}
 _lock = threading.Lock()

@@ -31,6 +31,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from bench.entries.frame_grid import seeded_regions  # noqa: E402
 from bench.reference import lane as ref_lane  # noqa: E402
 
 with open(os.path.join(ROOT, "bench/configs/nvdla-soc-yolov3.json")) as f:
@@ -63,7 +64,7 @@ def compacted():
 @pytest.fixture(scope="module")
 def uncompacted():
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(sweep, "_records_that_pay", lambda recs, lanes: None)
+        mp.setattr(sweep, "_records_that_pay", lambda recs: None)
         return _batch(LANES)
 
 
@@ -236,6 +237,105 @@ def test_model_regions_and_cut_hash_only_when_set():
         ModelSpec(window_bursts=None, regions=(0, 1))
     with pytest.raises(ValueError):
         ModelSpec(backend="npu", regions=(0, 2048, 4096))
+
+
+# NVDLA segments that continue one another: a run of full chunks
+# crosses the first two segment ends, a short tail chunk closes the
+# third, a stride change and a mid-block base follow
+SPLICED = [traces.Segment(0, 32, 640), traces.Segment(20480, 32, 480),
+           traces.Segment(35840, 32, 400), traces.Segment(48640, 32, 320),
+           traces.Segment(1 << 20, 64, 1000),
+           traces.Segment((1 << 21) + 32, 32, 777)]
+SEEDED = traces.network_trace(max_ops=2, regions=seeded_regions(
+    CFG, 2**31 + 11, 32768))
+BLOCK128 = LLCConfig(size_bytes=65536, ways=8, block_bytes=128)
+# the llc co-runners' span is one chunk: every round ends or wraps it
+SPAN16 = LLCConfig(size_bytes=2048, ways=2, block_bytes=64)
+DIRECT = ([(f"{m.wss}-x{m.corunners}", FRAME, 16, WIDE, m) for m in MIXES]
+          + [(f"ways4-dram-x{k}", FRAME, 16, NARROW, MixConfig(k, "dram"))
+             for k in (2, 4)]
+          + [(f"block128-{wss}-x{k}", FRAME, 16, BLOCK128, MixConfig(k, wss))
+             for wss, k in (("llc", 2), ("dram", 3))]
+          + [(f"seeded-{wss}-x{k}", SEEDED, 16, WIDE, MixConfig(k, wss))
+             for wss, k in (("llc", 3), ("dram", 4))]
+          + [(f"spliced-{wss}-x{k}", SPLICED, 16, WIDE, MixConfig(k, wss))
+             for wss, k in (("l1", 0), ("llc", 4), ("dram", 2))]
+          + [("chunks6-dram-x2", FRAME, 6, WIDE, MixConfig(2, "dram")),
+             ("span16-llc-x2", SPLICED, 16, SPAN16, MixConfig(2, "llc"))])
+
+
+@pytest.mark.parametrize("segs,chunk_bursts,llc,mix",
+                         [c[1:] for c in DIRECT], ids=[c[0] for c in DIRECT])
+def test_lane_records_match_the_folded_lane(segs, chunk_bursts, llc, mix):
+    """``lane_records`` gives, field for field and in the same order,
+    the records ``compact_lane`` folds from the expanded lane."""
+    chunks = sweep.nvdla_chunks(segs, chunk_bursts)
+    assert sweep._direct_records(chunks, llc, mix)
+    got = sweep.lane_records(chunks, llc, mix)
+    b, s, c, nv = sweep.corunner_meta(segs, llc=llc, mix=mix,
+                                      chunk_bursts=chunk_bursts)
+    want = sweep.compact_lane(b, s, c, nv, llc, 1 + (
+        0 if mix.wss == "l1" else mix.corunners))
+    for f in dataclasses.fields(want):
+        g, w = getattr(got, f.name), getattr(want, f.name)
+        assert g.dtype == w.dtype and np.array_equal(g, w), f.name
+    assert int(got.raw.sum()) == c.shape[0]
+
+
+def test_the_frame_batch_built_its_records_directly(compacted, monkeypatch):
+    """Every lane of the frame batch takes ``lane_records``: the batch
+    never expands a lane (``corunner_meta`` raises here) and gives the
+    same metrics."""
+    assert compacted[1][tracing.DIRECT_RECORD_LANES] == len(LANES)
+
+    def expanded(*args, **kw):
+        raise AssertionError("a lane whose records pay was expanded")
+
+    monkeypatch.setattr(sweep, "corunner_meta", expanded)
+    assert _batch(LANES) == compacted[0]
+
+
+WINDOW = traces.default_dbb_window(max_bursts=2048)
+# the frame's first segments, cut short: their chunks continue
+CUT = [traces.Segment(s.base, s.stride, min(s.count, 300)) for s in FRAME]
+BLOCK32 = LLCConfig(size_bytes=16384, ways=4, block_bytes=32)
+SPAN8 = LLCConfig(size_bytes=1024, ways=2, block_bytes=64)
+
+
+@pytest.mark.parametrize("segs,lanes,way_masks,direct", [
+    (WINDOW, [(WIDE, MixConfig(2, "dram")), (WIDE, MixConfig(3, "llc"))],
+     None, 0),
+    (CUT, [(WIDE, MixConfig(2, "dram")), (WIDE, MixConfig(3, "llc"))],
+     [0x0F, None], 0),
+    (CUT, [(BLOCK32, MixConfig(2, "dram"))], None, 0),
+    (CUT, [(SPAN8, MixConfig(2, "llc"))], None, 0),
+    (CUT, [(BLOCK32, MixConfig(1, "dram")), (BLOCK32, MixConfig(0, "l1")),
+           (WIDE, MixConfig(2, "llc"))], None, 2),
+], ids=["window", "way-masked", "block32", "span8", "mixed"])
+def test_lanes_build_directly_only_where_they_can(segs, lanes, way_masks,
+                                                  direct):
+    """A Fig. 6 window (no chunk continues another), a way-masked batch,
+    co-runner lines wider than a 32 B block and a span shorter than a
+    chunk keep the expanded lane; a batch that mixes them builds the
+    others' records directly.  Either way every lane gives what the
+    plain per-access reference gives."""
+    import lane_reference
+
+    chunks = sweep.nvdla_chunks(segs)
+    before = tracing.counters()
+    got = interference_lane_metrics_batch(
+        segs, llcs=[llc for llc, _ in lanes], drams=[DRAM] * len(lanes),
+        mixes=[m for _, m in lanes], way_masks=way_masks)
+    grew = (tracing.counters().get(tracing.DIRECT_RECORD_LANES, 0)
+            - before.get(tracing.DIRECT_RECORD_LANES, 0))
+    assert grew == direct
+    for i, (llc, mix) in enumerate(lanes):
+        wm = None if way_masks is None else way_masks[i]
+        assert got[i] == lane_reference.lane_metrics(
+            segs, llc=llc, dram=DRAM, mix=mix, way_mask=wm)
+        if not sweep._direct_records(chunks, llc, mix):
+            with pytest.raises(ValueError):
+                sweep.lane_records(chunks, llc, mix)
 
 
 @pytest.mark.parametrize("llc,mixes", [

@@ -240,8 +240,7 @@ def test_part_block_chunks_stay_uncompacted(chunk_bursts):
 
     got, reduced = _reduced(batch)
     assert reduced == 2 * (chunk_bursts == 6)
-    with mock.patch.object(sweep, "_records_that_pay",
-                           lambda recs, lanes: None):
+    with mock.patch.object(sweep, "_records_that_pay", lambda recs: None):
         assert batch() == got
 
 
