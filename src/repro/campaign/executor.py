@@ -135,24 +135,29 @@ def run_batch(points: list[CampaignPoint], nvdla_segs: list,
     chunk_bursts = {p.model.chunk_bursts for p in points}
     if len(chunk_bursts) != 1:
         raise ValueError("batch mixes chunk_bursts values; shard first")
+    way_masks = [p.way_mask for p in points]
     return interference_lane_metrics_batch(
         nvdla_segs,
         llcs=[p.geometry.llc() for p in points],
         drams=[p.dram.dram() for p in points],
         mixes=[p.mix.mix() for p in points],
-        chunk_bursts=chunk_bursts.pop(), mesh=mesh)
+        chunk_bursts=chunk_bursts.pop(), mesh=mesh,
+        way_masks=None if all(w is None for w in way_masks)
+        else way_masks)
 
 
 def _monotone_family_key(point: CampaignPoint) -> tuple | None:
     """Family under which LRU inclusion makes hit counts monotone in
     ways: identical trace (solo lanes only — co-runner traces depend on
-    the LLC size) and identical (sets, block).  None = not comparable."""
+    the LLC size), identical (sets, block) and the same way partition
+    (a fenced NVDLA allocates into its mask's ways only, whatever the
+    geometry's).  None = not comparable."""
     if point.mix.corunners and point.mix.wss != "l1":
         return None
     llc = point.geometry.llc()
     return (canonical_json(point.model.to_dict()),
             canonical_json(point.dram.to_dict()),
-            llc.sets, llc.block_bytes)
+            llc.sets, llc.block_bytes, point.way_mask)
 
 
 def validate_result(point: CampaignPoint, result: LaneMetrics,

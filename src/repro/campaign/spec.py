@@ -3,6 +3,7 @@
 A campaign is the cross product
 
     models x LLC geometries x co-runner mixes x DRAM configs
+      x LLC way partitions
 
 expanded into a *deterministic* list of ``CampaignPoint``s: same spec,
 same point list, same order, and every point carries a stable
@@ -248,21 +249,33 @@ class DRAMSpec:
 
 @dataclasses.dataclass(frozen=True)
 class CampaignPoint:
-    """One (model, geometry, mix, dram) simulation.  ``point_id`` hashes
-    the physics-determining parameters plus ``SPEC_VERSION`` so result
-    records are self-describing and spec edits invalidate exactly the
-    points they change."""
+    """One (model, geometry, mix, dram) simulation, optionally on a way
+    -partitioned LLC.  ``point_id`` hashes the physics-determining
+    parameters plus ``SPEC_VERSION`` so result records are
+    self-describing and spec edits invalidate exactly the points they
+    change.
+
+    ``way_mask`` is the NVDLA's LLC capacity bitmask (Intel CAT
+    semantics, ``repro.core.sweep.partition_way_sels``): the NVDLA
+    allocates only into its ways, the co-runners into the complement,
+    and either hits in any way.  ``None`` is the unpartitioned LLC; the
+    mask hashes only when set, so every unpartitioned point keeps its
+    id."""
     model: ModelSpec
     geometry: GeometrySpec
     mix: MixSpec
     dram: DRAMSpec
+    way_mask: int | None = None
 
     def params(self) -> dict:
-        return {"spec_version": SPEC_VERSION,
-                "model": self.model.to_dict(),
-                "geometry": self.geometry.to_dict(),
-                "mix": self.mix.to_dict(),
-                "dram": self.dram.to_dict()}
+        params = {"spec_version": SPEC_VERSION,
+                  "model": self.model.to_dict(),
+                  "geometry": self.geometry.to_dict(),
+                  "mix": self.mix.to_dict(),
+                  "dram": self.dram.to_dict()}
+        if self.way_mask is not None:
+            params["way_mask"] = self.way_mask
+        return params
 
     @property
     def point_id(self) -> str:
@@ -271,17 +284,35 @@ class CampaignPoint:
 
 @dataclasses.dataclass(frozen=True)
 class CampaignSpec:
+    """``way_masks`` lists the NVDLA's LLC capacity bitmasks the points
+    run under (``CampaignPoint.way_mask``; ``None`` unpartitioned).
+    Each mask must be non-zero and lie inside every geometry's ways.
+    The axis hashes only where it is not the default ``(None,)``."""
     name: str
     models: tuple[ModelSpec, ...] = (ModelSpec(),)
     geometries: tuple[GeometrySpec, ...] = (GeometrySpec(2048),)
     mixes: tuple[MixSpec, ...] = (MixSpec(),)
     drams: tuple[DRAMSpec, ...] = (DRAMSpec(),)
+    way_masks: tuple[int | None, ...] = (None,)
 
     def __post_init__(self):
+        object.__setattr__(self, "way_masks", tuple(self.way_masks))
         if not (self.models and self.geometries and self.mixes
-                and self.drams):
+                and self.drams and self.way_masks):
             raise ValueError("a campaign needs at least one model, "
-                             "geometry, mix, and DRAM config")
+                             "geometry, mix, DRAM config and way mask")
+        for w in self.way_masks:
+            if w is None:
+                continue
+            if not isinstance(w, int) or isinstance(w, bool) or w <= 0:
+                raise ValueError(f"way mask {w!r} must be a positive int: "
+                                 "the NVDLA's partition holds a way")
+            for g in self.geometries:
+                ways = g.llc().ways
+                if w >> ways:
+                    raise ValueError(
+                        f"way mask {w:#x} names ways past the {ways} of "
+                        f"geometry {g.to_dict()}")
         for d in self.drams:
             for g in self.geometries:
                 if d.row_bytes % g.block:
@@ -293,21 +324,26 @@ class CampaignSpec:
 
     def expand(self) -> list[CampaignPoint]:
         """The deterministic point list: models (outer) x drams x mixes
-        x geometries (inner), exactly the spec's declared order."""
-        return [CampaignPoint(m, g, x, d)
+        x way masks x geometries (inner), exactly the spec's declared
+        order."""
+        return [CampaignPoint(m, g, x, d, w)
                 for m in self.models for d in self.drams
-                for x in self.mixes for g in self.geometries]
+                for x in self.mixes for w in self.way_masks
+                for g in self.geometries]
 
     @property
     def spec_hash(self) -> str:
         return content_hash(self.to_dict())
 
     def to_dict(self) -> dict:
-        return {"spec_version": SPEC_VERSION, "name": self.name,
-                "models": [m.to_dict() for m in self.models],
-                "geometries": [g.to_dict() for g in self.geometries],
-                "mixes": [x.to_dict() for x in self.mixes],
-                "drams": [d.to_dict() for d in self.drams]}
+        out = {"spec_version": SPEC_VERSION, "name": self.name,
+               "models": [m.to_dict() for m in self.models],
+               "geometries": [g.to_dict() for g in self.geometries],
+               "mixes": [x.to_dict() for x in self.mixes],
+               "drams": [d.to_dict() for d in self.drams]}
+        if self.way_masks != (None,):
+            out["way_masks"] = list(self.way_masks)
+        return out
 
     @classmethod
     def from_dict(cls, d: dict) -> "CampaignSpec":
@@ -322,7 +358,8 @@ class CampaignSpec:
             geometries=tuple(GeometrySpec(**g)
                              for g in d["geometries"]),
             mixes=tuple(MixSpec(**x) for x in d.get("mixes", [{}])),
-            drams=tuple(DRAMSpec(**x) for x in d.get("drams", [{}])))
+            drams=tuple(DRAMSpec(**x) for x in d.get("drams", [{}])),
+            way_masks=tuple(d.get("way_masks", [None])))
 
     @classmethod
     def load(cls, path: str) -> "CampaignSpec":

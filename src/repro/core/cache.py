@@ -403,7 +403,7 @@ def segment_lane_scan(bases, strides, counts, r_needed, cold,
 
 
 def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
-                     r_needed, sets, ways, block_bytes,
+                     r_needed, sets, ways, block_bytes, masks=None,
                      *, max_sets: int, max_ways: int, r_pad: int):
     """One sweep lane of *compacted records*, exact, with runtime geometry.
 
@@ -453,7 +453,21 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
     name missed on its first access, and every later access to a block
     hits — from which ``repro.core.sweep`` rebuilds the exact miss runs
     in the order of the uncompacted trace.
+
+    ``masks`` (optional, (S, P) int32) adds LLC way partitioning with
+    ``segment_lane_scan``'s semantics: member ``m`` allocates only into
+    the ways of ``masks[m]`` (zero, the sentinel, allocates anywhere)
+    and hits in any way.  Members with the same mask are one class; the
+    caller (``repro.core.sweep``) gives masks only where the classes'
+    ways are disjoint and no block is touched by two classes, so each
+    set is one LRU stack per class over the class's ways, and the
+    record's exactness holds per class, against the class's way count
+    ``k``.  The round scan serves each class its first ``min(k,
+    arrivals)`` arrivals in a set, and the suffix fills each full
+    class's ways with its newest blocks.  Without ``masks`` the program
+    is the unpartitioned one.
     """
+    masked = masks is not None
     n_mem = bases.shape[-1]
     code_type = (jnp.int8 if n_mem * max_ways < np.iinfo(np.int8).max
                  else jnp.int16)
@@ -470,7 +484,19 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
 
     def per_record(carry, meta):
         tags, ts, counter = carry          # (max_ways, max_sets) x2, scalar
-        base, stride, count, chunk, offset, period, rounds = meta
+        base, stride, count, chunk, offset, period, rounds = meta[:7]
+        if masked:
+            # each member's allocation ways, (P, max_ways); one class per
+            # mask, with its way count
+            mask = meta[7][:, None]
+            alloc = way_mask[None, :] & (
+                (mask == 0) | (((mask >> way_idx[None, :]) & 1) != 0))
+            same = jnp.all(alloc[:, None, :] == alloc[None, :, :], axis=-1)
+            k_ways = jnp.sum(alloc, axis=1, dtype=jnp.int32)[:, None]
+
+            def of_class(x):               # (P, sets): summed over the class
+                return jnp.sum(jnp.where(same[:, :, None], x[None], 0),
+                               axis=1)
         live = count > 0
         chunk = jnp.maximum(chunk, 1)
         b_first = base // bb
@@ -492,6 +518,8 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
             tags, ts, hits, ptr, code = inner
             blk = block_of(ptr)
             valid = set_mask & (ptr < per)
+            if masked:                     # a class's first k arrivals only
+                valid = valid & (of_class(ptr) < k_ways)
             t_lo = jnp.where(valid, vtime(_first_access(
                 blk, col(base), col(stride), bb)), imax)
             first = jnp.min(t_lo, axis=0)
@@ -502,8 +530,12 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
             t_hi = jnp.sum(jnp.where(pick, vtime(_last_access(
                 blk, col(base), col(stride), col(count), bb)), 0), axis=0,
                 dtype=jnp.int32)
-            key = jnp.where(tags == t[None, :], -1,
-                            jnp.where(way_mask[:, None], ts, imax))
+            match = tags == t[None, :]
+            victims = way_mask[:, None]
+            if masked:                     # the picked member's ways
+                victims = jnp.any(pick[:, None, :] & alloc[:, :, None],
+                                  axis=0)
+            key = jnp.where(match, -1, jnp.where(victims, ts, imax))
             kmin = jnp.min(key, axis=0)
             hit = (kmin == -1) & arrive
             is_min = key == kmin[None, :]
@@ -525,19 +557,27 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
 
         # closed-form suffix: a set past `ways` arrivals ends holding the
         # record's `ways` blocks touched last, each member's latest first
+        # (partitioned: per class, in the class's ways)
         full = set_mask[0] & (total > ways)
+        if masked:
+            full_m = set_mask & (of_class(per) > k_ways)
 
         def newest(w, inner):
             tags, ts, taken = inner
             q = per - 1 - taken
             valid = set_mask & (q >= 0)
+            filled = full
+            if masked:                     # way w's class
+                mine = jnp.any(alloc & (way_idx == w)[None, :], axis=1)
+                valid = valid & mine[:, None]
+                filled = jnp.any(mine[:, None] & full_m, axis=0)
             blk = block_of(q)
             t_hi = jnp.where(valid, vtime(_last_access(
                 blk, col(base), col(stride), col(count), bb)), -1)
             last = jnp.max(t_hi, axis=0)
             pick = valid & (t_hi == last[None, :])
             t = jnp.sum(jnp.where(pick, blk // sets, 0), axis=0)
-            write = (way_idx == w)[:, None] & full[None, :]
+            write = (way_idx == w)[:, None] & filled[None, :]
             tags = jnp.where(write, t.astype(jnp.int32)[None, :], tags)
             ts = jnp.where(write, (counter + last + 1)[None, :], ts)
             return tags, ts, taken + pick
@@ -553,7 +593,8 @@ def record_lane_scan(bases, strides, counts, chunks, offsets, periods,
     init = (jnp.full((max_ways, max_sets), -1, jnp.int32),
             jnp.zeros((max_ways, max_sets), jnp.int32),
             jnp.int32(0))
-    _, (hits, codes) = jax.lax.scan(
-        per_record, init, (bases, strides, counts, chunks, offsets,
-                           periods, r_needed))
+    xs = (bases, strides, counts, chunks, offsets, periods, r_needed)
+    if masked:
+        xs += (masks,)
+    _, (hits, codes) = jax.lax.scan(per_record, init, xs)
     return hits, codes

@@ -41,7 +41,7 @@ Public API:
                             ``jax.sharding`` mesh (the campaign
                             executor's data-parallel path) and
                             optionally per-lane way-partitioned
-                            (``way_masks=``);
+                            (``way_masks=``), compacted either way;
 * ``interference_lane_metrics``       — one lane -> ``LaneMetrics``,
                             the batch of one, optionally LLC
                             way-partitioned (``way_mask=``);
@@ -785,7 +785,9 @@ class LaneRecords:
     accesses per repeat) and ``offsets`` (the chunks of the members
     before it); per record (S,) ``periods`` (accesses per repeat) and
     ``raw`` (the uncompacted segments it stands for); ``nv`` (S, P)
-    marks NVDLA members.  Live members come first in every record."""
+    marks NVDLA members, and ``masks`` (S, P) holds each member's LLC
+    allocation mask (``partition_way_sels``; 0, unpartitioned, in a lane
+    without a partition).  Live members come first in every record."""
     bases: np.ndarray
     strides: np.ndarray
     counts: np.ndarray
@@ -794,6 +796,7 @@ class LaneRecords:
     periods: np.ndarray
     raw: np.ndarray
     nv: np.ndarray
+    masks: np.ndarray
 
     @property
     def members(self) -> int:
@@ -803,7 +806,8 @@ class LaneRecords:
         return max(1, int(live.max(initial=1)))
 
 
-def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
+def compact_lane(b, s, c, nv, llc: LLCConfig, members: int,
+                 sels=None) -> LaneRecords:
     """Compact one interleaved lane (``corunner_meta``'s arrays) into
     records: each maximal run of two or more arbiter rounds in which
     every member — the NVDLA chunk, then each co-runner's — continues
@@ -818,10 +822,16 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
     chunks share sees fewer than ``ways`` arrivals of the other members
     in its set in between (at most one chunk of each).  The records replay
     exactly the lane's access order.  ``members`` is the lane's segments
-    per round without a wrap: 1 + its co-runners.  ``lane_records``
-    builds the same records without the expanded lane where it can."""
+    per round without a wrap: 1 + its co-runners.  ``sels`` gives a
+    way-partitioned lane's allocation mask per segment
+    (``partition_way_sels``); the records carry them, and the arrivals a
+    shared block waits out count per class, against the class's ways.
+    ``lane_records`` builds the same records without the expanded lane
+    where it can."""
     b, s, c = (np.asarray(a, np.int64) for a in (b, s, c))
     nv = np.asarray(nv, bool)
+    sels = (np.zeros(c.shape[0], np.int64) if sels is None
+            else np.asarray(sels, np.int64))
     n_seg = c.shape[0]
     starts = np.flatnonzero(nv)          # every round opens with the NVDLA
     if n_seg == 0 or starts.size == 0 or starts[0] != 0:
@@ -831,7 +841,7 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
     p = members
     normal = size == p
     at = np.minimum(starts[:, None] + np.arange(p)[None, :], n_seg - 1)
-    B, St, Cn = b[at], s[at], c[at]
+    B, St, Cn, M = b[at], s[at], c[at], sels[at]
     cont = (normal[:-1] & normal[1:] & (St[1:] == St[:-1]).all(1)
             & (B[1:] == B[:-1] + Cn[:-1] * St[:-1]).all(1))
     eq = cont & (Cn[1:] == Cn[:-1]).all(1)
@@ -841,7 +851,7 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
     edge = np.diff(np.concatenate([[0], link.astype(np.int8), [0]]))
     first, last = np.flatnonzero(edge == 1), np.flatnonzero(edge == -1)
     if p > 1 and first.size:
-        first, last = _exact_runs(first, last, B, St, Cn, llc)
+        first, last = _exact_runs(first, last, B, St, Cn, M, llc)
     covered = np.zeros(starts.shape[0] + 1, np.int64)
     np.add.at(covered, first, 1)
     np.add.at(covered, last + 1, -1)
@@ -871,7 +881,8 @@ def compact_lane(b, s, c, nv, llc: LLCConfig, members: int) -> LaneRecords:
         raw=stack((last - first + 1) * p, np.ones(plain.size, np.int64)
                   )[order],
         nv=stack(np.arange(p)[None, :].repeat(first.size, 0) == 0,
-                 (one > 0) & nv[plain, None])[order])
+                 (one > 0) & nv[plain, None])[order],
+        masks=stack(M[first], one * sels[plain, None])[order])
 
 
 def _direct_records(chunks: tuple, llc: LLCConfig, mix: MixConfig) -> bool:
@@ -886,8 +897,8 @@ def _direct_records(chunks: tuple, llc: LLCConfig, mix: MixConfig) -> bool:
         llc.block_bytes >= 64 and min(s for s, _ in spans) >= cc.max()))
 
 
-def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig
-                 ) -> LaneRecords:
+def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig,
+                 way_mask: int | None = None) -> LaneRecords:
     """``compact_lane`` of the lane ``corunner_meta`` interleaves, record
     for record, built from the NVDLA chunks (``nvdla_chunks``) without
     expanding the lane.  Round k is chunk k and a piece of each
@@ -897,7 +908,8 @@ def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig
     (the next round restarts the span).  The runs ``compact_lane`` folds
     follow from the chunks and those rounds, ``_exact_runs`` filters
     them on their first and last rounds, and only the rounds outside
-    every kept run are expanded, into plain segments.
+    every kept run are expanded, into plain segments.  ``way_mask``
+    partitions the lane's LLC as ``interference_lane_metrics`` does.
 
     Takes the lanes ``_direct_records`` admits; ``compact_lane`` of the
     expanded lane is the general fold, and this one's oracle
@@ -909,6 +921,9 @@ def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig
     cb, cs, cc = chunks
     spans = _corunner_spans(llc, mix)
     p = 1 + len(spans)
+    # each member's allocation mask: the NVDLA's, then the co-runners'
+    sel = (np.zeros(p, np.int64) if way_mask is None else partition_way_sels(
+        np.arange(p) == 0, llc, way_mask).astype(np.int64))
     n_ch = cc.shape[0]
     pre = np.cumsum(cc) - cc           # each co-runner's lines before round k
     wrap = np.zeros(n_ch, bool)        # a co-runner's chunk wraps its span
@@ -929,21 +944,22 @@ def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig
 
     def members(k):
         """Rounds ``k`` (none of them a wrap) as (bases, strides,
-        counts), a column per member."""
+        counts, masks), a column per member."""
         B = np.empty((k.shape[0], p), np.int64)
         B[:, 0] = cb[k]
         for w, (span, region) in enumerate(spans):
             B[:, 1 + w] = region + pre[k] % span * 64
         St = np.full((k.shape[0], p), 64, np.int64)
         St[:, 0] = cs[k]
-        return B, St, np.repeat(cc[k][:, None], p, axis=1)
+        return (B, St, np.repeat(cc[k][:, None], p, axis=1),
+                np.repeat(sel[None, :], k.shape[0], axis=0))
 
     if p > 1 and first.size:
         k = np.arange(first.size)
         keep, _ = _exact_runs(k, k + first.size,
                               *members(np.concatenate([first, last])), llc)
         first, last = first[keep], last[keep]
-    B, St, Cn = members(first)
+    B, St, Cn, M = members(first)
     r_count = np.repeat((pre[last] + cc[last] - pre[first])[:, None], p,
                         axis=1)
     # the rounds outside every run, expanded as ``corunner_meta`` orders
@@ -987,12 +1003,15 @@ def lane_records(chunks: tuple, llc: LLCConfig, mix: MixConfig
         raw=np.concatenate([(last - first + 1) * p,
                             np.ones(pc.shape[0], np.int64)])[order],
         nv=np.concatenate([np.arange(p)[None, :].repeat(first.size, 0) == 0,
-                           plain(pslot == 0, False)])[order])
+                           plain(pslot == 0, False)])[order],
+        masks=np.concatenate([M, plain(np.where(pslot == 0, sel[0],
+                                                sel[-1]), 0)])[order])
 
 
-def _exact_runs(first, last, B, St, Cn, llc: LLCConfig):
+def _exact_runs(first, last, B, St, Cn, M, llc: LLCConfig):
     """The runs of several members the record engine replays exactly at
-    ``llc`` (``compact_lane``)."""
+    ``llc`` (``compact_lane``); ``M`` holds the members' allocation
+    masks."""
     bb, sets, ways = llc.block_bytes, llc.sets, llc.ways
     width = Cn[first] * St[first]                     # bytes per chunk
     lo = B[first] // bb
@@ -1005,11 +1024,40 @@ def _exact_runs(first, last, B, St, Cn, llc: LLCConfig):
         for m2 in range(m + 1, p):
             ok &= (hi[:, m] < lo[:, m2]) | (hi[:, m2] < lo[:, m])
     # a shared boundary block waits out one chunk of every other member
+    # of its class, which only those can evict from the class's ways
     arrivals = -(-(-(-width // bb) + 1) // sets)
     shares = (B[first] % bb) != 0
-    others = arrivals.sum(1, keepdims=True) - arrivals
-    ok &= ~(shares & (others >= ways)).any(1)
+    same, k = _classes(M[first], ways)
+    others = (same * arrivals[:, None, :]).sum(2) - arrivals
+    ok &= ~(shares & (others >= k)).any(1)
     return first[ok], last[ok]
+
+
+def _classes(masks, ways: int) -> tuple[np.ndarray, np.ndarray]:
+    """Members' allocation masks (..., P) at ``ways`` ways as classes:
+    whether two members allocate into the same ways (..., P, P), and
+    each member's way count (..., P).  Zero, the unpartitioned
+    sentinel, is every way, as the full mask is."""
+    full = (1 << min(ways, 62)) - 1
+    m = np.asarray(masks, np.int64) & full
+    m = np.where(m == full, 0, m)
+    return (m[..., :, None] == m[..., None, :],
+            np.where(m == 0, ways, np.bitwise_count(m)))
+
+
+def _classes_apart(chunks: tuple, llc: LLCConfig, mix: MixConfig) -> bool:
+    """Whether no LLC block of the NVDLA's chunks lies in a co-runner's
+    span, so that the classes of a way partition never touch one
+    another's blocks: then each class's lines stay in its own ways, the
+    condition under which a partitioned lane runs as records."""
+    cb, cs, cc = chunks
+    if not cc.shape[0]:
+        return True
+    bb = llc.block_bytes
+    lo = int(cb.min()) // bb
+    hi = int((cb + (cc - 1) * cs).max()) // bb
+    return all(hi < region // bb or (region + span * 64 - 1) // bb < lo
+               for span, region in _corunner_spans(llc, mix))
 
 
 def _lane_metrics_from_runs(*, n_segments, accesses, hits, runs, bb, nv,
@@ -1368,7 +1416,7 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     (``bench/reference/lane.py``), whatever lanes share its batch.  A
     single lane is a batch of one (``interference_lane_metrics``).
 
-    Each unmasked lane is compacted first: ``lane_records`` builds its
+    Each lane is compacted first: ``lane_records`` builds its
     records from the NVDLA chunks without expanding it, where its
     co-runner lines fit the LLC block and its spans hold a chunk
     (``_direct_records``), and ``compact_lane`` folds the expanded lane
@@ -1397,7 +1445,12 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     ``way_masks`` is an equal-length sequence of per-lane LLC way
     partitions (``int`` victim masks, or ``None`` for unpartitioned
     lanes) — masked and unmasked lanes mix freely in one compiled
-    batch via the kernel's zero-mask sentinel."""
+    batch via the kernels' zero-mask sentinel.  A partitioned lane
+    compacts like any other where its classes never touch one
+    another's blocks (``_classes_apart``): its records carry the
+    members' masks into ``record_lane_scan``.  Otherwise, and where its
+    bucket's records do not pay, it runs through the masked segment
+    program, every segment in the round scan."""
     lanes_n = len(llcs)
     if not (len(drams) == len(mixes) == lanes_n):
         raise ValueError(
@@ -1412,6 +1465,7 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
     if masked and mesh is not None:
         raise ValueError("way-masked batches do not support mesh "
                          "sharding yet — pass mesh=None")
+    fenced = [masked and way_masks[i] is not None for i in range(lanes_n)]
     expanded: list = [None] * lanes_n
 
     def lane(i):
@@ -1428,15 +1482,22 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
             expanded[i] = (b, s, c, nv), n_seg
         return expanded[i]
 
+    def sels(i):
+        """Lane ``i``'s allocation mask per expanded segment, or None."""
+        return (partition_way_sels(lane(i)[0][3], llcs[i], way_masks[i])
+                if fenced[i] else None)
+
     with tracing.span(tracing.LANE_PLAN):
         chunks = nvdla_chunks(nvdla_segs, chunk_bursts)
-        # way-masked lanes replay every segment in the round scan, so
-        # only unmasked batches compact; a record folds repeats of an
-        # NVDLA chunk that continues the one before, so a trace with
-        # none (the Fig. 6 windows) has nothing to fold
-        compact = not masked and _chunks_continue(chunks)
-        direct = [compact and _direct_records(chunks, llc, mix)
-                  for llc, mix in zip(llcs, mixes)]
+        # a record folds repeats of an NVDLA chunk that continues the
+        # one before, so a trace with none (the Fig. 6 windows) has
+        # nothing to fold; a partitioned lane folds where its classes
+        # never share a block
+        compact = _chunks_continue(chunks)
+        folds = [compact and (not fenced[i] or _classes_apart(
+            chunks, llcs[i], mixes[i])) for i in range(lanes_n)]
+        direct = [folds[i] and _direct_records(chunks, llcs[i], mixes[i])
+                  for i in range(lanes_n)]
         checked: set = set()
         for i, (llc, dram) in enumerate(zip(llcs, drams)):
             _check_row_block(llc, dram)
@@ -1444,17 +1505,15 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                 _check_records_support(chunks, llc, mixes[i], checked)
             else:
                 lane(i)
-        lane_sels = [None if not masked or way_masks[i] is None
-                     else partition_way_sels(lane(i)[0][3], llcs[i],
-                                             way_masks[i])
-                     for i in range(lanes_n)]
     records = [None] * lanes_n
     with tracing.span(tracing.COMPACT):
         if compact:
             records = [
-                lane_records(chunks, llc, mix) if direct[i]
-                else compact_lane(*lane(i)[0], llc, 1 + (
-                    0 if mix.wss == "l1" else mix.corunners))
+                lane_records(chunks, llc, mix,
+                             way_masks[i] if fenced[i] else None)
+                if direct[i] else compact_lane(*lane(i)[0], llc, 1 + (
+                    0 if mix.wss == "l1" else mix.corunners), sels(i))
+                if folds[i] else None
                 for i, (llc, mix) in enumerate(zip(llcs, mixes))]
             tracing.count(tracing.DIRECT_RECORD_LANES, sum(direct))
     out: list[LaneMetrics | None] = [None] * lanes_n
@@ -1468,7 +1527,9 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                 tracing.count(tracing.LANE_SEGMENTS_RAW, sum(raw))
                 tracing.count(tracing.LANE_SEGMENTS, sum(
                     raw if recs is None else [r.raw.shape[0] for r in recs]))
-                if recs is not None and max(r.members for r in recs) > 1:
+                fenced_b = [i for i in bucket if fenced[i]]
+                if recs is not None and (
+                        fenced_b or max(r.members for r in recs) > 1):
                     # the row reduction lays out one set count's hit codes
                     parts = [[j for j, c in enumerate(cfgs_b) if c.sets == n]
                              for n in sorted({c.sets for c in cfgs_b},
@@ -1476,15 +1537,19 @@ def interference_lane_metrics_batch(nvdla_segs: list, *, llcs, drams,
                     runs = [([bucket[j] for j in js], _record_program(
                         [recs[j] for j in js], [cfgs_b[j] for j in js]))
                         for js in parts]
+                    tracing.count(tracing.PARTITIONED_RECORD_LANES,
+                                  len(fenced_b))
+                    tracing.count(tracing.PARTITIONED_LANE_SEGMENTS, sum(
+                        records[i].raw.shape[0] for i in fenced_b))
                 else:
+                    # plain records come from lanes with no partition
                     views = ([lane(i)[0] for i in bucket]
                              if recs is None else
                              # one member each: plain segments
                              [(r.bases[:, 0], r.strides[:, 0],
                                r.counts[:, 0], r.nv[:, 0]) for r in recs])
                     runs = [(bucket, _segment_program(
-                        views, cfgs_b, [lane_sels[i] for i in bucket],
-                        masked))]
+                        views, cfgs_b, [sels(i) for i in bucket], masked))]
             for part, run in runs:
                 got = run([drams[i] for i in part], t_llc_hit, mesh)
                 for i, m in zip(part, got):
@@ -1503,11 +1568,12 @@ def _chunks_continue(chunks) -> bool:
 
 
 def _records_that_pay(recs: list) -> list | None:
-    """A bucket's records where they pay, else None.  A record of P
-    members costs up to P times a segment's step in the round scan, so
-    compaction must shorten the padded scan, the most segments a lane
-    of the bucket has uncompacted (``raw.sum()``), by more than that."""
-    if recs[0] is None:
+    """A bucket's records where every lane has them and they pay, else
+    None.  A record of P members costs up to P times a segment's step in
+    the round scan, so compaction must shorten the padded scan, the
+    most segments a lane of the bucket has uncompacted (``raw.sum()``),
+    by more than that."""
+    if any(r is None for r in recs):
         return None
     members = max(r.members for r in recs)
     longest = max(r.raw.shape[0] for r in recs)
@@ -1637,18 +1703,22 @@ def _record_program(recs, cfgs_b):
     n_mem = max(r.members for r in recs)
     # members past the bucket's widest record are dead in every record
     recs = [dataclasses.replace(r, **{f: getattr(r, f)[:, :n_mem] for f in (
-        "bases", "strides", "counts", "chunks", "offsets", "nv")})
+        "bases", "strides", "counts", "chunks", "offsets", "nv", "masks")})
         for r in recs]
+    # a partitioned lane's program takes the members' masks; the others
+    # run the unpartitioned program
+    masked = any(r.masks.any() for r in recs)
     s_pad = max(r.raw.shape[0] for r in recs)
     shape = (len(recs), s_pad, n_mem)
     arrays = [np.zeros(shape, np.int32), np.ones(shape, np.int32),
               np.zeros(shape, np.int32), np.ones(shape, np.int32),
               np.zeros(shape, np.int32), np.ones(shape[:2], np.int32),
               np.zeros(shape[:2], np.int32)]
+    masks = np.zeros(shape, np.int32)
     for row, (r, cfg) in enumerate(zip(recs, cfgs_b)):
         k, p = r.counts.shape
-        for a, v in zip(arrays, (r.bases, r.strides, r.counts, r.chunks,
-                                 r.offsets)):
+        for a, v in zip(arrays[:5] + [masks], (
+                r.bases, r.strides, r.counts, r.chunks, r.offsets, r.masks)):
             a[row, :k, :p] = v
         arrays[5][row, :k] = r.periods
         arrays[6][row, :k] = _record_rounds(r, cfg)
@@ -1658,6 +1728,8 @@ def _record_program(recs, cfgs_b):
     def run(drams_b, t_llc_hit, mesh) -> list[LaneMetrics]:
         with tracing.span(tracing.DISPATCH):
             dev = [jnp.asarray(a) for a in arrays] + [sets, ways, blocks]
+            if masked:
+                dev.append(jnp.asarray(masks))
             if mesh is not None:
                 dev = _mesh_shard_lanes(dev, mesh, zero=(2, 6))
             engine = _record_engine(max_sets, max_ways, r_pad)
@@ -2077,12 +2149,19 @@ def _hit_units(codes, table, unit: int, n_u: int, width: int, lanes: int):
 
 
 def _record_rounds(r: LaneRecords, llc: LLCConfig) -> np.ndarray:
-    """Round-scan rounds per record: its first ``ways`` arrivals per
-    set, bounded by the arrivals its members bring to any one set."""
+    """Round-scan rounds per record: each class's first ``k`` arrivals
+    per set (``k`` the class's ways; unpartitioned, one class of
+    ``ways``), bounded by the arrivals the class's members bring to any
+    one set."""
     bb, sets = llc.block_bytes, llc.sets
+    live = r.counts > 0
     last = r.bases + np.maximum(r.counts - 1, 0) * r.strides
-    nb = np.where(r.counts > 0, last // bb - r.bases // bb + 1, 0)
-    return np.minimum(llc.ways, (-(-nb // sets)).sum(axis=1))
+    nb = np.where(live, last // bb - r.bases // bb + 1, 0)
+    same, k = _classes(r.masks, llc.ways)
+    per_class = (same * (-(-nb // sets))[:, None, :]).sum(axis=2)
+    # count each class once, at its first live member
+    first = live & ~(np.tril(same, -1) & live[:, None, :]).any(axis=2)
+    return np.where(first, np.minimum(k, per_class), 0).sum(axis=1)
 
 
 def _record_miss_runs(r: LaneRecords, llc: LLCConfig, codes: np.ndarray,
@@ -2152,6 +2231,8 @@ def _record_miss_runs(r: LaneRecords, llc: LLCConfig, codes: np.ndarray,
 
 @functools.lru_cache(maxsize=32)
 def _record_engine(max_sets: int, max_ways: int, r_pad: int):
+    """The vmapped record program; called with the members' allocation
+    masks as its last operand, it traces the partitioned scan."""
     from repro.core.cache import record_lane_scan
 
     return jax.jit(jax.vmap(functools.partial(

@@ -22,6 +22,8 @@ runs ``LANE_PLAN`` -> ``DISPATCH`` -> ``FETCH`` -> ``DISPATCH`` ->
 its lanes and their counts, with no ``MISS_RUNS`` or ``DRAM_ROWS``.
 ``COMPACT`` builds most such lanes' records from the NVDLA chunks
 (``DIRECT_RECORD_LANES``), so ``LANE_PLAN`` expands only the rest.
+Way-partitioned lanes take the same path where their records pay
+(``PARTITIONED_RECORD_LANES``, ``PARTITIONED_LANE_SEGMENTS``).
 ``CAMPAIGN`` and ``LANE_BATCH`` are parents: they own only the time
 their leaves leave.
 """
@@ -55,6 +57,9 @@ LANE_SEGMENTS = "sweep.lane_segments"          # records the programs scan
 LANE_SEGMENTS_RAW = "sweep.lane_segments_raw"  # the same, uncompacted
 DEVICE_REDUCED_LANES = "sweep.device_reduced_lanes"  # lanes counted on device
 DIRECT_RECORD_LANES = "sweep.direct_record_lanes"  # records, trace unexpanded
+# way-partitioned lanes run as records, and the records they scan
+PARTITIONED_RECORD_LANES = "sweep.partitioned_record_lanes"
+PARTITIONED_LANE_SEGMENTS = "sweep.partitioned_lane_segments"
 
 _counts: dict[str, int] = {}
 _lock = threading.Lock()

@@ -306,7 +306,7 @@ SPAN8 = LLCConfig(size_bytes=1024, ways=2, block_bytes=64)
     (WINDOW, [(WIDE, MixConfig(2, "dram")), (WIDE, MixConfig(3, "llc"))],
      None, 0),
     (CUT, [(WIDE, MixConfig(2, "dram")), (WIDE, MixConfig(3, "llc"))],
-     [0x0F, None], 0),
+     [0x0F, None], 2),
     (CUT, [(BLOCK32, MixConfig(2, "dram"))], None, 0),
     (CUT, [(SPAN8, MixConfig(2, "llc"))], None, 0),
     (CUT, [(BLOCK32, MixConfig(1, "dram")), (BLOCK32, MixConfig(0, "l1")),
@@ -314,9 +314,10 @@ SPAN8 = LLCConfig(size_bytes=1024, ways=2, block_bytes=64)
 ], ids=["window", "way-masked", "block32", "span8", "mixed"])
 def test_lanes_build_directly_only_where_they_can(segs, lanes, way_masks,
                                                   direct):
-    """A Fig. 6 window (no chunk continues another), a way-masked batch,
-    co-runner lines wider than a 32 B block and a span shorter than a
-    chunk keep the expanded lane; a batch that mixes them builds the
+    """A Fig. 6 window (no chunk continues another), co-runner lines
+    wider than a 32 B block and a span shorter than a chunk keep the
+    expanded lane; a way-masked batch builds its lanes' records directly
+    as an unmasked one does, and a batch that mixes them builds the
     others' records directly.  Either way every lane gives what the
     plain per-access reference gives."""
     import lane_reference

@@ -30,10 +30,13 @@ LANES = ([(WIDE, MixConfig(k, wss)) for wss in ("l1", "llc", "dram")
           for k in range(5)] + [(NARROW, MixConfig(4, "dram"))])
 
 
-def _records(segs, llc: LLCConfig, mix: MixConfig) -> sweep.LaneRecords:
+def _records(segs, llc: LLCConfig, mix: MixConfig,
+             way_mask: int | None = None) -> sweep.LaneRecords:
     b, s, c, nv = sweep.corunner_meta(segs, llc=llc, mix=mix)
-    return sweep.compact_lane(b, s, c, nv, llc,
-                              1 + (0 if mix.wss == "l1" else mix.corunners))
+    return sweep.compact_lane(
+        b, s, c, nv, llc, 1 + (0 if mix.wss == "l1" else mix.corunners),
+        None if way_mask is None else sweep.partition_way_sels(nv, llc,
+                                                               way_mask))
 
 
 def _device_and_oracle(recs, llcs, drams) -> tuple[list, list]:
@@ -85,6 +88,28 @@ def compacted():
     for llc, m in LANES])
 def test_device_reduction_matches_the_host_oracle(compacted, i):
     device, oracle = compacted
+    assert dataclasses.asdict(device[i]) == dataclasses.asdict(oracle[i])
+
+
+# way-partitioned lanes: the record program's hit codes of a fenced
+# class, laid out as the unpartitioned ones
+FENCED = [(WIDE, MixConfig(k, wss), w) for wss in ("llc", "dram")
+          for k in (0, 2, 4) for w in (0x01, 0x0F, 0x7F)]
+
+
+@pytest.fixture(scope="module")
+def fenced():
+    recs = [_records(FRAME, llc, m, w) for llc, m, w in FENCED]
+    assert all(r.masks.any() for r in recs)
+    return _device_and_oracle(recs, [llc for llc, _, _ in FENCED],
+                              [DRAM] * len(FENCED))
+
+
+@pytest.mark.parametrize("i", range(len(FENCED)), ids=[
+    f"{m.wss}-x{m.corunners}-{w:#04x}" for _, m, w in FENCED])
+def test_device_reduction_of_partitioned_lanes_matches_the_host_oracle(
+        fenced, i):
+    device, oracle = fenced
     assert dataclasses.asdict(device[i]) == dataclasses.asdict(oracle[i])
 
 
